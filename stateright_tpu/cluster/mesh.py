@@ -10,7 +10,7 @@ decomposition every distributed training stack uses:
   :func:`init_from_env`, the launcher contract) — a
   ``jax.distributed.initialize`` bootstrap that also forces the
   virtual-CPU backend for dry runs (``gloo`` cross-process collectives,
-  per-process ``jax_num_cpu_devices``/``XLA_FLAGS`` device forcing,
+  per-process ``jax_num_cpu_devices`` device forcing,
   exactly like ``__graft_entry__.dryrun_multichip``);
 * :func:`fleet_mesh` builds the host×device ``Mesh`` over the GLOBAL
   device list in host-major order, trimmed so every host contributes
@@ -40,7 +40,6 @@ paths or nothing) — never device work.
 from __future__ import annotations
 
 import os
-import re
 import time
 from typing import List, NamedTuple, Optional
 
@@ -69,23 +68,11 @@ class FleetContext(NamedTuple):
 
 def force_cpu_devices(n: int) -> None:
     """Pin this process to ``n`` virtual CPU devices, BEFORE backend
-    init. Newer JAX spells it ``jax_num_cpu_devices``; 0.4.x reads
-    ``XLA_FLAGS`` at CPU-client creation — and an inherited flag value
-    (the test suite exports 8) must be REPLACED, not kept, or every
-    launched worker would see the parent's device count."""
-    flag = f"--xla_force_host_platform_device_count={int(n)}"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" in flags:
-        flags = re.sub(
-            r"--xla_force_host_platform_device_count=\d+", flag, flags)
-    else:
-        flags = (flags + " " + flag).strip()
-    os.environ["XLA_FLAGS"] = flags
-    try:
-        import jax
-        jax.config.update("jax_num_cpu_devices", int(n))
-    except Exception:
-        pass  # 0.4.x: the XLA_FLAGS path above carries it
+    init. ``jax_num_cpu_devices`` takes precedence over an inherited
+    ``XLA_FLAGS`` device count (the test suite exports 8), so every
+    launched worker sees its own count, not its parent's."""
+    import jax
+    jax.config.update("jax_num_cpu_devices", int(n))
 
 
 def init_process(coordinator: Optional[str] = None,
@@ -293,15 +280,13 @@ def dcn_probe(mesh, axis: str) -> float:
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..parallel.sharded import shard_map_compat
-
     d = mesh.shape[axis]
 
     def local(x):
         return lax.psum(jnp.sum(x), axis)
 
-    fn = jax.jit(shard_map_compat(local, mesh=mesh, in_specs=P(axis),
-                                  out_specs=P()))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                               out_specs=P(), check_vma=False))
     x = jax.device_put(np.ones((d,), np.float32),
                        NamedSharding(mesh, P(axis)))
     np.asarray(fn(x))  # compile + warm

@@ -50,7 +50,7 @@ INTERVENTIONS = {
     "grow": "G", "hgrow": "G", "egrow": "G", "kovf": "K",
     "compile": "c", "retry": "R", "watchdog": "W", "autosave": "a",
     "failover": "F", "degrade": "D", "spill": "S", "evict": "S",
-    "pause": "P", "recorder_dump": "!", "fused_fallback": "f",
+    "pause": "P", "recorder_dump": "!",
     "fused_unsupported": "f", "crash": "C", "restart": "C",
     "partition": "C", "violation": "V", "burnin_preempt": "B",
     "host_drop": "H", "mesh_init": "M",

@@ -125,11 +125,6 @@ EVENT_SCHEMA: Dict[str, frozenset] = {
     "audit": frozenset({"chunk", "rows", "mismatches"}),
     "corruption": frozenset({"error"}),
     "quarantine": frozenset({"device", "quarantined"}),
-    # tpu_options(fused='auto') attempted the Pallas build and fell
-    # back to the staged path; `cause` is the resilience taxonomy's
-    # classification of the build failure (transient / capacity /
-    # programming), `error` the underlying message
-    "fused_fallback": frozenset({"cause", "error"}),
     # a fused='auto' configuration is OUTSIDE the kernel's support
     # matrix (sound mode / host properties / hint) and stayed staged —
     # emitted once per run with the supports() reason, so "why didn't

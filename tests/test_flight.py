@@ -235,22 +235,28 @@ class TestLiveStreaming:
 # --- bench-trend gate ------------------------------------------------------
 
 class TestBenchHistory:
-    def test_flags_real_r05_empty_artifact(self, capsys):
+    def test_flags_empty_artifact(self, tmp_path, capsys):
+        # the round-5 hole this tool exists to catch: an artifact whose
+        # bench process died (rc=1, parsed=null) next to a clean round
         bench_history = _tool("bench_history")
         root = os.path.dirname(os.path.dirname(os.path.abspath(
             __file__)))
+        (tmp_path / "BENCH_r05.json").write_text(json.dumps(
+            {"n": 1, "rc": 1, "tail": "", "parsed": None}))
+        with open(os.path.join(root, "BENCH_r06.json")) as f:
+            (tmp_path / "BENCH_r06.json").write_text(f.read())
         report = bench_history.build_report(
-            [os.path.join(root, f) for f in sorted(os.listdir(root))
-             if f.startswith("BENCH_") and f.endswith(".json")])
+            [str(tmp_path / "BENCH_r05.json"),
+             str(tmp_path / "BENCH_r06.json")])
         empty = [f for f in report["flags"]
                  if f["kind"] == "empty_artifact"]
-        assert any(f["round"] == "r05" for f in empty), report["flags"]
+        assert [f["round"] for f in empty] == ["r05"], report["flags"]
         # markdown renders without blowing up
         out = io.StringIO()
         bench_history.render_markdown(report, out)
         assert "empty_artifact" in out.getvalue()
         # --check turns flags into a failing exit code (the gate)
-        assert bench_history.main([root, "--check"]) == 1
+        assert bench_history.main([str(tmp_path), "--check"]) == 1
         capsys.readouterr()
 
     def test_flags_synthetic_regression(self, tmp_path):
@@ -285,18 +291,15 @@ class TestBenchHistory:
                        if f["kind"] == "regression")
 
     def test_committed_artifacts_pass_gate(self, capsys):
-        # THE tier-1 bench-trend gate (CI/tooling satellite): the
-        # committed BENCH_*.json set must be clean apart from the
-        # ACKNOWLEDGED r05 empty artifact (the round-5 rc=1 hole this
-        # tool exists to catch). A new empty/partial/regressed artifact
-        # in a future round fails the suite right here.
+        # THE tier-1 bench-trend gate: the committed BENCH_*.json set
+        # must be clean. A new empty/partial/regressed artifact in a
+        # future round fails the suite right here.
         bench_history = _tool("bench_history")
         root = os.path.dirname(os.path.dirname(os.path.abspath(
             __file__)))
-        assert bench_history.main(
-            [root, "--check", "--allow", "empty_artifact:r05"]) == 0
+        assert bench_history.main([root, "--check"]) == 0
         out = capsys.readouterr().out
-        assert "(allowed)" in out  # still reported, just not fatal
+        assert "none" in out  # the flags section reports no flag
 
     def test_allow_does_not_mask_new_flags(self, tmp_path, capsys):
         bench_history = _tool("bench_history")

@@ -33,7 +33,7 @@ pytestmark = pytest.mark.faults
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _unavailable(msg="UNAVAILABLE: fake tunnel drop (injected)"):
+def _unavailable(msg="UNAVAILABLE: fake backend drop (injected)"):
     return RuntimeError(msg)
 
 
@@ -102,7 +102,7 @@ class TestClassification:
         for msg in ("UNAVAILABLE: TPU backend setup/compile error",
                     "DEADLINE_EXCEEDED: slice op",
                     "connection reset by peer",
-                    "the tunnel collapsed"):
+                    "socket closed by the remote end"):
             assert classify_error(RuntimeError(msg)) \
                 is FaultKind.TRANSIENT, msg
         assert classify_error(ChunkDeadlineError("hung")) \
@@ -170,7 +170,7 @@ class TestClassification:
         assert blamed_device(RuntimeError(
             "UNAVAILABLE: device 3 heartbeat lost")) == 3
         assert blamed_device(RuntimeError(
-            "UNAVAILABLE: TPU_2 tunnel reset")) == 2
+            "UNAVAILABLE: TPU_2 connection reset")) == 2
         assert blamed_device(RuntimeError(
             "ABORTED: chip 1 power fault")) == 1
         assert blamed_device(RuntimeError(
@@ -830,7 +830,7 @@ class TestFailover:
         # device dies with a transient fault; the un-budgeted host BFS
         # fallback must still answer the check
         def hook(chunk):
-            raise _unavailable("UNAVAILABLE: permanent tunnel death")
+            raise _unavailable("UNAVAILABLE: permanent backend death")
 
         ck = (TwoPhaseSys(4).checker()
               .tpu_options(capacity=1 << 12, race_budget=0.0,
@@ -934,26 +934,25 @@ class TestBenchContract:
             assert row["gen_per_uniq"] is None \
                 or row["gen_per_uniq"] >= 1.0
 
-    def test_backend_init_failure_falls_back_to_cpu(self):
-        # ROADMAP item 3's hole (BENCH_r05: rc=1, no artifact, because
-        # platform INIT raised before per-workload isolation): an
-        # unusable configured backend must be classified, fall back to
-        # CPU, run the matrix, and still land a tagged contract line
+    def test_backend_init_failure_exits_classified(self):
+        # an unusable configured backend ends the bench non-zero with
+        # the classified cause — it never moves to the CPU, so no CPU
+        # number can land under a device metric's name
         env = dict(os.environ, JAX_PLATFORMS="definitely_not_a_backend")
         proc = subprocess.run(
             [sys.executable, os.path.join(REPO, "bench.py"), "--smoke"],
             capture_output=True, text=True, timeout=600, env=env,
             cwd=REPO)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        payload = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert payload["backend"] == "cpu"
-        assert payload["init_fallback"] is True
-        assert payload["init_cause"]  # classified, not just recorded
-        assert payload["value"] is not None  # the matrix actually ran
+        assert proc.returncode == 2, proc.stderr[-2000:]
+        assert not proc.stdout.strip()  # no contract line at all
         rows = [json.loads(ln) for ln in proc.stderr.splitlines()
                 if ln.startswith("{")]
-        fb = [r for r in rows if r.get("workload") == "backend"]
-        assert fb and fb[0]["fallback"] == "cpu"
+        fail = [r for r in rows if r.get("workload") == "backend"]
+        assert len(fail) == 1
+        assert fail[0]["cause"] in ("transient", "capacity",
+                                    "programming")
+        assert "definitely_not_a_backend" in fail[0]["error"]
+        assert "backend init failed" in fail[0]["error"]
 
     def test_forced_failure_still_lands_artifact(self):
         proc = _run_bench("--inject-fault")

@@ -35,7 +35,7 @@ found in the trace:
     layer's retry/watchdog/autosave/failover/degrade and the tiering
     layer's spill/evict events, flight-recorder dumps, and the soak
     harness's live crash/restart/partition injections) with
-    timestamps — on a flaky round this table says *where* the tunnel
+    timestamps — on a flaky round this table says *where* the device
     dropped, what the engine did about it, and whether an autosave
     landed;
   * a fleet summary line (process/host counts, the DCN round-trip
@@ -177,7 +177,7 @@ def report(events, out=None):
                   ("grow", "hgrow", "egrow", "kovf", "compile",
                    "retry", "watchdog", "autosave", "failover",
                    "degrade", "promote", "host_promote",
-                   "fused_fallback", "fused_unsupported",
+                   "fused_unsupported",
                    "recorder_dump",
                    "corruption", "quarantine",
                    "spill", "evict", "pause",
@@ -394,17 +394,9 @@ def report(events, out=None):
                          if e.get("bucket") == b]
                 out.write(f"  bucket {b}: lanes={lanes[0]}\n")
 
-        # fused-kernel summary: which path the run took, why a
-        # fused='auto' attempt fell back (the classified cause) or
-        # never fired (the supports() exclusion), and what the
-        # cross-chunk dedup ring killed
-        fb = [e for e in evs if e["ev"] == "fused_fallback"]
-        if fb:
-            causes = sorted({e.get("cause", "?") for e in fb})
-            out.write(f"\nfused: fallbacks={len(fb)} "
-                      f"causes={causes} "
-                      f"(staged path ran; first error: "
-                      f"{fb[0].get('error', '?')!r})\n")
+        # fused-kernel summary: why a fused='auto' run never fused
+        # (the supports() exclusion), and what the cross-chunk dedup
+        # ring killed
         unsup = [e for e in evs if e["ev"] == "fused_unsupported"]
         if unsup:
             out.write(f"\nfused: unsupported — "
