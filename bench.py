@@ -161,14 +161,14 @@ def _compact_metrics(ck):
         m["fault_device"] = prof["fault_device"]
     search = prof.get("search")
     if search:
-        for k, label in (("sync_stall", "stall_frac"),
-                         ("host_overlap", "overlap_frac"),
+        for k, label in (("span_s.sync", "stall_frac"),
+                         ("span_s.decode", "overlap_frac"),
                          # device-time attribution (obs GLOSSARY
-                         # device_s/xfer_s): how much of the wall was
-                         # device compute vs transfer, so a
+                         # span_s.device/span_s.pull): how much of the
+                         # wall was device compute vs transfer, so a
                          # slow round can be blamed on the right side
-                         ("device_s", "device_frac"),
-                         ("xfer_s", "xfer_frac")):
+                         ("span_s.device", "device_frac"),
+                         ("span_s.pull", "xfer_frac")):
             if k in prof:
                 m[label] = round(prof[k] / search, 3)
     # span attribution (obs/spans.py, attached by profile()): the

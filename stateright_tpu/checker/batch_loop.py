@@ -107,7 +107,7 @@ class BatchLoop:
 
     def __init__(self, model, lanes: int, capacity: int, fmax: int,
                  chunk_steps: int = 32, grow_at: float = 0.55,
-                 metrics=None, trace=None, spans=None):
+                 metrics=None, trace=None):
         reason = batch_supports(model)
         if reason is not None:
             raise ValueError(f"model unsupported by the batch loop: "
@@ -126,9 +126,11 @@ class BatchLoop:
         self._steps = int(chunk_steps)
         self._metrics = metrics
         self._trace = trace
-        # span profiler hook (obs/spans.py SpanRecorder): the batch's
-        # dispatch/device/xfer/host intervals for stall attribution
-        self._spans = spans
+        # span profiler (obs/spans.py SpanRecorder): the batch's
+        # seed/dispatch/sync/pull/decode phases (and the after-the-fact
+        # device interval) for stall attribution, timed under span_s.*
+        from ..obs import SpanRecorder
+        self._spans = SpanRecorder(trace, metrics=metrics)
         self._properties = model.properties()
         self._prop_count = len(self._properties)
         fa = self.fmax * model.max_actions
@@ -186,12 +188,12 @@ class BatchLoop:
         self._init_rows = rows
         self._init_fps = fps
         self._n_init = len(rows)
-        t0 = time.perf_counter()
         from ..ops.hashtable import plan_insert_host
-        plan = plan_insert_host(fps, self.capacity)
-        self._proto = seed_carry(model, self.qcap, self.capacity, rows,
-                                 np.uint32(0), init_fps=fps,
-                                 table_plan=(plan, fps))
+        with self._spans.span("seed"):
+            plan = plan_insert_host(fps, self.capacity)
+            self._proto = seed_carry(model, self.qcap, self.capacity,
+                                     rows, np.uint32(0), init_fps=fps,
+                                     table_plan=(plan, fps))
         key = self.compile_key
         fn = _BATCH_CHUNK_CACHE.get(key)
         if fn is None:
@@ -236,8 +238,6 @@ class BatchLoop:
             graft = jax.jit(_graft, donate_argnums=(0,))
             _GRAFT_CACHE[gkey] = graft
         self._graft = graft
-        if self._metrics is not None:
-            self._metrics.add_time("seed", time.perf_counter() - t0)
 
     # --- lane lifecycle ------------------------------------------------
     def activate(self, lane: int) -> None:
@@ -277,39 +277,38 @@ class BatchLoop:
             vmax=jnp.zeros((L,), jnp.int32),
             pdh=jnp.zeros((L,), jnp.int32),
             prb=jnp.zeros((L,), jnp.int32))
-        t0 = time.perf_counter()
-        carry, stats_d = self._chunk(carry, np.int32(2**31 - 1),
-                                     np.int32(self.grow_limit),
-                                     np.int32(0))
+        spans = self._spans
+        with spans.span("dispatch"):
+            carry, stats_d = self._chunk(carry, np.int32(2**31 - 1),
+                                         np.int32(self.grow_limit),
+                                         np.int32(0))
         self._carry = carry
         t_disp = time.perf_counter()
         if self._metrics is not None:
             self._metrics.inc("chunks")
-            self._metrics.add_time("dispatch", t_disp - t0)
-        t1 = time.perf_counter()
         # readiness split (the solo engines' _materialize_stats idiom):
-        # dispatch->ready is the batched kernel executing, ready->
-        # materialized the stats transfer
-        try:
-            stats_d.block_until_ready()
-        except AttributeError:
-            pass  # already host-side (host fallbacks, tests)
-        t_ready = time.perf_counter()
-        stats = np.asarray(jax.device_get(stats_d))
-        t_mat = time.perf_counter()
-        if self._metrics is not None:
-            self._metrics.add_time("sync_stall", t_mat - t1)
-        if self._spans is not None:
-            self._spans.record("dispatch", t0, t_disp)
-            self._spans.record("device", t_disp, t_ready)
-            self._spans.record("xfer", t_ready, t_mat)
+        # dispatch->ready is the batched kernel executing (the device
+        # span), the pull the stats transfer
+        with spans.span("sync"):
+            try:
+                stats_d.block_until_ready()
+            except AttributeError:
+                pass  # already host-side (host fallbacks, tests)
+        spans.record("device", t_disp, time.perf_counter())
+        with spans.span("pull"):
+            stats = np.asarray(jax.device_get(stats_d))
         self._last_stats = stats
+        # the per-lane consume loop is this engine's host phase
+        with spans.span("decode"):
+            return self._consume(stats, carry)
+
+    def _consume(self, stats, carry) -> List[Tuple[int, str]]:
+        jax = self._jax
         # ONE pull covers every lane's fresh log rows (the batch is
         # sized for small jobs, so the whole log matrix is cheap)
         log = None
         exits: List[Tuple[int, str]] = []
         P = self._prop_count
-        t_host0 = time.perf_counter()
         for lane in self.active_lanes():
             st = self._lanes[lane]
             row = stats[lane]
@@ -364,9 +363,6 @@ class BatchLoop:
             if reason is not None:
                 st.active = False
                 exits.append((lane, reason))
-        if self._spans is not None:
-            # the per-lane consume loop is this engine's host phase
-            self._spans.record("host", t_host0, time.perf_counter())
         return exits
 
     # --- per-lane reads ------------------------------------------------

@@ -104,7 +104,7 @@ class CheckerBuilder:
           loop — chunk N+1 is dispatched while the host consumes chunk
           N's stats; ``pipeline=False`` forces the synchronous loop
           (debugging, latency A/B — observable results are identical
-          either way, measurable via ``profile()``'s overlap timers);
+          either way, measurable via ``profile()['attribution']``);
         * ``trace=<path | file | callable | list>`` enables the
           structured run-trace: every engine (host engines included)
           emits timestamped JSONL events (chunk completed, growth and
@@ -122,9 +122,9 @@ class CheckerBuilder:
           artifact destination (default: next to ``autosave``, else
           the temp dir);
         * ``profile_dir=path`` captures a ``jax.profiler`` trace of
-          the whole run into the directory (TensorBoard/Perfetto) —
-          the deep-dive tier above the per-chunk ``device_s``/
-          ``xfer_s`` attribution in ``profile()``;
+          the whole run into the directory (TensorBoard/Perfetto),
+          device ops beside the host phase spans (``sr.<phase>``) —
+          the deep-dive tier under ``profile()``'s span timers;
         * resilience (README § Resilience, ``checker/resilience.py``):
           ``retries=N`` retries a transient backend fault (UNAVAILABLE,
           DEADLINE_EXCEEDED, connection resets) up to N consecutive times,
@@ -180,21 +180,27 @@ class CheckerBuilder:
         :class:`~stateright_tpu.models.packed.PackedModel` protocol.
         With ``tpu_options(mesh=jax.sharding.Mesh(...))`` the search runs
         SPMD over the mesh: frontier, visited table and logs sharded by
-        fingerprint prefix, children routed to owner shards over ICI."""
+        fingerprint prefix, children routed to owner shards over ICI.
+        The whole call is the ``spawn`` span of the device engine."""
+        from ..obs import annotate
         from .race import RacingChecker, race_eligible
-        if race_eligible(self):
-            # small-model latency: the device engine's fixed dispatch +
-            # sync costs dwarf tiny models, so a budgeted host BFS
-            # races the device run and the first finisher wins (see
-            # checker/race.py); tpu_options(race=False) opts out. Mesh
-            # runs race only on explicit race=True (the device lane is
-            # then the sharded engine).
-            return RacingChecker(self)
-        if "mesh" in self.tpu_options_:
-            from ..parallel.engine import ShardedTpuChecker
-            return ShardedTpuChecker(self)
-        from .tpu import TpuChecker
-        return TpuChecker(self)
+        t0 = time.perf_counter()
+        with annotate("spawn"):
+            if race_eligible(self):
+                # small-model latency: the device engine's fixed
+                # dispatch + sync costs dwarf tiny models, so a budgeted
+                # host BFS races the device run and the first finisher
+                # wins (see checker/race.py); tpu_options(race=False)
+                # opts out. Mesh runs race only on explicit race=True
+                # (the device lane is then the sharded engine).
+                checker = RacingChecker(self, spawn_t0=t0)
+                engine = checker._tpu
+            else:
+                checker = engine = device_engine(self, spawn_t0=t0)
+        # the recorder is born with the engine: the span is recorded
+        # once it exists
+        engine._spans.record("spawn", t0, time.perf_counter())
+        return checker
 
     def serve(self, address, engine: str = "bfs") -> "Checker":
         """Start the Explorer web service (`src/checker.rs:99-114`).
@@ -265,11 +271,17 @@ class Checker:
             reason = getattr(self, "_fused_unsupported_reason", None)
             parts.append("fused=unsupported"
                          + (f" ({reason})" if reason else ""))
-        if elapsed > 0 and "sync_stall" in prof:
-            parts.append(f"stall={prof['sync_stall'] / elapsed:.0%}")
-        if elapsed > 0 and "host_overlap" in prof:
+        attribution = prof.get("attribution") or {}
+        wall = sum(attribution.values())
+        if wall > 0:
+            # the chunk loop's span attribution (obs/spans.py): stall =
+            # device-bound share, overlap = host work hidden under it
+            from ..obs import DEVICE_SPANS
+            stall = sum(v for k, v in attribution.items()
+                        if k in DEVICE_SPANS)
+            parts.append(f"stall={stall / wall:.0%}")
             parts.append(
-                f"overlap={prof['host_overlap'] / elapsed:.0%}")
+                f"overlap={attribution.get('overlap', 0.0) / wall:.0%}")
         if "shard_balance" in prof:
             parts.append(f"shard_balance={prof['shard_balance']}")
         return "# " + " ".join(parts) if parts else ""
@@ -397,3 +409,23 @@ class Checker:
         raise AssertionError(
             f'Invalid discovery for "{name}"{info}, but a valid one was '
             f"found. found={found.into_actions()!r}")
+
+
+def device_engine(builder: CheckerBuilder,
+                  spawn_t0: Optional[float] = None) -> "Checker":
+    """The device engine ``builder`` asks for — sharded over
+    ``tpu_options(mesh=...)``, else single-chip — its construction
+    recorded as the engine's ``init`` span. ``spawn_t0`` is
+    ``spawn_tpu()``'s entry stamp, where ``first_dispatch_s`` counts
+    from."""
+    from ..obs import annotate
+    t0 = time.perf_counter()
+    with annotate("init"):
+        if "mesh" in builder.tpu_options_:
+            from ..parallel.engine import ShardedTpuChecker
+            engine = ShardedTpuChecker(builder, spawn_t0)
+        else:
+            from .tpu import TpuChecker
+            engine = TpuChecker(builder, spawn_t0)
+    engine._spans.record("init", t0, time.perf_counter())
+    return engine

@@ -14,14 +14,15 @@ from __future__ import annotations
 import os
 import threading
 import time
+from contextlib import nullcontext
 from typing import Dict, Optional
 
 from ..core import Expectation, Model
 from ..fingerprint import fp64_node
 from ..obs import (FlightRecorder, Metrics, SpanRecorder,
                    apply_artifact_dir, attach_attribution,
-                   default_flight_path, fault_info, identity_fields,
-                   make_trace, new_run_id)
+                   default_flight_path, fault_info, gc_totals,
+                   identity_fields, make_trace, new_run_id)
 from .builder import Checker, CheckerBuilder
 
 
@@ -97,15 +98,19 @@ class HostChecker(Checker):
         # from file paths. Stamped onto run_start by _step_wrapper.
         self._run_id = obs_opts.get("run_id") or new_run_id()
         self._job_id = obs_opts.get("job_id")
-        # span profiler (obs/spans.py): the device engines record each
-        # pipeline phase as an INTERVAL here; always on (bounded ring)
-        # so profile()'s attribution works traceless, and mirrored as
-        # `span` trace events when a sink is configured
-        self._spans = SpanRecorder(self._trace)
-
-    def _timed(self, name: str):
-        """Accumulate wall time under a glossary phase key."""
-        return self._metrics.timed(name)
+        # span profiler (obs/spans.py): the engines record each host
+        # phase as an INTERVAL here, its seconds under span_s.<phase>;
+        # always on (bounded ring) so profile()'s attribution works
+        # traceless, mirrored as `span` trace events when a sink is
+        # configured and annotated in any jax.profiler trace
+        self._spans = SpanRecorder(self._trace, metrics=self._metrics,
+                                   hold=True)
+        #: a span around the whole background run (the race names its
+        #: host racer's run "race.host"); None records none
+        self._run_span: Optional[str] = None
+        #: perf_counter stamp of the run's end (set with _done)
+        self._t_done: Optional[float] = None
+        self._gc0 = gc_totals()
 
     def profile(self) -> Dict[str, float]:
         """Snapshot of the run's metrics registry: phase timers
@@ -113,12 +118,19 @@ class HostChecker(Checker):
         pinned in ONE place — ``stateright_tpu.obs.GLOSSARY`` (also
         rendered in README.md § Observability) — rather than restated
         per engine; engines report only the phases they run. Engines
-        that recorded spans additionally report ``attribution`` /
+        that ran a chunk loop additionally report ``attribution`` /
         ``idle_s`` / ``bubble_frac`` — the overlap-aware wall-time
-        split (attached post-snapshot: fractions must never ride a
-        summing ``Metrics.merge``)."""
-        return attach_attribution(self._metrics.snapshot(),
-                                  self._spans)
+        split of the loop (attached post-snapshot: fractions must never
+        ride a summing ``Metrics.merge``); every engine reports the
+        process's ``gc_s`` / ``gc_collections`` since its construction,
+        and ``spans_dropped`` once its span ring wrapped."""
+        snap = attach_attribution(self._metrics.snapshot(), self._spans)
+        gc_s, gc_n = gc_totals()
+        snap["gc_s"] = gc_s - self._gc0[0]
+        snap["gc_collections"] = gc_n - self._gc0[1]
+        if self._spans.dropped:
+            snap["spans_dropped"] = self._spans.dropped
+        return snap
 
     def run_id(self) -> str:
         """This run's correlation id (stamped on its ``run_start``
@@ -306,8 +318,8 @@ class HostChecker(Checker):
         """Optional ``jax.profiler`` capture behind
         ``tpu_options(profile_dir=...)``: the full XLA-level trace
         (device timelines, HLO costs) lands in the directory for
-        TensorBoard/Perfetto — the deep-dive tier above the host-side
-        ``device_s``/``xfer_s`` estimates. Failures never kill the run."""
+        TensorBoard/Perfetto, with every host phase span annotated as
+        ``sr.<phase>`` on the same clock. Failures never kill the run."""
         if self._profile_dir is None:
             return False
         try:
@@ -323,8 +335,10 @@ class HostChecker(Checker):
             return False
 
     def _run_wrapper(self) -> None:
-        for _ in self._step_wrapper():
-            pass
+        with (self._spans.span(self._run_span) if self._run_span
+              else nullcontext()):
+            for _ in self._step_wrapper():
+                pass
 
     def _step_wrapper(self):
         """Generator twin of the old blocking run wrapper: the SAME
@@ -350,6 +364,8 @@ class HostChecker(Checker):
             faults = fault_info(self._model)
             if faults is not None:
                 trace.emit("fault_injection", **faults)
+        # the header leads: now the set-up spans (spawn, init) follow
+        self._spans.release()
         profiling = self._start_profiler()
         try:
             with self._metrics.timed("search"):
@@ -371,6 +387,7 @@ class HostChecker(Checker):
                     jax.profiler.stop_trace()
                 except Exception:
                     pass  # a failed stop must not mask the run result
+            self._t_done = time.perf_counter()
             self._done = True
             if trace:
                 trace.emit("done", gen=self._state_count,

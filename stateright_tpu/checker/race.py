@@ -43,15 +43,18 @@ from __future__ import annotations
 import atexit
 import threading
 import time
+from typing import Optional
 
-from .builder import Checker, CheckerBuilder
+from .builder import Checker, CheckerBuilder, device_engine
 
 #: worker THREADS of cancelled losers (threads only — retaining the
 #: checker objects would pin their visited sets/frontiers/device logs
 #: for process lifetime); a loser may still be draining a device chunk,
 #: and XLA teardown racing a live dispatch aborts the process
-#: (observed: "FATAL: exception not rethrown" on exit)
+#: (observed: "FATAL: exception not rethrown" on exit). Pruned of
+#: finished threads on every append, so it holds live threads only.
 _LOSER_THREADS: list = []
+_LOSER_LOCK = threading.Lock()
 
 
 @atexit.register
@@ -75,7 +78,18 @@ def _drain_losers() -> None:
 
 def _retire(checker) -> None:
     checker.cancel()
-    _LOSER_THREADS.append(getattr(checker, "_thread", None))
+    thread = getattr(checker, "_thread", None)
+    with _LOSER_LOCK:
+        _LOSER_THREADS[:] = [t for t in _LOSER_THREADS if t.is_alive()]
+        if thread is not None:
+            _LOSER_THREADS.append(thread)
+
+
+def losers_alive() -> int:
+    """Retired racer threads still running (a loser finishes its
+    current chunk or level before it sees the cancel)."""
+    with _LOSER_LOCK:
+        return sum(1 for t in _LOSER_THREADS if t.is_alive())
 
 
 def race_eligible(builder: CheckerBuilder) -> bool:
@@ -103,25 +117,22 @@ class RacingChecker(Checker):
     #: finish at ~2 s should not get its racer cancelled at the line.
     HOST_BUDGET_S = 1.5
 
-    def __init__(self, builder: CheckerBuilder):
+    def __init__(self, builder: CheckerBuilder,
+                 spawn_t0: Optional[float] = None):
         from .bfs import BfsChecker
-        from .tpu import TpuChecker
 
         self._model = builder.model
         self._builder = builder  # kept for the engine-failover fallback
         budget = builder.tpu_options_.get("race_budget")
         if budget is not None:
             self.HOST_BUDGET_S = float(budget)
-        if "mesh" in builder.tpu_options_:
-            # explicit race=True on a mesh run (race_eligible): the
-            # device lane is the sharded engine, whose degradation
-            # ladder runs BEFORE the failover rung below ever applies
-            from ..parallel.engine import ShardedTpuChecker
-            self._tpu = ShardedTpuChecker(builder)
-        else:
-            self._tpu = TpuChecker(builder)
+        # with explicit race=True on a mesh run (race_eligible) the
+        # device lane is the sharded engine, whose degradation ladder
+        # runs BEFORE the failover rung below ever applies
+        self._tpu = device_engine(builder, spawn_t0)
         try:
             self._host = BfsChecker(builder)
+            self._host._run_span = "race.host"
         except Exception:
             # a model that can't run on the host engine races nothing
             self._host = None
@@ -187,6 +198,14 @@ class RacingChecker(Checker):
                 _retire(host)
                 host = None
             time.sleep(0.002)
+        winner = self._winner
+        if winner._t_done is not None:
+            # done -> decision: the decider's poll, seen only once it has
+            # happened (so no profiler annotation); recorded before the
+            # decision is published, so every profile read after it
+            # carries the span
+            winner._spans.record("race.decide", winner._t_done,
+                                 time.perf_counter())
         self._decided.set()
         # drop the loser references AFTER publishing the decision, so
         # concurrent progress readers never see a half-decided state;
@@ -265,7 +284,8 @@ class RacingChecker(Checker):
         ``{}``; now both outcomes carry the winner's real phase
         timers/counters. An engine failover (transient device failure
         adopted by the un-budgeted host fallback) adds
-        ``failovers=1``."""
+        ``failovers=1``. ``race_losers_alive`` counts the retired
+        racer threads (of this race and earlier ones) still running."""
         from .bfs import BfsChecker
 
         winner = self._decide()
@@ -274,6 +294,7 @@ class RacingChecker(Checker):
                           else "device")
         if self._failover:
             prof["failovers"] = prof.get("failovers", 0) + 1
+        prof["race_losers_alive"] = losers_alive()
         return prof
 
     def discoveries(self):

@@ -48,6 +48,7 @@ from __future__ import annotations
 import os
 import time
 from collections import deque
+from contextlib import nullcontext
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -325,7 +326,12 @@ def _enable_compile_cache() -> None:
 class TpuChecker(HostChecker):
     """Level-synchronous device BFS over a packed model."""
 
-    def __init__(self, builder: CheckerBuilder):
+    def __init__(self, builder: CheckerBuilder,
+                 spawn_t0: Optional[float] = None):
+        #: where first_dispatch_s counts from: spawn_tpu()'s entry when
+        #: the builder spawned the engine, else its construction
+        self._spawn_t0 = (time.perf_counter() if spawn_t0 is None
+                          else spawn_t0)
         model = builder.model
         for attr in ("packed_width", "max_actions", "encode", "packed_step",
                      "packed_properties"):
@@ -503,9 +509,6 @@ class TpuChecker(HostChecker):
         # most recently enqueued queue row (rides each chunk sync) —
         # the Explorer's live-progress sample for the device engine
         self._recent_row = None
-        # last sync's (device_s, xfer_s) split, set by
-        # _materialize_stats (None when the pull never completed)
-        self._pull_timing = None
         self._resume_path = builder.resume_path_
         self._resume_frontier = None
         self._base_fps: List[int] = []
@@ -533,9 +536,9 @@ class TpuChecker(HostChecker):
                     "re-execute them independently. Audit unreduced "
                     "runs, or rely on the artifact integrity chain.")
 
-    # _timed/profile() come from HostChecker: ONE metrics registry per
-    # run, keys documented once in stateright_tpu.obs.GLOSSARY (the
-    # overlap timers dispatch/sync_stall/host_overlap included).
+    # profile() and the span recorder come from HostChecker: ONE
+    # metrics registry per run, keys documented once in
+    # stateright_tpu.obs.GLOSSARY (every phase span as span_s.<phase>).
 
     def _resolve_host_fns(self, fns) -> "Optional[list]":
         """Normalize ``model.host_property_fns`` into ``_host_props``
@@ -675,19 +678,16 @@ class TpuChecker(HostChecker):
         injection point), then the optional watchdog deadline bounds
         the device round trip (a hang becomes a classified fault).
 
-        Device-time attribution: the host-side ``sync_stall`` timer
-        conflated device compute with the transfer. The pull now
-        splits the interval at the stats future's readiness —
-        dispatch→ready is the ``device_s`` estimate (the chunk program
-        executing; an upper bound under pipelining, where host work
-        overlaps it), ready→materialized is ``xfer_s`` (the transfer).
-        Stored in ``_pull_timing`` for the caller's metrics/trace."""
+        The pull is two spans: ``sync`` (blocked until the stats future
+        is ready) and ``pull`` (the transfer). Dispatch to readiness is
+        recorded after the fact as the chunk's ``device`` span (the
+        chunk program executing; an upper bound under pipelining,
+        where host work overlaps it)."""
         import jax
 
         hook = self._fault_hook
         shards = int(self._fault_shards)
-        self._pull_timing = None
-        self._pull_stamps = None
+        spans = self._spans
 
         def pull():
             if hook is not None:
@@ -696,18 +696,16 @@ class TpuChecker(HostChecker):
                 else:
                     hook(ordinal)
             t0 = time.perf_counter()
-            try:
-                stats_d.block_until_ready()
-            except AttributeError:
-                pass  # already host-side (host fallbacks, tests)
-            t1 = time.perf_counter()
-            out = np.asarray(jax.device_get(stats_d))
-            t2 = time.perf_counter()
-            base = t_disp if t_disp is not None else t0
-            self._pull_timing = (max(t1 - base, 0.0), max(t2 - t1, 0.0))
-            # absolute stamps for the span profiler: the device span
-            # runs dispatch->ready, the xfer span ready->materialized
-            self._pull_stamps = (t1, t2)
+            with spans.span("sync", chunk=ordinal):
+                try:
+                    stats_d.block_until_ready()
+                except AttributeError:
+                    pass  # already host-side (host fallbacks, tests)
+            t_ready = time.perf_counter()
+            with spans.span("pull", chunk=ordinal):
+                out = np.asarray(jax.device_get(stats_d))
+            spans.record("device", t_disp if t_disp is not None else t0,
+                         t_ready, chunk=ordinal)
             return out
 
         deadline = self._chunk_deadline
@@ -970,7 +968,7 @@ class TpuChecker(HostChecker):
         if mode in ("auto", "device"):
             yield from self._drive_device()
             if self._visitor is not None and not self._paused:
-                with self._timed("visit"):
+                with self._spans.span("visit"):
                     self._visit_reached()
         else:
             self._run_levels()
@@ -981,7 +979,7 @@ class TpuChecker(HostChecker):
         frontier the caller gathered) and mark the run paused. Shared
         by the single-chip and sharded chunk loops."""
         path = self.pause_path()
-        with self._timed("pause"):
+        with self._spans.span("pause"):
             self._checkpoint_save(path, rows, ebits, ffps, discoveries)
         self._paused = True
         self._metrics.inc("pauses")
@@ -1221,7 +1219,7 @@ class TpuChecker(HostChecker):
         # sound mode logs cross edges (dedup hits with pending bits) for
         # the post-exhaustion lasso sweep; grows independently on demand
         ecap = self._capacity if self._sound else 0
-        with self._timed("seed"):
+        with self._spans.span("seed"):
             # the block before the first chunk launch is deliberate:
             # launching the chunk (which donates the carry) while the
             # seed/insert programs are still in flight was measured to
@@ -1282,20 +1280,26 @@ class TpuChecker(HostChecker):
         if cc_cap:
             self._metrics.set("cc_dedup_capacity", cc_cap)
 
+        # True until a freshly built chunk program's first call, which
+        # traces and compiles it (or loads it from the compile cache)
+        first_call = [False]
+
         def mk_chunk(reason: str = "initial"):
             # every rebuild implies an XLA retrace (unless the shapes
             # hit the compile cache) — count it and leave a trace event
             self._metrics.inc("compiles")
             if self._trace:
                 self._trace.emit("compile", reason=reason)
-            fn = build_chunk_fn(model, qcap, self._capacity, fmax,
-                                kmax, symmetry=self._symmetry,
-                                sound=self._sound, hcap=hcap,
-                                n_init=n_init, kraw=kraw,
-                                hint_eff=hint_eff, ecap=ecap,
-                                fused=fused_on,
-                                fused_interpret=fused_interp,
-                                cc=cc_cap)
+            with self._spans.span("program"):
+                fn = build_chunk_fn(model, qcap, self._capacity, fmax,
+                                    kmax, symmetry=self._symmetry,
+                                    sound=self._sound, hcap=hcap,
+                                    n_init=n_init, kraw=kraw,
+                                    hint_eff=hint_eff, ecap=ecap,
+                                    fused=fused_on,
+                                    fused_interpret=fused_interp,
+                                    cc=cc_cap)
+            first_call[0] = True
             if not cc_cap:
                 return fn
 
@@ -1377,16 +1381,20 @@ class TpuChecker(HostChecker):
                                    steps=jnp.int32(k_steps),
                                    vmax=jnp.int32(0),
                                    pdh=jnp.int32(0), prb=jnp.int32(0))
-            t_d0 = time.perf_counter()
-            with self._timed("dispatch"):
+            ordinal = int(self._metrics.get("chunks", 0)) + 1
+            with self._spans.span("dispatch", chunk=ordinal), \
+                    (self._spans.span("program", chunk=ordinal)
+                     if first_call[0] else nullcontext()):
                 carry, stats_d = chunk_fn(carry, remaining, grow_limit,
                                           np.int32(self._h_pulled))
+            first_call[0] = False
             t_disp = time.perf_counter()
             self._metrics.inc("chunks")
             if fused_on:
                 self._metrics.inc("fused_chunks")
-            ordinal = int(self._metrics.get("chunks"))
-            self._spans.record("dispatch", t_d0, t_disp, chunk=ordinal)
+            if self._metrics.get("first_dispatch_s") is None:
+                self._metrics.set("first_dispatch_s",
+                                  t_disp - self._spawn_t0)
             inflight.append((ordinal, stats_d, self._h_pulled,
                              int(grow_limit), hcap, t_disp))
 
@@ -1394,36 +1402,26 @@ class TpuChecker(HostChecker):
                     hcap_d: int, t_disp: float) -> set:
             """Consume one chunk's stats vector; returns the host
             actions it demands (handled once the pipeline is drained)."""
-            nonlocal seed_ovf, fault_attempt, spill_attempt, \
-                corruption_attempt
-            with self._timed("sync_stall"):
-                # ONE transfer for everything the host reads per chunk
-                # (scalars + the representative window when host props
-                # are on): each transfer is a sync — routed through
-                # the fault hook + watchdog deadline
-                stats = self._materialize_stats(stats_d, ordinal,
-                                                t_disp=t_disp)
-            # device-time attribution from the completed pull
-            timing = self._pull_timing
-            if timing is not None:
-                self._metrics.add_time("device_s", timing[0])
-                self._metrics.add_time("xfer_s", timing[1])
-            # span twins: device (dispatch->ready) and xfer (ready->
-            # materialized) as INTERVALS — under pipelining the device
-            # span overlaps the PREVIOUS chunk's host span, which is
-            # exactly what the attribution sweep needs to see
-            stamps = getattr(self, "_pull_stamps", None)
-            if stamps is not None:
-                self._spans.record("device", t_disp, stamps[0],
-                                   chunk=ordinal)
-                self._spans.record("xfer", stamps[0], stamps[1],
-                                   chunk=ordinal)
+            nonlocal fault_attempt, spill_attempt
+            # ONE transfer for everything the host reads per chunk
+            # (scalars + the representative window when host props are
+            # on): each transfer is a sync — routed through the fault
+            # hook + watchdog deadline
+            stats = self._materialize_stats(stats_d, ordinal,
+                                            t_disp=t_disp)
             # a successful sync proves the backend is alive: the retry
             # budget bounds CONSECUTIVE faults, not lifetime hiccups
             # (and the spill budget CONSECUTIVE unproductive spills)
             fault_attempt = 0
             spill_attempt = 0
-            t0 = time.perf_counter()
+            # the host's side of the chunk: overlapped when chunk N+1
+            # is in flight, the pipeline bubble when nothing is
+            with self._spans.span("decode", chunk=ordinal):
+                return decode(ordinal, stats, h_base, grow_limit, hcap_d)
+
+        def decode(ordinal: int, stats, h_base: int, grow_limit: int,
+                   hcap_d: int) -> set:
+            nonlocal seed_ovf, corruption_attempt
             acts: set = set()
             (q_head, q_tail, log_n, gen, ovf, xovf, kovf, h_n, hovf,
              vmax, dmax, rmax, e_n, pdh, prb) = (
@@ -1448,8 +1446,7 @@ class TpuChecker(HostChecker):
                 # queue/log suffixes are append-only, so gathering them
                 # from the LIVE carry — possibly a later in-flight
                 # chunk's future — reads exactly the committed rows)
-                with self._spans.span("host_probe", chunk=ordinal), \
-                        self._timed("shadow"):
+                with self._spans.span("shadow", chunk=ordinal):
                     prev = shadow.log_n[0]
                     q_new = gather_rows(carry.q, np.arange(
                         n_init + prev, n_init + log_n, dtype=np.int32))
@@ -1551,11 +1548,7 @@ class TpuChecker(HostChecker):
                     vmax=vmax, dmax=dmax,
                     # cross-chunk ring hits this chunk (fused+cc only;
                     # trace_report's fused summary totals them)
-                    cc_hits=(cch if cc_cap else None),
-                    # dispatch->ready / ready->materialized split (see
-                    # _materialize_stats: device compute vs transfer)
-                    device_s=(round(timing[0], 6) if timing else None),
-                    xfer_s=(round(timing[1], 6) if timing else None))
+                    cc_hits=(cch if cc_cap else None))
             disc_fps = _combine64(disc_hi, disc_lo)
             for i, prop in enumerate(properties):
                 if i in host_prop_idx:
@@ -1588,8 +1581,7 @@ class TpuChecker(HostChecker):
                 # same order as the synchronous path.
                 fresh = h_n - self._h_pulled
                 if fresh > 0:
-                    with self._spans.span("props", chunk=ordinal), \
-                            self._timed("posthoc"):
+                    with self._spans.span("props", chunk=ordinal):
                         win = stats[tail0 + width3:].reshape(
                             (HIST_WINDOW, -1))
                         offset = self._h_pulled - h_base
@@ -1614,12 +1606,6 @@ class TpuChecker(HostChecker):
                         h_n=max(hgrow_pend["h_n"], h_n))
                 else:
                     self._hscan_tail = q_tail
-            t_host_end = time.perf_counter()
-            self._metrics.add_time("host_overlap", t_host_end - t0)
-            # the umbrella host span (stats decode + shadow fold +
-            # inline props): overlapped when chunk N+1 is in flight,
-            # the pipeline bubble when nothing is
-            self._spans.record("host", t0, t_host_end, chunk=ordinal)
             if kovf:
                 # resize data for the drained handler; skip the exit
                 # checks exactly like the synchronous retry `continue`
@@ -1655,7 +1641,7 @@ class TpuChecker(HostChecker):
             nonlocal carry, hcap, chunk_fn
             h_n = hgrow_pend["h_n"]
             q_tail = cur["q_tail"]
-            with self._timed("hgrow"):
+            with self._spans.span("hgrow"):
                 while True:
                     new_hcap = self._posthoc_cap
                     while new_hcap * self._grow_at <= h_n:
@@ -1729,7 +1715,7 @@ class TpuChecker(HostChecker):
         def handle_egrow() -> None:
             # cross-edge log full: quadruple it and resume
             nonlocal carry, chunk_fn, ecap
-            with self._timed("grow"):
+            with self._spans.span("grow"):
                 new_elog = jnp.zeros((ecap * 4, 4), jnp.uint32)
                 new_elog = jax.lax.dynamic_update_slice(
                     new_elog, carry.elog, (0, 0))
@@ -1741,7 +1727,7 @@ class TpuChecker(HostChecker):
 
         def handle_grow() -> None:
             nonlocal carry, chunk_fn, qcap
-            with self._timed("grow"):
+            with self._spans.span("grow"):
                 carry, qcap = self._grow_device(carry, qcap, n_init,
                                                 headroom, insert_fn)
             self._metrics.inc("grows")
@@ -1805,7 +1791,7 @@ class TpuChecker(HostChecker):
                     f"the device table (capacity {self._capacity}) "
                     "under its growth budget"), shadow, discoveries)
             warn_spill_eventually()
-            with self._timed("spill"):
+            with self._spans.span("spill"):
                 mask = np.zeros((1 << SPILL_PREFIX_BITS,), bool)
                 mask[sorted(shadow.evicted_prefixes)] = True
                 khi, klo, ecount_d = _evict_jit()(
@@ -1822,7 +1808,7 @@ class TpuChecker(HostChecker):
                 qcap = self._device_qcap(n_init, headroom)
                 hcap = (self._posthoc_cap
                         if self._host_props and want_reps_now() else 0)
-                with self._timed("seed"):
+                with self._spans.span("seed"):
                     carry = seed_carry(
                         model, qcap, self._capacity, init_rows2,
                         np.asarray(ebs, np.uint32),
@@ -1905,7 +1891,7 @@ class TpuChecker(HostChecker):
                     if self._host_props and want_reps_now() else 0)
             if self._sound:
                 ecap = max(ecap, self._capacity)
-            with self._timed("seed"):
+            with self._spans.span("seed"):
                 carry = seed_carry(
                     model, qcap, self._capacity, init_rows2,
                     np.asarray(ebs, np.uint32),
@@ -2111,95 +2097,97 @@ class TpuChecker(HostChecker):
                         delay=round(recover_delay, 3),
                         error=f"{type(exc).__name__}: {exc}",
                         device=blamed)
-        q_size = cur["q_size"]
-        q_tail, log_n, e_n = cur["q_tail"], cur["log_n"], cur["e_n"]
+        # the engine's tail after its last chunk, up to done
+        with self._spans.span("finish"):
+            q_size = cur["q_size"]
+            q_tail, log_n, e_n = cur["q_tail"], cur["log_n"], cur["e_n"]
 
-        if (self._pause_event.is_set()
-                and not self._cancel_event.is_set()
-                and q_size > 0
-                and len(discoveries) < prop_count
-                and not (target is not None
-                         and self._state_count >= target)):
-            # pause exit (the run did NOT finish): the pipeline drained
-            # above; gather the pending frontier — the shadow holds it
-            # when resilience is on, otherwise pull it from the live
-            # carry exactly like the resumable-frontier path — and land
-            # the resume_from-loadable pause checkpoint
-            if shadow is not None:
-                p_rows, p_ebs, p_fps = shadow.pending()
-            else:
-                # complete the host mirror from the device log first:
-                # the checkpoint needs the full (fp -> parent) record
-                self._mirror_carry = (carry.log, carry.log_n)
-                self._ensure_mirror()
+            if (self._pause_event.is_set()
+                    and not self._cancel_event.is_set()
+                    and q_size > 0
+                    and len(discoveries) < prop_count
+                    and not (target is not None
+                             and self._state_count >= target)):
+                # pause exit (the run did NOT finish): the pipeline drained
+                # above; gather the pending frontier — the shadow holds it
+                # when resilience is on, otherwise pull it from the live
+                # carry exactly like the resumable-frontier path — and land
+                # the resume_from-loadable pause checkpoint
+                if shadow is not None:
+                    p_rows, p_ebs, p_fps = shadow.pending()
+                else:
+                    # complete the host mirror from the device log first:
+                    # the checkpoint needs the full (fp -> parent) record
+                    self._mirror_carry = (carry.log, carry.log_n)
+                    self._ensure_mirror()
+                    head = int(jax.device_get(carry.q_head))
+                    tail = int(jax.device_get(carry.q_tail))
+                    width = model.packed_width
+                    pend = np.asarray(jax.device_get(carry.q[head:tail]))
+                    p_rows = pend[:, :width]
+                    p_ebs = pend[:, width]
+                    p_fps = _combine64(pend[:, width + 1],
+                                       pend[:, width + 2])
+                self._write_pause_checkpoint(p_rows, p_ebs, p_fps,
+                                             discoveries)
+                self._discovery_fps.update(discoveries)
+                return
+
+            if self._sound and q_size == 0 and self._resume_path is not None:
+                import warnings
+                warnings.warn(
+                    "resume_from() + sound_eventually(): the post-exhaustion "
+                    "lasso sweep is SKIPPED on resumed runs (the "
+                    "pre-checkpoint subgraph's edges are not in this run's "
+                    "device logs), so liveness cycles entered through "
+                    "pre-checkpoint states go unreported. Re-run without "
+                    "resume_from() for a cycle-complete liveness verdict.",
+                    RuntimeWarning, stacklevel=2)
+            if (self._sound and q_size == 0 and self._resume_path is None
+                    and not self._symmetry
+                    and not self._cancel_event.is_set()):
+                # (not under symmetry: a cross-branch cycle witness cannot
+                # be replayed through concrete orbit members — the host DFS
+                # disables its sweep the same way, dfs.py)
+                # full exhaustion under sound mode: run the shared lasso
+                # sweep (checker/lasso.py) over the node graph rebuilt from
+                # the device logs — insert edges from the main log, cross
+                # edges (dedup hits with pending bits) from the round-5 edge
+                # log. Cycles entered via cross edges into explored branches
+                # are liveness counterexamples neither the per-row flush nor
+                # the reference can see. Skipped on resume: the
+                # pre-checkpoint subgraph's edges are not in this run's logs.
+                with self._spans.span("lasso"):
+                    if shadow is not None:
+                        # after a mid-run recovery the device logs cover
+                        # only the last epoch; the shadow spans the run
+                        self._shadow_lasso_sweep(shadow, int(full_ebits),
+                                                 discoveries)
+                    else:
+                        self._device_lasso_sweep(carry, int(q_tail),
+                                                 int(log_n), int(e_n),
+                                                 n_init, int(full_ebits),
+                                                 discoveries)
+
+            if self._tpu_options.get("resumable"):
+                # pull the pending frontier eagerly so save() needs no pinned
+                # device buffers; the queue's cached fps (canonical under
+                # symmetry) ride along so resume never recomputes them
                 head = int(jax.device_get(carry.q_head))
                 tail = int(jax.device_get(carry.q_tail))
                 width = model.packed_width
                 pend = np.asarray(jax.device_get(carry.q[head:tail]))
-                p_rows = pend[:, :width]
-                p_ebs = pend[:, width]
-                p_fps = _combine64(pend[:, width + 1],
-                                   pend[:, width + 2])
-            self._write_pause_checkpoint(p_rows, p_ebs, p_fps,
-                                         discoveries)
+                self._resume_frontier = (
+                    pend[:, :width].copy(), pend[:, width].copy(),
+                    _combine64(pend[:, width + 1], pend[:, width + 2]))
+            # the mirror (fp -> parent fp) stays device-resident until someone
+            # needs it (path reconstruction, checkpointing): the log pull is
+            # pure host-link cost, pointless for count-only runs. Keep only
+            # the log fields so the table/queue HBM is freed promptly. With
+            # the shadow on, the host mirror is already complete — no pull.
+            self._mirror_carry = (None if shadow is not None
+                                  else (carry.log, carry.log_n))
             self._discovery_fps.update(discoveries)
-            return
-
-        if self._sound and q_size == 0 and self._resume_path is not None:
-            import warnings
-            warnings.warn(
-                "resume_from() + sound_eventually(): the post-exhaustion "
-                "lasso sweep is SKIPPED on resumed runs (the "
-                "pre-checkpoint subgraph's edges are not in this run's "
-                "device logs), so liveness cycles entered through "
-                "pre-checkpoint states go unreported. Re-run without "
-                "resume_from() for a cycle-complete liveness verdict.",
-                RuntimeWarning, stacklevel=2)
-        if (self._sound and q_size == 0 and self._resume_path is None
-                and not self._symmetry
-                and not self._cancel_event.is_set()):
-            # (not under symmetry: a cross-branch cycle witness cannot
-            # be replayed through concrete orbit members — the host DFS
-            # disables its sweep the same way, dfs.py)
-            # full exhaustion under sound mode: run the shared lasso
-            # sweep (checker/lasso.py) over the node graph rebuilt from
-            # the device logs — insert edges from the main log, cross
-            # edges (dedup hits with pending bits) from the round-5 edge
-            # log. Cycles entered via cross edges into explored branches
-            # are liveness counterexamples neither the per-row flush nor
-            # the reference can see. Skipped on resume: the
-            # pre-checkpoint subgraph's edges are not in this run's logs.
-            with self._timed("lasso"):
-                if shadow is not None:
-                    # after a mid-run recovery the device logs cover
-                    # only the last epoch; the shadow spans the run
-                    self._shadow_lasso_sweep(shadow, int(full_ebits),
-                                             discoveries)
-                else:
-                    self._device_lasso_sweep(carry, int(q_tail),
-                                             int(log_n), int(e_n),
-                                             n_init, int(full_ebits),
-                                             discoveries)
-
-        if self._tpu_options.get("resumable"):
-            # pull the pending frontier eagerly so save() needs no pinned
-            # device buffers; the queue's cached fps (canonical under
-            # symmetry) ride along so resume never recomputes them
-            head = int(jax.device_get(carry.q_head))
-            tail = int(jax.device_get(carry.q_tail))
-            width = model.packed_width
-            pend = np.asarray(jax.device_get(carry.q[head:tail]))
-            self._resume_frontier = (
-                pend[:, :width].copy(), pend[:, width].copy(),
-                _combine64(pend[:, width + 1], pend[:, width + 2]))
-        # the mirror (fp -> parent fp) stays device-resident until someone
-        # needs it (path reconstruction, checkpointing): the log pull is
-        # pure host-link cost, pointless for count-only runs. Keep only
-        # the log fields so the table/queue HBM is freed promptly. With
-        # the shadow on, the host mirror is already complete — no pull.
-        self._mirror_carry = (None if shadow is not None
-                              else (carry.log, carry.log_n))
-        self._discovery_fps.update(discoveries)
 
     def _device_lasso_sweep(self, carry, q_tail: int, log_n: int,
                             e_n: int, n_init: int, full_mask: int,
@@ -2572,7 +2560,7 @@ class TpuChecker(HostChecker):
         log_d, log_n_d = mirror
         import jax
 
-        with self._spans.span("mirror"), self._timed("mirror_pull"):
+        with self._spans.span("mirror.pull"):
             log_n = int(jax.device_get(log_n_d))
             if self._trace:
                 self._trace.emit("mirror_pull", n=log_n)
@@ -2583,6 +2571,7 @@ class TpuChecker(HostChecker):
             n = min(_bucket(log_n), log_d.shape[0])
             _slice, _take, take_rows_fn, _take2 = _level_helpers()
             log = np.asarray(jax.device_get(take_rows_fn(log_d, n)))
+        with self._spans.span("mirror.index"):
             child = _combine64(log[:log_n, 0], log[:log_n, 1])
             parent = _combine64(log[:log_n, 2], log[:log_n, 3])
             self._generated.update(zip(child.tolist(), parent.tolist()))
@@ -3078,18 +3067,20 @@ class TpuChecker(HostChecker):
 
     def _reconstruct_path(self, fp: int) -> Path:
         self._ensure_mirror()
-        if not (self._symmetry or self._sound):
-            return super()._reconstruct_path(fp)
-        # the mirror chain is canonical; translate each node to the
-        # ORIGINAL explored state's fingerprint (recorded device-side), so
-        # the replayed trace is a concrete path — the DFS engine's
-        # enqueue-original rule (`dfs.rs:260-285`) carried to the mirror
-        fingerprints: deque = deque()
-        nxt = fp
-        while nxt in self._generated:
-            fingerprints.appendleft(self._orig_of.get(nxt, nxt))
-            parent = self._generated[nxt]
-            if parent is None:
-                break
-            nxt = parent
-        return Path.from_fingerprints(self._model, fingerprints)
+        with self._spans.span("paths"):
+            if not (self._symmetry or self._sound):
+                return super()._reconstruct_path(fp)
+            # the mirror chain is canonical; translate each node to the
+            # ORIGINAL explored state's fingerprint (recorded
+            # device-side), so the replayed trace is a concrete path —
+            # the DFS engine's enqueue-original rule
+            # (`dfs.rs:260-285`) carried to the mirror
+            fingerprints: deque = deque()
+            nxt = fp
+            while nxt in self._generated:
+                fingerprints.appendleft(self._orig_of.get(nxt, nxt))
+                parent = self._generated[nxt]
+                if parent is None:
+                    break
+                nxt = parent
+            return Path.from_fingerprints(self._model, fingerprints)

@@ -9,6 +9,9 @@
   (zero-cost :data:`~stateright_tpu.obs.trace.NULL_TRACE` when fully
   off), with per-event requirements pinned by
   :data:`~stateright_tpu.obs.trace.EVENT_SCHEMA`.
+* :class:`~stateright_tpu.obs.spans.SpanRecorder` — each host phase as
+  one interval: the span ring, the ``span_s.<phase>`` timer, and an
+  ``sr.<phase>`` annotation in any ``jax.profiler`` trace.
 * :class:`~stateright_tpu.obs.recorder.FlightRecorder` — the always-on
   bounded ring of recent trace events behind every checker, dumped as
   a JSONL postmortem artifact when a run dies (README.md
@@ -29,11 +32,12 @@ not re-exported here — so ``import stateright_tpu.obs`` stays light.)
 
 from .artifacts import (ARTIFACT_NAMES, apply_artifact_dir,
                         artifact_paths)
-from .metrics import GAUGES, GLOSSARY, MAXIMA, Metrics, MetricsRing
+from .metrics import (GAUGES, GLOSSARY, MAXIMA, Metrics, MetricsRing,
+                      gc_totals)
 from .recorder import FlightRecorder, default_flight_path
-from .spans import (DEVICE_SPANS, SpanRecorder, analyze,
-                    attach_attribution, ranked, shard_imbalance,
-                    spans_from_events, top_stalls)
+from .spans import (DEVICE_SPANS, SpanRecorder, analyze, annotate,
+                    attach_attribution, loop_spans, ranked,
+                    shard_imbalance, spans_from_events, top_stalls)
 from .trace import (EVENT_SCHEMA, NULL_TRACE, NullTrace, RunTrace,
                     emit_trace_header, fault_info, identity_fields,
                     make_trace, new_run_id, validate_event)
@@ -53,13 +57,16 @@ __all__ = [
     "RunTrace",
     "SpanRecorder",
     "analyze",
+    "annotate",
     "apply_artifact_dir",
     "artifact_paths",
     "attach_attribution",
     "default_flight_path",
     "emit_trace_header",
     "fault_info",
+    "gc_totals",
     "identity_fields",
+    "loop_spans",
     "make_trace",
     "new_run_id",
     "ranked",

@@ -10,21 +10,27 @@ docstring references instead of restating.
 
 Key-name conventions:
 
-* phase timers are wall-seconds and use bare phase names (``dispatch``,
-  ``sync_stall``, ``grow``);
+* host phases are spans (``obs/spans.py``): their wall-seconds land
+  under ``span_s.<phase>`` (``span_s.dispatch``, ``span_s.grow``); the
+  only bare timers left are ``search`` (a host engine's whole run) and
+  the ``*_s`` measurements;
 * counters are integral and plural where natural (``chunks``,
   ``grows``);
 * observed maxima keep their engine names (``vmax``/``dmax``/``rmax``).
 
 Engines that historically used divergent keys now agree: the sharded
-engine's growth pass reports BOTH the ``grow`` timer and the ``grows``
-counter, exactly like the single-chip engine (which gained ``grows``);
-``hgrow`` remains a distinct key because it times a different structure
-(the host-property history table), not a naming drift.
+engine's growth pass reports BOTH the ``span_s.grow`` timer and the
+``grows`` counter, exactly like the single-chip engine;
+``span_s.hgrow`` remains a distinct key because it times a different
+structure (the host-property history table), not a naming drift.
+
+:func:`gc_totals` is the process-wide garbage-collector clock behind
+``profile()``'s ``gc_s`` / ``gc_collections``.
 """
 
 from __future__ import annotations
 
+import gc
 import time
 from contextlib import contextmanager
 from typing import Dict, Optional
@@ -34,32 +40,88 @@ from typing import Dict, Optional
 #: the phases they run). Timers are wall-clock seconds; counters and
 #: maxima are integers.
 GLOSSARY: Dict[str, str] = {
-    # --- device chunk-loop phase timers (single-chip + sharded) -------
-    "seed": "building + inserting the initial frontier/table buffers",
-    "dispatch": "host time launching chunk programs (async; small "
-                "unless tracing/compiling)",
-    "sync_stall": "time blocked materializing a chunk's stats vector — "
-                  "the device round trip the pipeline hides host work "
-                  "under; if it dominates, the device is the "
-                  "bottleneck (try a larger fmax/chunk_steps)",
-    "host_overlap": "host-side consumption of a chunk's outputs (stats "
-                    "decode, batched host-property evaluation, "
-                    "discovery bookkeeping) that overlaps the NEXT "
-                    "in-flight chunk under tpu_options(pipeline=True)",
-    "grow": "hash-table/queue/log growth passes (rebuild + re-insert)",
-    "hgrow": "host-property history-table growth (re-seed + rescan)",
-    "posthoc": "host-property evaluation over pulled representatives",
-    "lasso": "post-exhaustion SCC sweep (sound_eventually)",
-    "mirror_pull": "pulling the device (child, parent) log into the "
-                   "host mirror",
-    "visit": "post-hoc CheckerVisitor replay over the reached set",
-    "shadow": "maintaining the host-side authoritative state "
-              "(checker/resilience.py) — per-chunk queue/log suffix "
-              "gathers while retry/autosave/tiering is enabled",
-    "spill": "visited-set spill passes (drain + cold-range eviction + "
-             "epoch re-seed) taken when table growth would exceed the "
-             "HBM budget (tpu_options(max_capacity=...)); includes "
-             "the embedded re-seed time",
+    # --- host phase spans (obs/spans.py SpanRecorder.span): seconds
+    # per span name, accumulated at record time; each phase is also an
+    # interval on the span timeline and, annotated "sr.<name>", in any
+    # jax.profiler trace of the run --------------------------------------
+    "span_s.spawn": "CheckerBuilder.spawn_tpu() whole: the builder, the "
+                    "host racer's set-up and the device engine's "
+                    "construction",
+    "span_s.init": "constructing the device engine (TpuChecker / "
+                   "ShardedTpuChecker)",
+    "span_s.seed": "building + inserting the initial frontier/table "
+                   "buffers (fresh runs, post-fault re-seeds, spill "
+                   "epochs)",
+    "span_s.program": "building or looking up a chunk program, and the "
+                      "first call of each new one (trace + compile, or "
+                      "a compile-cache load)",
+    "span_s.dispatch": "launching chunk programs (async; small unless "
+                       "a program is new)",
+    "span_s.device": "dispatch to stats-ready of each chunk, recorded "
+                     "after the fact: the chunk program executing (an "
+                     "upper bound under the pipeline, where host work "
+                     "overlaps it)",
+    "span_s.sync": "host blocked in block_until_ready on a chunk's "
+                   "stats (inside the device span; the attribution "
+                   "sweep skips it)",
+    "span_s.pull": "the device->host transfer of a chunk's stats "
+                   "vector (one sync per chunk)",
+    "span_s.decode": "host consumption of one chunk's stats: decode, "
+                     "counters, discovery bookkeeping, with the shadow "
+                     "fold and host properties nested inside",
+    "span_s.shadow": "maintaining the host-side authoritative state "
+                     "(checker/resilience.py) — per-chunk queue/log "
+                     "suffix gathers while retry/autosave/tiering is "
+                     "enabled",
+    "span_s.props": "host-property evaluation (e.g. linearizability) "
+                    "over the chunk's new representatives",
+    "span_s.grow": "hash-table/queue/log growth passes (rebuild + "
+                   "re-insert)",
+    "span_s.hgrow": "host-property history-table growth (re-seed + "
+                    "rescan)",
+    "span_s.spill": "visited-set spill passes (drain + cold-range "
+                    "eviction + epoch re-seed) taken when table growth "
+                    "would exceed the HBM budget "
+                    "(tpu_options(max_capacity=...))",
+    "span_s.promote": "elastic scale-up passes: widening the mesh and "
+                      "re-seeding the sharded carry at the new width "
+                      "(promote_step, parallel/engine.py)",
+    "span_s.exchange": "the timed cross-host collective at mesh init "
+                       "(multi-process meshes)",
+    "span_s.finish": "the engine's tail after its last chunk: the "
+                     "lasso sweep, the resumable-frontier pull, "
+                     "publishing discoveries",
+    "span_s.lasso": "post-exhaustion SCC sweep (sound_eventually)",
+    "span_s.visit": "post-hoc CheckerVisitor replay over the reached "
+                    "set",
+    "span_s.pause": "engine-level pause: draining the pipeline and "
+                    "writing the resume_from-loadable pause checkpoint "
+                    "(Checker.request_pause; the step-driver boundary)",
+    "span_s.mirror.pull": "the verdict read's device_get of the search "
+                          "log (child, parent) rows",
+    "span_s.mirror.index": "building the host mirror (fp -> parent "
+                           "dict, and the original-fp map under "
+                           "symmetry) from the pulled log",
+    "span_s.paths": "reconstructing discovery paths from the mirror "
+                    "(parent walk + host replay)",
+    "span_s.race.host": "the budgeted host racer's run on its own "
+                        "thread (raced spawn_tpu runs)",
+    "span_s.race.decide": "winning engine done -> race decision "
+                          "published (the decider's poll), recorded "
+                          "after the fact",
+    "spans_dropped": "spans recorded but evicted from the bounded span "
+                     "ring (their span_s.* seconds still count; the "
+                     "attribution sees only the ring)",
+    "first_dispatch_s": "spawn_tpu() entry to the end of the first "
+                        "chunk dispatch: the fixed cost before the "
+                        "device works on this check",
+    "race_losers_alive": "retired racer threads (cancelled losers of "
+                         "earlier raced runs) still alive when profile() "
+                         "is read (gauge)",
+    "gc_s": "seconds the cyclic garbage collector ran, process-wide, "
+            "between the checker's construction and this profile() read",
+    "gc_collections": "cyclic garbage collections, process-wide, over "
+                      "the same interval as gc_s",
     # --- counters ----------------------------------------------------
     "chunks": "completed chunk dispatches (each up to chunk_steps "
               "frontier levels)",
@@ -87,9 +149,6 @@ GLOSSARY: Dict[str, str] = {
                 "controller) — the exact mirror of a degradation rung, "
                 "so a run that degraded around a transient fault can "
                 "climb back up the ladder",
-    "promote": "elastic scale-up passes: widening the mesh and "
-               "re-seeding the sharded carry at the new width "
-               "(promote_step, parallel/engine.py)",
     "autosaves": "resilience checkpoints written (periodic "
                  "tpu_options(autosave=...) snapshots plus the "
                  "exhausted-retries and capacity-terminal writes)",
@@ -216,16 +275,6 @@ GLOSSARY: Dict[str, str] = {
                    "them)",
     # --- host search timers -------------------------------------------
     "search": "host-engine search loop wall time",
-    # --- device-time attribution (chunk loops) ------------------------
-    "device_s": "estimated device-execution seconds: the dispatch-to-"
-                "stats-ready interval summed over chunks. Splits the "
-                "old host-side sync_stall conflation of compute and "
-                "transfer; under the pipelined loop host work overlaps "
-                "this interval, so it is an upper bound on pure device "
-                "compute (per-chunk values ride the chunk trace event)",
-    "xfer_s": "estimated device->host transfer seconds: stats-ready-to-"
-              "materialized, summed over chunks (the transfer "
-              "component of each sync)",
     # --- flight recorder (obs/recorder.py) -----------------------------
     "recorder_dumps": "flight-recorder artifacts written (the bounded "
                       "always-on event ring dumped as JSONL on error, "
@@ -233,9 +282,6 @@ GLOSSARY: Dict[str, str] = {
                       "degradation rungs; see the recorder_dump trace "
                       "event for the path)",
     # --- pausable runs + the job service (stateright_tpu/service) ------
-    "pause": "engine-level pause: draining the pipeline and writing "
-             "the resume_from-loadable pause checkpoint "
-             "(Checker.request_pause; the step-driver boundary)",
     "pauses": "pause checkpoints written (a paused run exits its "
               "engine loop cleanly; resumption is a fresh checker via "
               "resume_from — possibly on a different mesh width, which "
@@ -327,18 +373,19 @@ GLOSSARY: Dict[str, str] = {
     # --- span attribution (obs/spans.py; attached by profile(), NOT
     # stored in the registry — merge() would sum fractions) -------------
     "attribution": "overlap-aware wall-time split (dict: bucket -> "
-                   "seconds, largest first) from the run's span "
-                   "timeline — device-only buckets (device/xfer/"
+                   "seconds, largest first) of the chunk loop's span "
+                   "timeline, first dispatch to last decode — "
+                   "device-only buckets (device/pull/"
                    "exchange) are device-bound, 'overlap' is host work "
                    "hidden under an in-flight chunk (free), 'host:*' "
                    "is host work blocking an idle device (the pipeline "
                    "bubble), 'idle' is dead air; rows sum to wall "
                    "(tools/stall_report.py renders the ranked table)",
     "idle_s": "wall seconds with NO span active (neither device nor "
-              "host side) inside the run's span extent — dead air "
+              "host side) inside the chunk loop — dead air "
               "between chunks (from the attribution sweep; not a "
               "registry counter)",
-    "bubble_frac": "fraction of span-extent wall time where the host "
+    "bubble_frac": "fraction of the chunk loop's wall time where the host "
                    "blocked the critical path (host:* buckets) or "
                    "nothing ran (idle) — the addressable pipeline "
                    "bubble; 0 means every host second hid under device "
@@ -354,7 +401,7 @@ GAUGES = frozenset({
     "shard_balance", "host_tier_keys", "queue_depth", "lanes",
     "hosts", "procs", "fused_unsupported", "cc_dedup_capacity",
     "pool_busy_frac", "jobs_per_min", "burnin_frac", "flex_width",
-    "quarantined",
+    "quarantined", "first_dispatch_s", "race_losers_alive",
 })
 
 #: keys merged by maximum (observed buffer-sizing maxima).
@@ -482,3 +529,28 @@ class MetricsRing:
         from ..checker.explorer import metrics_view
         self.sample_until(lambda: metrics_view(checker),
                           checker.is_done)
+
+
+#: the process-wide garbage-collector clock: [seconds, collections,
+#: perf_counter stamp of the collection in progress]
+_GC = [0.0, 0, None]
+
+
+def _gc_clock(phase: str, _info) -> None:
+    if phase == "start":
+        _GC[2] = time.perf_counter()
+    elif _GC[2] is not None:
+        _GC[0] += time.perf_counter() - _GC[2]
+        _GC[1] += 1
+        _GC[2] = None
+
+
+def gc_totals() -> tuple:
+    """``(seconds, collections)`` the cyclic garbage collector has run
+    in this process since the clock was installed — on the first call,
+    as one ``gc.callbacks`` entry. A collection stops every thread (it
+    holds the GIL), so its seconds are a stall of whatever the host was
+    doing; callers report deltas between two readings."""
+    if _gc_clock not in gc.callbacks:
+        gc.callbacks.append(_gc_clock)
+    return _GC[0], _GC[1]

@@ -93,7 +93,9 @@ def render(rows: Iterable[Tuple[Mapping, Mapping]],
                 value = int(value)
             if not isinstance(value, (int, float)):
                 continue  # string gauges (engine=...) are JSON-only
-            name = f"{prefix}_{key}"
+            # span timers are dotted (span_s.mirror.pull): a dot is
+            # no name character in the exposition format
+            name = f"{prefix}_{key.replace('.', '_')}"
             if not _NAME_RE.match(name):
                 continue  # defensively skip unrenderable keys
             ident = (name, tuple(sorted(lab.items())))

@@ -1,14 +1,16 @@
 """Overlap-aware span profiler: intervals, not durations.
 
-The flat phase timers (``dispatch``/``sync_stall``/``host_overlap``,
-``device_s``/``xfer_s``) double-count under the double-buffered
-pipeline: chunk N+1's device compute deliberately overlaps chunk N's
-host processing, so the timer sum exceeds wall time and ratios between
-phases are not actionable. The fix is span-structured tracing
-(Dapper-style) plus critical-path attribution (Coz-style): record each
-phase as an **interval** ``[t0, t1)`` on the shared trace clock, then
-sweep the merged timeline and attribute every wall-clock segment to
-the one side that exclusively blocks it.
+Every host phase of the device engines is ONE call,
+``SpanRecorder.span(name)``, which records the phase as an **interval**
+``[t0, t1)`` on the run trace's clock, adds its seconds to the
+``span_s.<name>`` timer of the run's metrics registry, emits the
+``span`` trace event when a sink is set, and wraps the block in a
+``jax.profiler.TraceAnnotation("sr.<name>")`` — so any ``jax.profiler``
+trace (``tpu_options(profile_dir=...)``, a benchmark's traced run)
+shows the phase on the same clock as the device ops. Intervals the
+host only learns after the fact (``device``: dispatch to stats-ready;
+``race.decide``) are recorded with :meth:`SpanRecorder.record` and stay
+out of the profiler trace, which shows the device side itself.
 
 * :class:`SpanRecorder` — bridges the engines' ``time.perf_counter()``
   stamps onto the trace clock (``RunTrace`` events use
@@ -18,46 +20,79 @@ the one side that exclusively blocks it.
 * :func:`analyze` — the overlap-aware critical-path sweep. Wall time
   splits into exclusively-attributed buckets that **sum to wall**:
   device-only-busy segments are device-bound (named by the innermost
-  device span: ``device``/``xfer``/``exchange``), host-busy-while-a-
+  device span: ``device``/``pull``/``exchange``), host-busy-while-a-
   chunk-is-in-flight is ``overlap`` (free — the pipeline working as
   designed), host-busy-with-nothing-dispatched is the pipeline bubble
   (``host:<phase>``), and nothing-active is ``idle``. The bubble
   fraction (``host:*`` + ``idle`` over wall) is the number the next
-  perf PR attacks.
+  perf PR attacks. :func:`attach_attribution` sweeps the chunk loop
+  only (:func:`loop_spans`); the entry and verdict phases around it
+  report through their ``span_s.*`` timers.
 * :func:`spans_from_events` / :func:`shard_imbalance` — the consumer
-  side shared by ``tools/stall_report.py`` and the ``perf_probe``/
-  ``prof_chunk`` shims: extract spans from a JSONL event stream
-  (optionally wall-anchored for merged fleet timelines) and summarize
-  per-shard work imbalance from ``chunk`` events' per-shard vectors.
+  side used by ``tools/stall_report.py``: extract spans from a JSONL
+  event stream (optionally wall-anchored for merged fleet timelines)
+  and summarize per-shard work imbalance from ``chunk`` events'
+  per-shard vectors.
 
 See README.md § Observability, "How to read a stall".
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, Iterable, List, Optional
 
 #: span names attributed to the DEVICE side of the pipeline; every
-#: other name is host-side work ("idle" is neither — it marks a gap)
-DEVICE_SPANS = frozenset({"device", "xfer", "exchange"})
+#: other name is host-side work ("idle" is neither — it marks a gap).
+DEVICE_SPANS = frozenset({"device", "pull", "exchange"})
 
 #: the gap span (scheduler queue-wait, engine drain): active on
 #: neither side of the sweep
 IDLE_SPAN = "idle"
 
+#: spans in which the host only waits for the device
+#: (``block_until_ready``): no work of their own, so the sweep skips
+#: them as it skips ``idle`` — the ``device`` span covering them
+#: carries the time
+WAIT_SPANS = frozenset({"sync"})
+
+#: phases around the chunk loop: engine set-up, the finish, the verdict
+#: read and the host racer. The loop's attribution leaves them out
+#: (their own ``span_s.*`` timers report them); ``race.host`` runs on
+#: its own thread beside the loop.
+OUTSIDE_LOOP = frozenset({"spawn", "init", "finish", "mirror.pull",
+                          "mirror.index", "paths", "race.host",
+                          "race.decide"})
+
+#: the chunk loop's first and last phases: its attribution window runs
+#: from the first ``dispatch`` to the end of the last ``decode``
+LOOP_FIRST, LOOP_LAST = "dispatch", "decode"
+
+#: prefix of the span names in a ``jax.profiler`` trace
+ANNOTATION_PREFIX = "sr."
+
+
+def annotate(name: str):
+    """``jax.profiler.TraceAnnotation("sr.<name>")``, or a no-op when
+    this process has not imported JAX (then no profiler session can be
+    running, and a host-only engine never pays JAX's import)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return nullcontext()
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+
 
 class SpanRecorder:
     """Collect phase intervals from one engine and mirror them onto
-    its run trace.
+    its run trace, its metrics registry and the profiler trace.
 
-    Engines stamp phases with ``time.perf_counter()`` (the clock the
-    existing ``device_s``/``xfer_s`` estimates already use); trace
-    events carry ``t`` = seconds since the trace's ``monotonic()``
-    anchor. The recorder captures one paired reading of both clocks at
+    Engines stamp phases with ``time.perf_counter()``; trace events
+    carry ``t`` = seconds since the trace's ``monotonic()`` anchor. The
+    recorder captures one paired reading of both clocks at
     construction and converts every stamp, so a span's ``t0``/``t1``
     land on the same axis as every other event in the stream (and on
     the fleet timeline via the run's wall anchor).
@@ -65,11 +100,19 @@ class SpanRecorder:
     The in-memory ring is bounded (``limit``) and always on — a
     traceless run still gets ``profile()['attribution']`` — while the
     ``span`` trace event is only emitted when the trace has a sink.
+    With ``metrics``, each span's seconds accumulate at record time
+    under ``span_s.<name>``, so a wrapped ring loses none of them
+    (``dropped`` counts the spans the ring let go). With ``hold``, the
+    ``span`` events of phases before the run starts (an engine's
+    ``spawn``/``init``) wait for :meth:`release`, so the run's header
+    still leads its trace.
     """
 
-    __slots__ = ("_trace", "_off", "_spans", "_lock")
+    __slots__ = ("_trace", "_off", "_spans", "_lock", "_metrics",
+                 "_recorded", "_held")
 
-    def __init__(self, trace: Any = None, limit: int = 4096):
+    def __init__(self, trace: Any = None, limit: int = 4096,
+                 metrics: Any = None, hold: bool = False):
         pc = time.perf_counter()
         mono = time.monotonic()
         base = getattr(trace, "_t0", None)
@@ -80,6 +123,9 @@ class SpanRecorder:
         self._trace = trace
         self._spans: deque = deque(maxlen=int(limit))
         self._lock = threading.Lock()
+        self._metrics = metrics
+        self._recorded = 0
+        self._held: Optional[List[Dict[str, Any]]] = [] if hold else None
 
     def rel(self, stamp: float) -> float:
         """A ``perf_counter()`` stamp as trace-relative seconds."""
@@ -90,28 +136,45 @@ class SpanRecorder:
         stamps (``t1`` is clamped to ``t0``). Optional identity fields
         (``chunk``, ``shard``, ``lane``, ``job``) ride along; ``None``
         values are dropped so absent identity never pads the stream."""
+        t1 = max(t0, t1)
         span: Dict[str, Any] = {
             "name": name,
             "t0": round(t0 + self._off, 6),
-            "t1": round(max(t0, t1) + self._off, 6),
+            "t1": round(t1 + self._off, 6),
         }
         for key, value in fields.items():
             if value is not None:
                 span[key] = value
         with self._lock:
             self._spans.append(span)
+            self._recorded += 1
+            if self._metrics is not None:
+                self._metrics.add_time("span_s." + name, t1 - t0)
+            if self._held is not None:
+                self._held.append(span)
+                return
         trace = self._trace
         if trace:
             trace.emit("span", **span)
 
+    def release(self) -> None:
+        """Emit the spans held since construction and stop holding."""
+        with self._lock:
+            held, self._held = self._held, None
+        trace = self._trace
+        if trace:
+            for span in held or ():
+                trace.emit("span", **span)
+
     @contextmanager
     def span(self, name: str, **fields):
-        """Record the enclosed block as one span (the interval twin of
-        ``Metrics.timed``); recorded even when the block raises or
-        returns early, so the timeline never loses its tail."""
+        """Record the enclosed block as one span, annotated for the
+        profiler trace; recorded even when the block raises or returns
+        early, so the timeline never loses its tail."""
         t0 = time.perf_counter()
         try:
-            yield
+            with annotate(name):
+                yield
         finally:
             self.record(name, t0, time.perf_counter(), **fields)
 
@@ -119,6 +182,11 @@ class SpanRecorder:
         """Snapshot of the recorded spans (oldest first)."""
         with self._lock:
             return list(self._spans)
+
+    @property
+    def dropped(self) -> int:
+        """Spans recorded but no longer in the ring (it wrapped)."""
+        return self._recorded - len(self._spans)
 
     def __len__(self) -> int:
         return len(self._spans)
@@ -134,6 +202,8 @@ def analyze(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     * device only           -> the innermost device span's name
     * host only             -> ``host:<innermost host span name>``
     * neither               -> ``idle``
+
+    Wait spans (:data:`WAIT_SPANS`) and ``idle`` count on neither side.
 
     "Innermost" is the active span with the latest start, so a
     ``device`` segment nested inside an umbrella span is attributed to
@@ -172,7 +242,8 @@ def analyze(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
             dev = [j for j in active if ivs[j][2] in DEVICE_SPANS]
             host = [j for j in active
                     if ivs[j][2] not in DEVICE_SPANS
-                    and ivs[j][2] != IDLE_SPAN]
+                    and ivs[j][2] != IDLE_SPAN
+                    and ivs[j][2] not in WAIT_SPANS]
             if dev and host:
                 key = "overlap"
             elif dev:
@@ -230,13 +301,35 @@ def top_stalls(attribution: Dict[str, Any], n: int = 3) -> List[list]:
             for name, secs, _share in ranked(attribution)[:n]]
 
 
+def loop_spans(spans: Iterable[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """The chunk loop's spans: those inside the window from the first
+    ``dispatch`` start to the last ``decode`` end, clipped to it, less
+    the phases around the loop (:data:`OUTSIDE_LOOP`). Empty when the
+    spans hold no complete chunk."""
+    spans = list(spans)
+    starts = [s["t0"] for s in spans if s.get("name") == LOOP_FIRST]
+    ends = [s["t1"] for s in spans if s.get("name") == LOOP_LAST]
+    if not starts or not ends:
+        return []
+    lo, hi = min(starts), max(ends)
+    out = []
+    for s in spans:
+        if s.get("name") in OUTSIDE_LOOP:
+            continue
+        t0, t1 = max(s["t0"], lo), min(s["t1"], hi)
+        if t0 < t1:
+            out.append(dict(s, t0=t0, t1=t1))
+    return out
+
+
 def attach_attribution(snapshot: Dict[str, Any],
                        recorder: Optional[SpanRecorder]) -> Dict[str, Any]:
-    """Fold a recorder's attribution into a ``profile()`` snapshot:
-    ``attribution`` (bucket -> seconds, largest first), ``idle_s`` and
-    ``bubble_frac``. Mutates and returns ``snapshot``; a span-less run
-    is left untouched (keys stay absent, not zero)."""
-    spans = recorder.spans() if recorder is not None else []
+    """Fold the attribution of a recorder's chunk loop
+    (:func:`loop_spans`) into a ``profile()`` snapshot: ``attribution``
+    (bucket -> seconds, largest first), ``idle_s`` and ``bubble_frac``.
+    Mutates and returns ``snapshot``; a run with no complete chunk is
+    left untouched (keys stay absent, not zero)."""
+    spans = loop_spans(recorder.spans()) if recorder is not None else []
     if not spans:
         return snapshot
     attr = analyze(spans)

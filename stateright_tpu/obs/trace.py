@@ -221,11 +221,12 @@ EVENT_SCHEMA: Dict[str, frozenset] = {
     "host_join": frozenset({"host"}),
     "host_drop": frozenset({"host"}),
     # one phase INTERVAL on the trace clock (obs/spans.py): `name` is
-    # the phase (dispatch / device / xfer / host / host_probe / mirror
-    # / exchange / props / idle), `t0`/`t1` its trace-relative bounds
-    # — unlike the flat phase timers these compose under the pipeline:
-    # the overlap-aware sweep (spans.analyze, tools/stall_report.py)
-    # attributes wall time only where a phase is the unique blocker.
+    # the phase (the `span_s.<name>` keys of obs.GLOSSARY list them:
+    # dispatch / device / sync / pull / decode / shadow / props /
+    # mirror.pull / ...), `t0`/`t1` its trace-relative bounds — they
+    # compose under the pipeline: the overlap-aware sweep
+    # (spans.analyze, tools/stall_report.py) attributes wall time only
+    # where a phase is the unique blocker.
     # Optional identity fields (`chunk`, `shard`, `lane`, `job`) ride
     # along when the emitting loop has them
     "span": frozenset({"name", "t0", "t1"}),

@@ -20,7 +20,8 @@ remain unsupported (a host feature; use the per-level engine).
 
 from __future__ import annotations
 
-from typing import Dict, List
+from contextlib import nullcontext
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -34,8 +35,9 @@ from .sharded import (MAX_MESH_SHARDS, ShardedCarry,
 class ShardedTpuChecker(TpuChecker):
     """Fingerprint-prefix-sharded BFS over a device mesh."""
 
-    def __init__(self, builder: CheckerBuilder):
-        super().__init__(builder)
+    def __init__(self, builder: CheckerBuilder,
+                 spawn_t0: Optional[float] = None):
+        super().__init__(builder, spawn_t0)
         opts = builder.tpu_options_
         self._mesh = opts["mesh"]
         self._axis = str(opts.get("mesh_axis", "shards"))
@@ -269,7 +271,7 @@ class ShardedTpuChecker(TpuChecker):
                 keys_by_shard[owner_of(fp, D)].append(fp)
             table_plan = ([plan_insert_host(b, self._capacity // D)
                            for b in keys_by_shard], keys_by_shard)
-        with self._timed("seed"):
+        with self._spans.span("seed"):
             carry = seed_sharded_carry(model, mesh, axis, qcap,
                                        self._capacity, init_rows,
                                        frontier_fps, seed_ebits,
@@ -312,15 +314,22 @@ class ShardedTpuChecker(TpuChecker):
             z = np.zeros((D * cc_cap,), np.uint32)
             return (jax.device_put(z, sh), jax.device_put(z.copy(), sh))
 
+        # True until a freshly built chunk program's first call, which
+        # traces and compiles it (or loads it from the compile cache)
+        first_call = [False]
+
         def rebuild_chunk(reason: str = "initial"):
             self._metrics.inc("compiles")
             if self._trace:
                 self._trace.emit("compile", reason=reason)
-            fn = build_sharded_chunk_fn(
-                model, mesh, axis, qcap, self._capacity, fmax, kmax,
-                symmetry=self._symmetry, sound=self._sound, kraw=kraw,
-                exchange=exchange, kb=kb, ecap=ecap, fused=fused_on,
-                fused_interpret=fused_interp, cc=cc_cap)
+            with self._spans.span("program"):
+                fn = build_sharded_chunk_fn(
+                    model, mesh, axis, qcap, self._capacity, fmax, kmax,
+                    symmetry=self._symmetry, sound=self._sound,
+                    kraw=kraw, exchange=exchange, kb=kb, ecap=ecap,
+                    fused=fused_on, fused_interpret=fused_interp,
+                    cc=cc_cap)
+            first_call[0] = True
             if not cc_cap:
                 return fn
 
@@ -429,45 +438,40 @@ class ShardedTpuChecker(TpuChecker):
                                    bmax=jnp.int32(0),
                                    pdh=jnp.int32(0),
                                    prb=jnp.int32(0))
-            t_d0 = time.perf_counter()
-            with self._timed("dispatch"):
+            ordinal = int(self._metrics.get("chunks", 0)) + 1
+            with self._spans.span("dispatch", chunk=ordinal), \
+                    (self._spans.span("program", chunk=ordinal)
+                     if first_call[0] else nullcontext()):
                 carry, stats_d = chunk_fn(carry, remaining, grow_limit)
+            first_call[0] = False
             t_disp = time.perf_counter()
             self._metrics.inc("chunks")
             if fused_on:
                 self._metrics.inc("fused_chunks")
-            ordinal = int(self._metrics.get("chunks"))
-            self._spans.record("dispatch", t_d0, t_disp, chunk=ordinal)
+            if self._metrics.get("first_dispatch_s") is None:
+                self._metrics.set("first_dispatch_s",
+                                  t_disp - self._spawn_t0)
             inflight.append((ordinal, stats_d, int(grow_limit), t_disp))
 
         def process(ordinal: int, stats_d, grow_limit: int,
                     t_disp: float) -> set:
-            nonlocal fault_attempt, spill_attempt, corruption_attempt
-            with self._timed("sync_stall"):
-                # ONE transfer for everything the host reads per chunk
-                # — routed through the fault hook + watchdog deadline
-                stats = self._materialize_stats(stats_d, ordinal,
-                                                t_disp=t_disp)
-            # device-time attribution (checker/tpu.py
-            # _materialize_stats): dispatch->ready vs ready->pulled
-            timing = self._pull_timing
-            if timing is not None:
-                self._metrics.add_time("device_s", timing[0])
-                self._metrics.add_time("xfer_s", timing[1])
-            # interval twins for the attribution sweep (obs/spans.py)
-            stamps = getattr(self, "_pull_stamps", None)
-            if stamps is not None:
-                self._spans.record("device", t_disp, stamps[0],
-                                   chunk=ordinal)
-                self._spans.record("xfer", stamps[0], stamps[1],
-                                   chunk=ordinal)
+            nonlocal fault_attempt, spill_attempt
+            # ONE transfer for everything the host reads per chunk —
+            # routed through the fault hook + watchdog deadline (the
+            # sync / pull / device spans, checker/tpu.py)
+            stats = self._materialize_stats(stats_d, ordinal,
+                                            t_disp=t_disp)
             # a successful sync proves the backend is alive; the retry
             # budget (and the per-device blame streak) bounds
             # CONSECUTIVE faults, the spill budget CONSECUTIVE spills
             fault_attempt = 0
             spill_attempt = 0
             attributor.clear()
-            t0 = time.perf_counter()
+            with self._spans.span("decode", chunk=ordinal):
+                return decode(ordinal, stats, grow_limit)
+
+        def decode(ordinal: int, stats, grow_limit: int) -> set:
+            nonlocal corruption_attempt
             acts: set = set()
             q_head = stats[:D].astype(np.int64)
             q_tail = stats[D:2 * D].astype(np.int64)
@@ -496,8 +500,7 @@ class ShardedTpuChecker(TpuChecker):
                 # per-shard queue/log slices are append-only and keep
                 # their shard-relative positions across growths, so the
                 # suffix gathers reconstruct the device state exactly
-                with self._spans.span("host_probe", chunk=ordinal), \
-                        self._timed("shadow"):
+                with self._spans.span("shadow", chunk=ordinal):
                     qloc = qcap // D
                     closc = self._capacity // D
                     eloc = (ecap // D) if ecap else 0
@@ -651,9 +654,7 @@ class ShardedTpuChecker(TpuChecker):
                     # owner shard inserted this chunk, plus its live
                     # queue depth
                     shard_new=[int(x) for x in shard_new],
-                    shard_q=[int(x) for x in (q_tail - q_head)],
-                    device_s=(round(timing[0], 6) if timing else None),
-                    xfer_s=(round(timing[1], 6) if timing else None))
+                    shard_q=[int(x) for x in (q_tail - q_head)])
             disc_fps = _combine64(disc_hi, disc_lo)
             for i, prop in enumerate(properties):
                 if i in host_prop_idx:
@@ -672,8 +673,7 @@ class ShardedTpuChecker(TpuChecker):
             if self._host_props and any(
                     p.name not in discoveries
                     for _i, p in self._host_props):
-                with self._spans.span("props", chunk=ordinal), \
-                        self._timed("posthoc"):
+                with self._spans.span("props", chunk=ordinal):
                     # the reduction is pinned to THIS chunk's per-shard
                     # queue tails: under pipelining the live carry
                     # already holds the next chunk's appends, and
@@ -682,9 +682,6 @@ class ShardedTpuChecker(TpuChecker):
                     self._posthoc_sharded(carry, qcap, n_init_arr,
                                           discoveries,
                                           q_tail_h=q_tail)
-            t_host_end = time.perf_counter()
-            self._metrics.add_time("host_overlap", t_host_end - t0)
-            self._spans.record("host", t0, t_host_end, chunk=ordinal)
             if kovf:
                 kovf_pend[0] = max(kovf_pend[0], vmax)
                 kovf_pend[1] = max(kovf_pend[1], dmax)
@@ -773,7 +770,7 @@ class ShardedTpuChecker(TpuChecker):
             # capacity/table/queue regrow would inflate every buffer
             # toward O(edges))
             nonlocal carry, chunk_fn, ecap
-            with self._timed("grow"):
+            with self._spans.span("grow"):
                 from jax.sharding import (NamedSharding,
                                           PartitionSpec as P)
                 old_eloc = ecap // D
@@ -796,7 +793,7 @@ class ShardedTpuChecker(TpuChecker):
         def handle_grow() -> None:
             nonlocal carry, chunk_fn, qcap, ecap
             self._metrics.inc("grows")
-            with self._timed("grow"):
+            with self._spans.span("grow"):
                 carry, qcap = self._grow_sharded(
                     carry, qcap, n_init, headroom, table_fps, insert_fn)
             if ecap:
@@ -871,7 +868,7 @@ class ShardedTpuChecker(TpuChecker):
                 headroom, D)
             if self._sound:
                 ecap = max(ecap, self._capacity)
-            with self._timed("seed"):
+            with self._spans.span("seed"):
                 carry2 = seed_sharded_carry(
                     model, mesh, axis, qcap, self._capacity, init_rows2,
                     frontier2, np.asarray(ebs, np.uint32), prop_count,
@@ -950,7 +947,7 @@ class ShardedTpuChecker(TpuChecker):
                                  hot=plan[1], reason=reason,
                                  host_tier_keys=shadow.host_tier_keys)
             recover_reason = "spill"
-            with self._timed("spill"):
+            with self._spans.span("spill"):
                 reseed()
 
         def degrade_step(blamed, exc) -> bool:
@@ -1102,7 +1099,7 @@ class ShardedTpuChecker(TpuChecker):
                         if mk is not None else None)
             shadow.reshard(D)
             recover_reason = "promote"
-            with self._timed("promote"):
+            with self._spans.span("promote"):
                 reseed()
             return True
 
@@ -1341,115 +1338,116 @@ class ShardedTpuChecker(TpuChecker):
             # mirror post-passes) take over from here — driven through
             # the same generator so a stepped/paused run stays
             # responsive across the handoff.
-            import contextlib
             self._fault_shards = 1
             dev = self._handoff_device
             ctx = (jax.default_device(dev) if dev is not None
-                   else contextlib.nullcontext())
+                   else nullcontext())
             with ctx:
                 yield from self._drive_device()
             if self._visitor is not None and not self._paused:
-                with self._timed("visit"):
+                with self._spans.span("visit"):
                     self._visit_reached()
             return
 
-        q_head, q_tail = cur["q_head"], cur["q_tail"]
-        log_n, e_n = cur["log_n"], cur["e_n"]
-        if int(log_n.max()):
-            # end-of-run shard balance: min/max per-shard inserted
-            # states (1.0 = perfectly balanced fingerprint routing)
-            self._metrics.set(
-                "shard_balance",
-                round(float(int(log_n.min()) / int(log_n.max())), 4))
+        # the engine's tail after its last chunk, up to done
+        with self._spans.span("finish"):
+            q_head, q_tail = cur["q_head"], cur["q_tail"]
+            log_n, e_n = cur["log_n"], cur["e_n"]
+            if int(log_n.max()):
+                # end-of-run shard balance: min/max per-shard inserted
+                # states (1.0 = perfectly balanced fingerprint routing)
+                self._metrics.set(
+                    "shard_balance",
+                    round(float(int(log_n.min()) / int(log_n.max())), 4))
 
-        if (self._pause_event.is_set()
-                and not self._cancel_event.is_set()
-                and int((q_tail - q_head).sum()) > 0
-                and len(discoveries) < prop_count
-                and not (target is not None
-                         and self._state_count >= target)):
-            # pause exit (the run did NOT finish): the pipeline drained
-            # above; checkpoint the complete mirror + pending frontier
-            # in the shard-agnostic single-chip format, so the job
-            # resumes on ANY mesh width (preemption onto a smaller
-            # subset rides the same machinery as a cross-mesh resume)
-            if shadow is not None:
-                p_rows, p_ebs, p_fps = shadow.pending()
-            else:
-                self._finalize_sharded(carry)
-                self._ensure_mirror()
+            if (self._pause_event.is_set()
+                    and not self._cancel_event.is_set()
+                    and int((q_tail - q_head).sum()) > 0
+                    and len(discoveries) < prop_count
+                    and not (target is not None
+                             and self._state_count >= target)):
+                # pause exit (the run did NOT finish): the pipeline drained
+                # above; checkpoint the complete mirror + pending frontier
+                # in the shard-agnostic single-chip format, so the job
+                # resumes on ANY mesh width (preemption onto a smaller
+                # subset rides the same machinery as a cross-mesh resume)
+                if shadow is not None:
+                    p_rows, p_ebs, p_fps = shadow.pending()
+                else:
+                    self._finalize_sharded(carry)
+                    self._ensure_mirror()
+                    qloc = qcap // D
+                    width = model.packed_width
+                    q_h, qh, qt = self._pull_global(
+                        (carry.q, carry.q_head, carry.q_tail))
+                    pend = np.concatenate(
+                        [q_h[s * qloc + int(qh[s]):s * qloc + int(qt[s])]
+                         for s in range(D)])
+                    p_rows = pend[:, :width]
+                    p_ebs = pend[:, width]
+                    p_fps = _combine64(pend[:, width + 1],
+                                       pend[:, width + 2])
+                self._write_pause_checkpoint(p_rows, p_ebs, p_fps,
+                                             discoveries)
+                self._discovery_fps.update(discoveries)
+                return
+
+            if (self._sound and int((q_tail - q_head).sum()) == 0
+                    and self._resume_path is not None):
+                import warnings
+                warnings.warn(
+                    "resume_from() + sound_eventually(): the post-exhaustion "
+                    "lasso sweep is SKIPPED on resumed runs (the "
+                    "pre-checkpoint subgraph's edges are not in this run's "
+                    "device logs), so liveness cycles entered through "
+                    "pre-checkpoint states go unreported. Re-run without "
+                    "resume_from() for a cycle-complete liveness verdict.",
+                    RuntimeWarning, stacklevel=2)
+            if (self._sound and int((q_tail - q_head).sum()) == 0
+                    and self._resume_path is None and not self._symmetry):
+                # (not under symmetry — cross-branch witnesses cannot replay
+                # through concrete orbit members; see the single-chip sweep)
+                # full exhaustion under sound mode: merged lasso sweep over
+                # every shard's node graph (insert edges from the per-shard
+                # logs, cross edges from the per-shard edge logs) — the
+                # sharded twin of TpuChecker._device_lasso_sweep
+                with self._spans.span("lasso"):
+                    if shadow is not None:
+                        # after a mid-run recovery the device logs cover
+                        # only the last epoch; the shadow spans the run
+                        self._shadow_lasso_sweep(shadow, int(full_ebits),
+                                                 discoveries)
+                    else:
+                        self._sharded_lasso_sweep(carry, qcap, q_tail,
+                                                  log_n, e_n, discoveries,
+                                                  int(full_ebits))
+
+            if self._tpu_options.get("resumable"):
+                # pull the pending per-shard frontiers eagerly so save()
+                # needs no pinned device buffers; the checkpoint format is
+                # the single-chip one (shard-agnostic)
                 qloc = qcap // D
                 width = model.packed_width
                 q_h, qh, qt = self._pull_global(
                     (carry.q, carry.q_head, carry.q_tail))
-                pend = np.concatenate(
-                    [q_h[s * qloc + int(qh[s]):s * qloc + int(qt[s])]
-                     for s in range(D)])
-                p_rows = pend[:, :width]
-                p_ebs = pend[:, width]
-                p_fps = _combine64(pend[:, width + 1],
-                                   pend[:, width + 2])
-            self._write_pause_checkpoint(p_rows, p_ebs, p_fps,
-                                         discoveries)
+                pend_l = [q_h[s * qloc + int(qh[s]):s * qloc + int(qt[s])]
+                          for s in range(D)]
+                pend = np.concatenate(pend_l)
+                self._resume_frontier = (
+                    pend[:, :width].copy(), pend[:, width].copy(),
+                    _combine64(pend[:, width + 1], pend[:, width + 2]))
+            if shadow is not None:
+                # the shadow-maintained host mirror is already complete
+                self._mirror_carry = None
+            else:
+                self._finalize_sharded(carry)
             self._discovery_fps.update(discoveries)
-            return
-
-        if (self._sound and int((q_tail - q_head).sum()) == 0
-                and self._resume_path is not None):
-            import warnings
-            warnings.warn(
-                "resume_from() + sound_eventually(): the post-exhaustion "
-                "lasso sweep is SKIPPED on resumed runs (the "
-                "pre-checkpoint subgraph's edges are not in this run's "
-                "device logs), so liveness cycles entered through "
-                "pre-checkpoint states go unreported. Re-run without "
-                "resume_from() for a cycle-complete liveness verdict.",
-                RuntimeWarning, stacklevel=2)
-        if (self._sound and int((q_tail - q_head).sum()) == 0
-                and self._resume_path is None and not self._symmetry):
-            # (not under symmetry — cross-branch witnesses cannot replay
-            # through concrete orbit members; see the single-chip sweep)
-            # full exhaustion under sound mode: merged lasso sweep over
-            # every shard's node graph (insert edges from the per-shard
-            # logs, cross edges from the per-shard edge logs) — the
-            # sharded twin of TpuChecker._device_lasso_sweep
-            with self._timed("lasso"):
-                if shadow is not None:
-                    # after a mid-run recovery the device logs cover
-                    # only the last epoch; the shadow spans the run
-                    self._shadow_lasso_sweep(shadow, int(full_ebits),
-                                             discoveries)
-                else:
-                    self._sharded_lasso_sweep(carry, qcap, q_tail,
-                                              log_n, e_n, discoveries,
-                                              int(full_ebits))
-
-        if self._tpu_options.get("resumable"):
-            # pull the pending per-shard frontiers eagerly so save()
-            # needs no pinned device buffers; the checkpoint format is
-            # the single-chip one (shard-agnostic)
-            qloc = qcap // D
-            width = model.packed_width
-            q_h, qh, qt = self._pull_global(
-                (carry.q, carry.q_head, carry.q_tail))
-            pend_l = [q_h[s * qloc + int(qh[s]):s * qloc + int(qt[s])]
-                      for s in range(D)]
-            pend = np.concatenate(pend_l)
-            self._resume_frontier = (
-                pend[:, :width].copy(), pend[:, width].copy(),
-                _combine64(pend[:, width + 1], pend[:, width + 2]))
-        if shadow is not None:
-            # the shadow-maintained host mirror is already complete
-            self._mirror_carry = None
-        else:
-            self._finalize_sharded(carry)
-        self._discovery_fps.update(discoveries)
-        if self._visitor is not None:
-            # same post-hoc visitation as the single-chip engine; the
-            # global interleaving of per-shard insertion orders is
-            # unspecified, like the reference's multithreaded visitors
-            with self._timed("visit"):
-                self._visit_reached()
+            if self._visitor is not None:
+                # same post-hoc visitation as the single-chip engine; the
+                # global interleaving of per-shard insertion orders is
+                # unspecified, like the reference's multithreaded visitors
+                with self._spans.span("visit"):
+                    self._visit_reached()
 
     def _sharded_qcap(self, n_init: int, headroom: int, d: int) -> int:
         """Append-only per-shard queues: a shard's tail never exceeds its
@@ -1696,7 +1694,7 @@ class ShardedTpuChecker(TpuChecker):
         _tag, log_d, log_n_d = mirror
         import jax
 
-        with self._timed("mirror_pull"):
+        with self._spans.span("mirror.pull"):
             D = self._mesh.shape[self._axis]
             closc = self._capacity // D
             log_n, log = self._pull_global((log_n_d, log_d))
@@ -1706,6 +1704,7 @@ class ShardedTpuChecker(TpuChecker):
                 self._trace.emit(
                     "mirror_pull", n=int(np.asarray(log_n).sum()),
                     shards=[int(x) for x in np.asarray(log_n)])
+        with self._spans.span("mirror.index"):
             for s in range(D):
                 ln = int(log_n[s])
                 if not ln:

@@ -282,7 +282,6 @@ class BatchRun:
     # --- lifecycle ------------------------------------------------------
     def run(self) -> None:
         from ..checker.batch_loop import BatchLoop
-        from ..obs import SpanRecorder
         sched = self._sched
         trace = sched._trace
         # the batch's phase intervals land on the SCHEDULER stream
@@ -290,8 +289,7 @@ class BatchRun:
         # report attributes the shared kernel launches once
         loop = BatchLoop(self._model, self._lanes, self._capacity,
                          self._fmax, chunk_steps=self._chunk_steps,
-                         metrics=self._metrics, trace=trace,
-                         spans=SpanRecorder(trace))
+                         metrics=self._metrics, trace=trace)
         before = self._metrics.get("compiles", 0)
         loop.start()
         self._built_fresh = self._metrics.get("compiles", 0) > before

@@ -7,7 +7,8 @@ The load-bearing guarantees:
   JSONL postmortem artifact (the flight recorder's whole point);
 * the Explorer's ``/.events`` SSE stream and ``tools/watch.py`` render
   a live run without perturbing it (a slow client drops, never blocks);
-* ``device_s``/``xfer_s`` split the old host-side sync conflation;
+* the ``device``/``pull`` spans split each chunk's sync into device
+  time and the stats transfer;
 * ``tools/bench_history.py`` flags the BENCH_r05-style empty artifact
   and synthetic regressions machine-readably.
 """
@@ -142,15 +143,18 @@ class TestDeviceTime:
         events = []
         ck = _twopc(trace=events).spawn_tpu().join()
         prof = ck.profile()
-        assert prof.get("device_s", -1) >= 0.0
-        assert prof.get("xfer_s", -1) >= 0.0
-        assert "device_s" in GLOSSARY and "xfer_s" in GLOSSARY
-        chunk = [e for e in events if e["ev"] == "chunk"][-1]
-        assert chunk["device_s"] >= 0.0
-        assert chunk["xfer_s"] >= 0.0
-        # the split partitions (a slice of) the old conflated stall:
+        assert prof.get("span_s.device", -1) >= 0.0
+        assert prof.get("span_s.pull", -1) >= 0.0
+        assert "span_s.device" in GLOSSARY and "span_s.pull" in GLOSSARY
+        # the split rides the span events, one pair per chunk
+        last = [e for e in events if e["ev"] == "chunk"][-1]["chunk"]
+        split = {e["name"]: e for e in events if e["ev"] == "span"
+                 and e.get("chunk") == last
+                 and e["name"] in ("device", "pull")}
+        assert set(split) == {"device", "pull"}
+        assert all(e["t1"] >= e["t0"] for e in split.values())
         # both components are bounded by the run's wall time
-        assert prof["device_s"] <= prof["search"] + 1.0
+        assert prof["span_s.device"] <= prof["search"] + 1.0
 
     @pytest.mark.slow  # the profiler session costs ~10s on CPU
     def test_profile_dir_capture_smoke(self, tmp_path):

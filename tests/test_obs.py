@@ -80,8 +80,9 @@ class TestMetrics:
 
     def test_glossary_covers_engine_keys(self):
         # the canonical keys every engine emits must stay documented
-        for key in ("dispatch", "sync_stall", "host_overlap", "grow",
-                    "hgrow", "chunks", "grows", "compiles", "vmax",
+        for key in ("span_s.dispatch", "span_s.sync", "span_s.decode",
+                    "span_s.grow", "span_s.hgrow", "chunks", "grows",
+                    "compiles", "vmax",
                     "dmax", "rmax", "levels", "jobs", "search",
                     "shard_balance"):
             assert key in GLOSSARY, key
@@ -397,13 +398,19 @@ class TestSchemaDriftLint:
             re.compile(r'(?:metrics|_metrics)\.'
                        r'(?:inc|set|observe_max|add_time|timed)'
                        r'\(\s*[\'"]([a-z_0-9]+)[\'"]'),
-            re.compile(r'self\._timed\(\s*[\'"]([a-z_0-9]+)[\'"]'),
         )
+        # every engine span times itself under span_s.<name>
+        span_re = re.compile(r'spans\.(?:span|record)'
+                             r'\(\s*[\'"]([a-z_.0-9]+)[\'"]')
         bad = [(path, key)
                for path, src in self._sources()
                for rx in key_res
                for key in rx.findall(src)
                if key not in GLOSSARY]
+        bad += [(path, "span_s." + name)
+                for path, src in self._sources()
+                for name in span_re.findall(src)
+                if "span_s." + name not in GLOSSARY]
         assert not bad, f"metrics keys missing from GLOSSARY: {bad}"
 
     def test_schema_events_all_have_emit_sites(self):

@@ -106,17 +106,18 @@ class TestSingleChipParity:
         on = _run(lambda: TwoPhaseSys(4), capacity=1 << 8, fmax=16)
         off = _run(lambda: TwoPhaseSys(4), capacity=1 << 8, fmax=16,
                    pipeline=False)
-        assert on.profile().get("grow", 0) > 0
+        assert on.profile().get("span_s.grow", 0) > 0
         _assert_full_parity(on, off)
 
     def test_profile_overlap_timers(self):
         on = _run(lambda: TwoPhaseSys(3), capacity=1 << 12)
         prof = on.profile()
-        for key in ("dispatch", "sync_stall", "host_overlap", "chunks"):
+        for key in ("span_s.dispatch", "span_s.sync", "span_s.decode",
+                    "chunks"):
             assert key in prof, key
         off = _run(lambda: TwoPhaseSys(3), capacity=1 << 12,
                    pipeline=False)
-        assert "sync_stall" in off.profile()
+        assert "span_s.sync" in off.profile()
 
 
 class TestShardedParity:

@@ -290,7 +290,9 @@ class TestFlexController:
                                                    solo_2pc4):
         # the 8-device carve: B holds half the pool, so A (wants 8)
         # lands on 4; when B finishes and its buddies merge free, the
-        # flex pass doubles A mid-run — promotes == 1, digest pinned
+        # flex pass doubles A mid-run — promotes == 1, digest pinned.
+        # A's step delay keeps it running past B's finish (at 0.02 s a
+        # step it sometimes finished first, and nothing was promoted)
         if len(jax.devices()) < 8:
             pytest.skip("need 8 devices")
         sched = Scheduler(JobStore(tmp_path), devices=jax.devices(),
@@ -300,7 +302,7 @@ class TestFlexController:
             b = sched.submit(JobSpec("twopc", args=[3], options=OPTS,
                                      width=4))
             a = sched.submit(JobSpec("twopc", args=[4], options=OPTS,
-                                     width=8, step_delay=0.02))
+                                     width=8, step_delay=0.1))
             deadline = time.monotonic() + 60.0
             while time.monotonic() < deadline and not \
                     sched.job(a.id).status.get("granted_width"):

@@ -8,7 +8,7 @@ The load-bearing guarantees:
   of the chunk anatomy, schema-valid and identity-tagged;
 * **buckets sum to wall** — :func:`stateright_tpu.obs.spans.analyze`
   sweeps the merged timeline and splits wall time into exclusively-
-  attributed buckets (``device``/``xfer``/``exchange``, ``overlap``,
+  attributed buckets (``device``/``pull``/``exchange``, ``overlap``,
   ``host:<phase>``, ``idle``) that partition the wall interval by
   construction;
 * **the pipeline shift is visible** — a ``pipeline=False`` run has
@@ -90,11 +90,11 @@ class TestAnalyze:
         assert attr["bubble_frac"] == pytest.approx(4.0 / 9.0)
 
     def test_innermost_device_span_wins(self):
-        """A ``xfer`` nested inside the ``device`` interval names its
+        """A ``pull`` nested inside the ``device`` interval names its
         own segment — the umbrella does not swallow it."""
         attr = analyze([_span("device", 0.0, 6.0),
-                        _span("xfer", 2.0, 4.0)])
-        assert attr["buckets"] == {"device": 4.0, "xfer": 2.0}
+                        _span("pull", 2.0, 4.0)])
+        assert attr["buckets"] == {"device": 4.0, "pull": 2.0}
 
     def test_innermost_host_span_wins(self):
         attr = analyze([_span("host", 0.0, 10.0),
@@ -124,7 +124,7 @@ class TestAnalyze:
         spans = [
             _span("dispatch", 0.0, 0.3),
             _span("device", 0.3, 2.1),
-            _span("xfer", 2.1, 2.4),
+            _span("pull", 2.1, 2.4),
             _span("host", 2.2, 3.7),          # partially overlapped
             _span("host_probe", 2.5, 3.0),    # nested host phase
             _span("device", 2.6, 4.8),        # next chunk in flight
@@ -139,7 +139,7 @@ class TestAnalyze:
         kinds = set(attr["buckets"])
         assert "overlap" in kinds and "idle" in kinds
         assert any(k.startswith("host:") for k in kinds)
-        assert kinds & {"device", "xfer", "exchange"}
+        assert kinds & {"device", "pull", "exchange"}
 
     def test_pipeline_shift_synthetic(self):
         """The signature the e2e pin looks for, in miniature: same
@@ -208,8 +208,9 @@ class TestSpanRecorder:
     def test_traceless_ring_still_feeds_attribution(self):
         rec = SpanRecorder(None)
         t = time.perf_counter()
-        rec.record("device", t, t + 0.5)
-        rec.record("host", t + 0.5, t + 0.7)
+        rec.record("dispatch", t, t + 0.01)
+        rec.record("device", t + 0.01, t + 0.5)
+        rec.record("decode", t + 0.5, t + 0.7)
         snap = attach_attribution({"chunks": 2}, rec)
         assert "attribution" in snap
         assert snap["bubble_frac"] > 0.0
@@ -434,10 +435,10 @@ class TestEndToEnd:
         for ev in spans:
             validate_event(ev)
         names = {e["name"] for e in spans}
-        assert {"dispatch", "device", "xfer", "host"} <= names
-        # device/xfer spans carry the chunk ordinal for correlation
+        assert {"dispatch", "device", "sync", "pull", "decode"} <= names
+        # the chunk's spans carry its ordinal for correlation
         assert all("chunk" in e for e in spans
-                   if e["name"] in ("device", "xfer"))
+                   if e["name"] in ("device", "sync", "pull", "decode"))
 
     def test_attribution_sums_to_wall(self, pipeline_runs):
         """Acceptance: buckets sum to within 5% of wall on the

@@ -7,15 +7,14 @@ Usage:
 Consumes the ``span`` events emitted by the engines' dispatch/process
 loops (``stateright_tpu/obs/spans.py``) and renders the ranked stall
 table from the overlap-aware critical-path sweep: wall time split into
-exclusively-attributed buckets that SUM TO WALL — ``device``/``xfer``/
+exclusively-attributed buckets that SUM TO WALL — ``device``/``pull``/
 ``exchange`` segments where only the device pipeline was busy,
 ``overlap`` where host work hid under an in-flight chunk (free, the
 pipeline doing its job), ``host:<phase>`` where the host blocked an
-idle device (the pipeline bubble), and ``idle`` dead air. The flat
-phase timers (``dispatch``/``sync_stall``/``host_overlap``) double-
-count under the double-buffered pipeline; this report is the
-actionable replacement — the biggest non-overlap row is the next perf
-target.
+idle device (the pipeline bubble), and ``idle`` dead air. The sweep
+covers the whole run — engine set-up and the verdict read included
+(``profile()['attribution']`` covers the chunk loop alone); the
+biggest non-overlap row is the next perf target.
 
 ``--fleet`` merges any set of trace artifacts (directories expand via
 ``stateright_tpu.obs.aggregate.collect_artifacts``) onto one

@@ -104,12 +104,10 @@ class Console:
             parts.append(f"load={ev['load']:.3f}")
         if "q_size" in ev:
             parts.append(f"q={ev['q_size']:>8,}")
-        if ev.get("device_s") is not None:
-            self._dev_total += ev["device_s"]
-            self._xfer_total += ev.get("xfer_s") or 0.0
-            if t > 0:
-                parts.append(f"dev={self._dev_total / t:4.0%}")
-                parts.append(f"xfer={self._xfer_total / t:4.0%}")
+        if self._dev_total and t > 0:
+            # device / stats-transfer seconds from the span events
+            parts.append(f"dev={self._dev_total / t:4.0%}")
+            parts.append(f"xfer={self._xfer_total / t:4.0%}")
         if "shard_q" in ev:
             parts.append(f"shards={len(ev['shard_q'])}")
         stall = self._top_stall()
@@ -145,9 +143,15 @@ class Console:
     def feed(self, ev: Dict[str, Any]) -> None:
         kind = ev.get("ev")
         if kind == "span":
-            # accumulated for the progress lines' top-stall fragment,
-            # never rendered per-event (several land per chunk)
+            # accumulated for the progress lines' top-stall fragment
+            # and device/transfer shares, never rendered per-event
+            # (several land per chunk)
             self._spans.append(ev)
+            name = ev.get("name")
+            if name == "device":
+                self._dev_total += float(ev["t1"]) - float(ev["t0"])
+            elif name == "pull":
+                self._xfer_total += float(ev["t1"]) - float(ev["t0"])
             return
         if kind in _PROGRESS:
             now = time.monotonic()
