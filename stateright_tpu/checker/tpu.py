@@ -95,6 +95,14 @@ def _combine64(hi, lo) -> np.ndarray:
         | np.asarray(lo).astype(np.uint64)
 
 
+def _word_key(fp: int) -> np.uint64:
+    """``fp`` as a log row stores it: its (hi, lo) word pair read as one
+    native uint64, the form of the walk's child-key column (a view of
+    the pulled rows, so no column has to be combined)."""
+    return np.array([fp >> 32, fp & 0xFFFFFFFF],
+                    np.uint32).view(np.uint64)[0]
+
+
 def _compact(mask, *columns):
     """Scatter-compact ``columns`` rows where ``mask`` holds to the front.
 
@@ -504,8 +512,16 @@ class TpuChecker(HostChecker):
         self._hscan_tail = 0  # queue rows known fully history-deduped
         # phase timers/counters ride the shared obs registry
         # (HostChecker._metrics); keys are the obs.GLOSSARY canon
-        # device-resident search record, pulled lazily by _ensure_mirror
+        # device-resident search record, pulled lazily by _pull_log
         self._mirror_carry = None
+        # the pulled log's live rows and their child-key column, walked
+        # by _reconstruct_path until _ensure_mirror folds them into the
+        # dict; _log_ordered holds where a parent's row precedes its
+        # child's (one insertion-ordered log), _walks memoises chains
+        self._log_rows: Optional[np.ndarray] = None
+        self._log_keys: Optional[np.ndarray] = None
+        self._log_ordered = False
+        self._walks: Dict[int, List[int]] = {}
         # most recently enqueued queue row (rides each chunk sync) —
         # the Explorer's live-progress sample for the device engine
         self._recent_row = None
@@ -2549,36 +2565,110 @@ class TpuChecker(HostChecker):
             self._eval_host_props_block(rows_h[:hcnt], wfp, discoveries)
         return carry._replace(hkey_hi=khi, hkey_lo=klo), False
 
-    def _ensure_mirror(self) -> None:
-        """Pull the device-resident (child fp, parent fp) log — lazily, on
-        first use — to complete the host mirror used for path
-        reconstruction and checkpointing."""
-        mirror = getattr(self, "_mirror_carry", None)
-        if mirror is None:
-            return
-        self._mirror_carry = None
+    def _pull_log_blocks(self, mirror) -> List[np.ndarray]:
+        """The device-resident search log's live rows, in ONE transfer
+        (engine-specific; the sharded engine returns one block per
+        shard). Each block is in insertion order."""
         log_d, log_n_d = mirror
         import jax
 
+        log_n = int(jax.device_get(log_n_d))
+        if self._trace:
+            self._trace.emit("mirror_pull", n=log_n)
+        if not log_n:
+            return []
+        # pull only the live prefix (pow2-padded slice jitted on
+        # device); the log matrix rides ONE transfer
+        n = min(_bucket(log_n), log_d.shape[0])
+        _slice, _take, take_rows_fn, _take2 = _level_helpers()
+        return [np.asarray(jax.device_get(take_rows_fn(log_d, n)))[:log_n]]
+
+    def _pull_log(self) -> None:
+        """Pull the search log — lazily, on first use — and index its
+        child keys for path walks (``_walk_log``). The fp -> parent dict
+        is not built here: only the callers that need the whole record
+        build it (``_ensure_mirror``)."""
+        mirror = self._mirror_carry
+        if mirror is None:
+            return
+        self._mirror_carry = None
+        self._metrics.inc("log_walks", 0)
+        self._metrics.inc("mirror_builds", 0)
         with self._spans.span("mirror.pull"):
-            log_n = int(jax.device_get(log_n_d))
-            if self._trace:
-                self._trace.emit("mirror_pull", n=log_n)
-            if not log_n:
-                return
-            # pull only the live prefix (pow2-padded slice jitted on
-            # device); the log matrix rides ONE transfer
-            n = min(_bucket(log_n), log_d.shape[0])
-            _slice, _take, take_rows_fn, _take2 = _level_helpers()
-            log = np.asarray(jax.device_get(take_rows_fn(log_d, n)))
+            blocks = self._pull_log_blocks(mirror)
+        if not blocks:
+            return
         with self._spans.span("mirror.index"):
-            child = _combine64(log[:log_n, 0], log[:log_n, 1])
-            parent = _combine64(log[:log_n, 2], log[:log_n, 3])
-            self._generated.update(zip(child.tolist(), parent.tolist()))
+            log = np.ascontiguousarray(
+                blocks[0] if len(blocks) == 1 else np.concatenate(blocks))
+            self._log_rows = log
+            # child (hi, lo) words read as one uint64 each: one strided
+            # copy, no combine (queried in the same form, _word_key)
+            self._log_keys = np.ascontiguousarray(
+                log.view(np.uint64)[:, 0])
+            # a parent is inserted (and expanded) before its children,
+            # so within one block its row comes first
+            self._log_ordered = len(blocks) == 1
+
+    def _walk_log(self, fp: int) -> List[int]:
+        """The chain from an init state to ``fp``, as the fingerprints
+        replay takes, walked over the pulled log: one vectorised key
+        scan a step, the parent read from the found row. A key in
+        ``_generated`` (init states, a resumed checkpoint's records, the
+        whole record once built) is walked there; the walk stops where
+        neither holds the key. Under symmetry/sound each canonical or
+        node key is translated to the ORIGINAL explored state's
+        fingerprint (recorded device-side), so the replayed trace is a
+        concrete path — the DFS engine's enqueue-original rule
+        (`dfs.rs:260-285`)."""
+        keys, rows = self._log_keys, self._log_rows
+        generated, orig_of = self._generated, self._orig_of
+        translate = self._symmetry or self._sound
+        end = 0 if keys is None else len(keys)
+        chain: List[int] = []
+        nxt = fp
+        while True:
+            if nxt in generated:
+                chain.append(orig_of.get(nxt, nxt) if translate else nxt)
+                parent = generated[nxt]
+                if parent is None:
+                    break
+                nxt = parent
+                continue
+            if keys is None:
+                break
+            hit = np.flatnonzero(keys[:end] == _word_key(nxt))
+            if not hit.size:
+                break
+            r = int(hit[0])
+            row = rows[r]
+            chain.append((int(row[4]) << 32 | int(row[5])) if translate
+                         else nxt)
+            nxt = int(row[2]) << 32 | int(row[3])
+            if self._log_ordered:
+                end = r
+        chain.reverse()
+        return chain
+
+    def _ensure_mirror(self) -> None:
+        """Complete the host mirror — the fp -> parent dict, and the
+        original-fp map under symmetry/sound — from the pulled log, for
+        the callers that need the whole record (checkpoints, visitors,
+        ``generated_fingerprints()``). Built once, from the one pull."""
+        self._pull_log()
+        log = self._log_rows
+        if log is None:
+            return
+        self._log_rows = self._log_keys = None
+        with self._spans.span("mirror.dict"):
+            child = _combine64(log[:, 0], log[:, 1]).tolist()
+            self._generated.update(
+                zip(child, _combine64(log[:, 2], log[:, 3]).tolist()))
             if self._symmetry or self._sound:
-                orig = _combine64(log[:log_n, 4], log[:log_n, 5])
-                self._orig_of.update(zip(child.tolist(), orig.tolist()))
+                self._orig_of.update(
+                    zip(child, _combine64(log[:, 4], log[:, 5]).tolist()))
             self._unique_state_count = len(self._generated)
+        self._metrics.inc("mirror_builds")
 
     # ------------------------------------------------------------------
     def _run_levels(self) -> None:
@@ -3066,21 +3156,13 @@ class TpuChecker(HostChecker):
         return rows, np.asarray(data["ebits"], np.uint32), fps
 
     def _reconstruct_path(self, fp: int) -> Path:
-        self._ensure_mirror()
+        self._pull_log()
         with self._spans.span("paths"):
-            if not (self._symmetry or self._sound):
-                return super()._reconstruct_path(fp)
-            # the mirror chain is canonical; translate each node to the
-            # ORIGINAL explored state's fingerprint (recorded
-            # device-side), so the replayed trace is a concrete path —
-            # the DFS engine's enqueue-original rule
-            # (`dfs.rs:260-285`) carried to the mirror
-            fingerprints: deque = deque()
-            nxt = fp
-            while nxt in self._generated:
-                fingerprints.appendleft(self._orig_of.get(nxt, nxt))
-                parent = self._generated[nxt]
-                if parent is None:
-                    break
-                nxt = parent
-            return Path.from_fingerprints(self._model, fingerprints)
+            if self._log_keys is None:  # the whole record is the dict
+                chain = self._walk_log(fp)
+            else:
+                chain = self._walks.get(fp)
+                if chain is None:
+                    chain = self._walks[fp] = self._walk_log(fp)
+                    self._metrics.inc("log_walks")
+            return Path.from_fingerprints(self._model, chain)

@@ -99,11 +99,15 @@ GLOSSARY: Dict[str, str] = {
                     "(Checker.request_pause; the step-driver boundary)",
     "span_s.mirror.pull": "the verdict read's device_get of the search "
                           "log (child, parent) rows",
-    "span_s.mirror.index": "building the host mirror (fp -> parent "
-                           "dict, and the original-fp map under "
-                           "symmetry) from the pulled log",
-    "span_s.paths": "reconstructing discovery paths from the mirror "
-                    "(parent walk + host replay)",
+    "span_s.mirror.index": "indexing the pulled log's child keys "
+                           "(one contiguous column) for path walks",
+    "span_s.mirror.dict": "building the whole host mirror (fp -> "
+                          "parent dict, and the original-fp map under "
+                          "symmetry/sound) from the pulled log, for "
+                          "checkpoints, visitors and "
+                          "generated_fingerprints()",
+    "span_s.paths": "reconstructing discovery paths (parent walk over "
+                    "the pulled log or the mirror, + host replay)",
     "span_s.race.host": "the budgeted host racer's run on its own "
                         "thread (raced spawn_tpu runs)",
     "span_s.race.decide": "winning engine done -> race decision "
@@ -131,6 +135,12 @@ GLOSSARY: Dict[str, str] = {
     "compiles": "chunk-program (re)builds — each implies an XLA "
                 "retrace unless the shapes hit the compile cache",
     "levels": "BFS levels completed (host/per-level engines)",
+    "log_walks": "discovery paths walked over the pulled search log's "
+                 "arrays, without the host mirror dict (a path walked "
+                 "once is memoised; device engines)",
+    "mirror_builds": "whole host-mirror dict builds from the pulled "
+                     "log (span_s.mirror.dict): 0 where only paths "
+                     "were read (device engines)",
     "jobs": "DFS stack jobs completed (multi-process DFS)",
     "retries": "transient-fault recoveries taken (re-seed + resume; "
                "bounded per consecutive burst by "

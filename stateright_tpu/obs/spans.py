@@ -65,8 +65,8 @@ WAIT_SPANS = frozenset({"sync"})
 #: (their own ``span_s.*`` timers report them); ``race.host`` runs on
 #: its own thread beside the loop.
 OUTSIDE_LOOP = frozenset({"spawn", "init", "finish", "mirror.pull",
-                          "mirror.index", "paths", "race.host",
-                          "race.decide"})
+                          "mirror.index", "mirror.dict", "paths",
+                          "race.host", "race.decide"})
 
 #: the chunk loop's first and last phases: its attribution window runs
 #: from the first ``dispatch`` to the end of the last ``decode``

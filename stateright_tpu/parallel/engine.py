@@ -1680,42 +1680,24 @@ class ShardedTpuChecker(TpuChecker):
 
     # ------------------------------------------------------------------
     def _finalize_sharded(self, carry: ShardedCarry) -> None:
-        """Stash the device-resident per-shard logs; the host mirror is
-        completed lazily on first use (`_ensure_mirror`) — the log pull
-        is ~tens of MB over a ~35 MB/s link, pointless for count-only
-        runs (the unique count comes from the stats vector)."""
+        """Stash the device-resident per-shard logs; they are pulled
+        lazily on first use (`_pull_log`) — the log pull is ~tens of MB
+        over a ~35 MB/s link, pointless for count-only runs (the unique
+        count comes from the stats vector)."""
         self._mirror_carry = ("sharded", carry.log, carry.log_n)
 
-    def _ensure_mirror(self) -> None:
-        mirror = getattr(self, "_mirror_carry", None)
-        if mirror is None or mirror[0] != "sharded":
-            return super()._ensure_mirror()
-        self._mirror_carry = None
+    def _pull_log_blocks(self, mirror) -> List[np.ndarray]:
+        if mirror[0] != "sharded":
+            return super()._pull_log_blocks(mirror)
         _tag, log_d, log_n_d = mirror
-        import jax
-
-        with self._spans.span("mirror.pull"):
-            D = self._mesh.shape[self._axis]
-            closc = self._capacity // D
-            log_n, log = self._pull_global((log_n_d, log_d))
-            if self._trace:
-                # per-shard pull volumes: the mirror transfer is the
-                # big host-link cost of a sharded run
-                self._trace.emit(
-                    "mirror_pull", n=int(np.asarray(log_n).sum()),
-                    shards=[int(x) for x in np.asarray(log_n)])
-        with self._spans.span("mirror.index"):
-            for s in range(D):
-                ln = int(log_n[s])
-                if not ln:
-                    continue
-                blk = log[s * closc:s * closc + ln]
-                child = _combine64(blk[:, 0], blk[:, 1])
-                parent = _combine64(blk[:, 2], blk[:, 3])
-                self._generated.update(zip(child.tolist(),
-                                           parent.tolist()))
-                if self._symmetry or self._sound:
-                    orig = _combine64(blk[:, 4], blk[:, 5])
-                    self._orig_of.update(zip(child.tolist(),
-                                             orig.tolist()))
-            self._unique_state_count = len(self._generated)
+        D = self._mesh.shape[self._axis]
+        closc = self._capacity // D
+        log_n, log = self._pull_global((log_n_d, log_d))
+        if self._trace:
+            # per-shard pull volumes: the mirror transfer is the big
+            # host-link cost of a sharded run
+            self._trace.emit(
+                "mirror_pull", n=int(np.asarray(log_n).sum()),
+                shards=[int(x) for x in np.asarray(log_n)])
+        return [log[s * closc:s * closc + int(log_n[s])]
+                for s in range(D) if int(log_n[s])]
