@@ -8,8 +8,10 @@ fault planted in the program where its answers are made:
 * ``half_batch``: half of the successor lanes are left out;
 * ``answer_altered``: one fingerprint of the answer is altered.
 
-The exchange between chips has no cell yet (no four-chip cell), so it
-has no case. ``none`` is the sound run, which must come out correct.
+Every cell in ``BENCHMARK.json`` gets every case; a cell that asks for
+more chips than the CPU run has devices is skipped, and says so. The
+exchange between chips has no case yet. ``none`` is the sound run,
+which must come out correct.
 """
 
 from __future__ import annotations
@@ -21,23 +23,37 @@ import sys
 
 import pytest
 
+from tests.small import workloads
+
 HERE = os.path.dirname(os.path.abspath(__file__))
-CELLS = ["2pc-full", "paxos3-capped", "paxos2-full"]
+ENV = dict(os.environ, JAX_PLATFORMS="cpu")
+
+
+@pytest.fixture(scope="module")
+def cpu_devices():
+    """The devices a CPU run in ``ENV`` has."""
+    proc = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.device_count())"],
+        capture_output=True, text=True, env=ENV, timeout=120, check=True)
+    return int(proc.stdout.split()[-1])
 
 
 def _run(cell, fault):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(HERE, "fault_run.py"), cell, fault],
-        capture_output=True, text=True, env=env, timeout=600)
+        capture_output=True, text=True, env=ENV, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", list(workloads()))
 @pytest.mark.parametrize("fault", ["none", "state_unchanged", "half_batch",
                                    "answer_altered"])
-def test_fault_is_caught(cell, fault):
+def test_fault_is_caught(cell, fault, cpu_devices):
+    chips = int(workloads()[cell]["chips"])
+    if chips > cpu_devices:
+        pytest.skip(f"{cell} asks for {chips} chips; the CPU run has "
+                    f"{cpu_devices} devices")
     res = _run(cell, fault)
     print(cell, fault, res["compared"])
     assert res["correct"] is (fault == "none"), res["compared"]
