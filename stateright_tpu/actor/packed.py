@@ -582,7 +582,11 @@ class PackedActorModel(ActorModel, PackedModel):
 
     def packed_step(self, words):
         if self._net_ordered:
-            return self._packed_step_ordered(words)
+            import jax
+            # the ordered step's ops carry this name in the compiled HLO
+            # (op_name metadata), so a device trace maps its fusions back
+            with jax.named_scope("net.ordered"):
+                return self._packed_step_ordered(words)
         return self._packed_step_unordered(words)
 
     def _packed_step_ordered(self, words):

@@ -1283,6 +1283,8 @@ class TpuChecker(HostChecker):
         fused_on, fused_interp = self._fused_resolve(
             sharded=False, fmax=fmax, capacity=self._capacity)
         self._metrics.set("fused", 1 if fused_on else 0)
+        self._metrics.set("action_lanes", model.max_actions)
+        self._metrics.set("row_words", model.packed_width)
         # cross-chunk dedup ring (fused path only): the ring halves
         # thread OUTSIDE the carry — adding ChunkCarry fields would
         # change every STAGED program's traced signature and invalidate
@@ -1367,7 +1369,8 @@ class TpuChecker(HostChecker):
 
         inflight: deque = deque()
         # latest unpacked per-chunk scalars, read by the post-loop phases
-        cur = {"q_size": 0, "q_tail": 0, "log_n": 0, "e_n": 0}
+        cur = {"q_head": 0, "q_size": 0, "q_tail": 0, "log_n": 0,
+               "e_n": 0}
         hgrow_pend = {"on": False, "hovf": False, "h_n": 0}
         kovf_pend = [0, 0, 0]  # observed vmax/dmax/rmax of kovf chunks
 
@@ -1525,11 +1528,14 @@ class TpuChecker(HostChecker):
                         and ordinal % self._autosave_every == 0):
                     self._write_autosave(shadow, discoveries)
             new = log_n - cur["log_n"]  # this chunk's fresh inserts
-            cur.update(q_size=q_tail - q_head, q_tail=q_tail,
-                       log_n=log_n, e_n=e_n)
+            metrics = self._metrics
+            # rows this chunk expanded: the head only advances (a
+            # re-seed restarts it at 0, and cur with it)
+            metrics.inc("expanded", q_head - cur["q_head"])
+            cur.update(q_head=q_head, q_size=q_tail - q_head,
+                       q_tail=q_tail, log_n=log_n, e_n=e_n)
             # observed branching (raw / post-dedup), for tuning
             # model.branching_hint and the kraw/kmax buffer sizes
-            metrics = self._metrics
             metrics.observe_max("vmax", vmax)
             metrics.observe_max("dmax", dmax)
             metrics.observe_max("rmax", rmax)
@@ -1833,7 +1839,8 @@ class TpuChecker(HostChecker):
                         ecap=ecap, table=(khi, klo))
                 shadow.seed_epoch([pack_qrows(init_rows2, ebs, fps,
                                               model.packed_width)])
-            cur.update(q_size=n_init, q_tail=n_init, log_n=0, e_n=0)
+            cur.update(q_head=0, q_size=n_init, q_tail=n_init, log_n=0,
+                       e_n=0)
             hgrow_pend.update(on=False, hovf=False, h_n=0)
             kovf_pend[:] = [0, 0, 0]
             # fresh epoch: re-zero the cc ring lazily (its entries stay
@@ -1918,7 +1925,8 @@ class TpuChecker(HostChecker):
                 carry = carry._replace(key_hi=key_hi, key_lo=key_lo)
             shadow.seed_epoch([pack_qrows(init_rows2, ebs, fps,
                                           model.packed_width)])
-            cur.update(q_size=n_init, q_tail=n_init, log_n=0, e_n=0)
+            cur.update(q_head=0, q_size=n_init, q_tail=n_init, log_n=0,
+                       e_n=0)
             hgrow_pend.update(on=False, hovf=False, h_n=0)
             kovf_pend[:] = [0, 0, 0]
             # the old ring arrays may be poisoned by the fault that got

@@ -135,6 +135,14 @@ GLOSSARY: Dict[str, str] = {
     "compiles": "chunk-program (re)builds — each implies an XLA "
                 "retrace unless the shapes hit the compile cache",
     "levels": "BFS levels completed (host/per-level engines)",
+    "expanded": "frontier rows expanded by the device engine, from the "
+                "queue head's advance per chunk (summed over shards; a "
+                "row re-expanded after a re-seed counts again)",
+    "action_lanes": "successor lanes per expanded row: the model's "
+                    "max_actions, the dense action axis (gauge; device "
+                    "engines)",
+    "row_words": "uint32 words per packed state row: the model's "
+                 "packed_width (gauge; device engines)",
     "log_walks": "discovery paths walked over the pulled search log's "
                  "arrays, without the host mirror dict (a path walked "
                  "once is memoised; device engines)",
@@ -412,6 +420,7 @@ GAUGES = frozenset({
     "hosts", "procs", "fused_unsupported", "cc_dedup_capacity",
     "pool_busy_frac", "jobs_per_min", "burnin_frac", "flex_width",
     "quarantined", "first_dispatch_s", "race_losers_alive",
+    "action_lanes", "row_words",
 })
 
 #: keys merged by maximum (observed buffer-sizing maxima).

@@ -297,6 +297,8 @@ class ShardedTpuChecker(TpuChecker):
             sharded=True, fmax=fmax, capacity=self._capacity // D,
             probe_lanes=(D * kb_eff if kb_eff else kmax))
         self._metrics.set("fused", 1 if fused_on else 0)
+        self._metrics.set("action_lanes", model.max_actions)
+        self._metrics.set("row_words", model.packed_width)
         # cross-chunk dedup ring (fused path only): per-shard (cc,)
         # slices of one mesh-sharded array pair, threaded OUTSIDE
         # ShardedCarry for the same persistent-compile-cache reason as
@@ -613,9 +615,12 @@ class ShardedTpuChecker(TpuChecker):
                         and ordinal % self._autosave_every == 0):
                     self._write_autosave(shadow, discoveries)
             shard_new = log_n - cur["log_n"]  # per-shard fresh inserts
+            metrics = self._metrics
+            # rows this chunk expanded, over the shards: each head only
+            # advances (a re-seed restarts them at 0, and cur with them)
+            metrics.inc("expanded", int((q_head - cur["q_head"]).sum()))
             cur.update(q_head=q_head, q_tail=q_tail, log_n=log_n,
                        e_n=e_n)
-            metrics = self._metrics
             metrics.observe_max("vmax", vmax)
             metrics.observe_max("dmax", dmax)
             # dedup telemetry: chunk-local (reset at dispatch, so a
