@@ -580,22 +580,61 @@ class PackedActorModel(ActorModel, PackedModel):
                 "packed_on_timeout kernel) to explore Timeout actions on "
                 "the device engine; use the host engines otherwise")
 
+    @property
+    def offers_lane_step(self) -> bool:
+        """Whether the engine may build successor rows lane by lane,
+        after it has kept only the valid lanes (:meth:`packed_lane_valid`
+        for every lane, then :meth:`packed_step_lane` for the kept ones).
+        True on an ordered network, whose lane validity reads the channel
+        lengths, the heads and the actor words alone — unless a subclass
+        overrides ``packed_step``, or ``packed_boundary``, which reads the
+        built row."""
+        cls = type(self)
+        return (self._net_ordered
+                and cls.packed_step is PackedActorModel.packed_step
+                and cls.packed_boundary is PackedActorModel.packed_boundary)
+
     def packed_step(self, words):
         if self._net_ordered:
             import jax
-            # the ordered step's ops carry this name in the compiled HLO
-            # (op_name metadata), so a device trace maps its fusions back
-            with jax.named_scope("net.ordered"):
-                return self._packed_step_ordered(words)
+            import jax.numpy as jnp
+            return jax.vmap(lambda a: self.packed_step_lane(words, a))(
+                jnp.arange(self.max_actions))
         return self._packed_step_unordered(words)
 
-    def _packed_step_ordered(self, words):
-        """Ordered-network step: action ``c`` delivers the HEAD of
+    def packed_step_lane(self, words, a):
+        """Action ``a`` of an ordered network's step alone: ``(row
+        uint32[packed_width], valid bool, overflow bool)``, equal to lane
+        ``a`` of :meth:`packed_step`. The action delivers the HEAD of
         channel ``c = src * A + dst`` (`network.rs:157-170` — ordered
         networks expose only channel heads); sends append at the
         destination channel's tail; a full channel reports encoding
         overflow. Lossy ordered checking stays host-only."""
+        if not self._net_ordered:
+            raise NotImplementedError(
+                "packed_step_lane is the ordered network's step")
         import jax
+        # the ordered step's ops carry this name in the compiled HLO
+        # (op_name metadata), so a device trace maps its fusions back
+        with jax.named_scope("net.ordered"):
+            return self._ordered_lane(words, a)
+
+    def packed_lane_valid(self, words):
+        """``(valid bool[max_actions], overflow bool[max_actions])`` of
+        :meth:`packed_step`, with no successor row kept: validity reads
+        the channel lengths, the heads and the actor words alone, so the
+        compiler drops the unused rows. Offered where
+        :attr:`offers_lane_step` holds."""
+        if not self.offers_lane_step:
+            raise NotImplementedError(
+                "lane validity without the row needs an ordered network "
+                "and the default packed_step and packed_boundary")
+        import jax
+        import jax.numpy as jnp
+        return jax.vmap(lambda a: self.packed_step_lane(words, a)[1:])(
+            jnp.arange(self.max_actions))
+
+    def _ordered_lane(self, words, a):
         import jax.numpy as jnp
         aw, mw = self._aw, self.msg_width
         d, n_chan = self.channel_depth, self._n_chan
@@ -638,78 +677,73 @@ class PackedActorModel(ActorModel, PackedModel):
             lens = jnp.where(csel & svalid & ~ovf, lens + 1, lens)
             return lens, msgs, hist, overflow | ovf
 
-        def one_action(a):
-            is_timeout = a >= n_chan  # lanes only exist with timers
-            c = jnp.minimum(a, n_chan - 1)
-            src = chan_src[c].astype(jnp.uint32)
-            dst = chan_dst[c].astype(jnp.uint32)
-            csel = jnp.arange(n_chan) == c
-            ln = jnp.where(csel, lens, 0).sum()
-            occupied = ln > 0
-            head = (msgs[:, 0, :] * csel[:, None]).sum(axis=0) \
+        is_timeout = a >= n_chan  # lanes only exist with timers
+        c = jnp.minimum(a, n_chan - 1)
+        src = chan_src[c].astype(jnp.uint32)
+        dst = chan_dst[c].astype(jnp.uint32)
+        csel = jnp.arange(n_chan) == c
+        ln = jnp.where(csel, lens, 0).sum()
+        occupied = ln > 0
+        head = (msgs[:, 0, :] * csel[:, None]).sum(axis=0) \
+            .astype(jnp.uint32)
+        new_actors, changed, sends = self.packed_deliver(
+            actors, src, dst, head)
+        assert len(sends) == self.max_sends
+        any_send = jnp.bool_(False)
+        for _d2, _m2, sv in sends:
+            any_send = any_send | sv
+        valid = occupied & (changed | any_send)
+
+        # pop the head: shift the channel left, zero the tail entry
+        shifted = jnp.concatenate(
+            [msgs[:, 1:, :], jnp.zeros_like(msgs[:, :1, :])], axis=1)
+        new_msgs = jnp.where(csel[:, None, None], shifted, msgs)
+        new_lens = jnp.where(csel, lens - 1, lens)
+        new_hist = None
+        if hw:
+            new_hist = self.packed_record_in(hist, src, dst, head)
+        overflow = jnp.bool_(False)
+        for sdst, smsg, svalid in sends:
+            new_lens, new_msgs, new_hist, overflow = append_send(
+                new_lens, new_msgs, new_hist, overflow,
+                dst, sdst.astype(jnp.uint32), smsg, svalid)
+        parts = [new_actors, new_lens, new_msgs.reshape(-1), timer, crash]
+        if hw:
+            parts.append(new_hist)
+        row_out = jnp.concatenate(parts).astype(jnp.uint32)
+
+        if timers_on:
+            # same Timeout semantics as the unordered step (see
+            # _packed_step_unordered): a fired timer always yields a
+            # successor; sends append to ordered channels
+            aidx = jnp.minimum(a - n_chan, n_actors - 1) \
                 .astype(jnp.uint32)
-            new_actors, changed, sends = self.packed_deliver(
-                actors, src, dst, head)
-            assert len(sends) == self.max_sends
-            any_send = jnp.bool_(False)
-            for _d2, _m2, sv in sends:
-                any_send = any_send | sv
-            valid = occupied & (changed | any_send)
-
-            # pop the head: shift the channel left, zero the tail entry
-            shifted = jnp.concatenate(
-                [msgs[:, 1:, :], jnp.zeros_like(msgs[:, :1, :])], axis=1)
-            new_msgs = jnp.where(csel[:, None, None], shifted, msgs)
-            new_lens = jnp.where(csel, lens - 1, lens)
-            new_hist = None
+            tw = timer[0]
+            tbit = ((tw >> aidx) & 1).astype(bool)
+            t_actors, t_changed, t_sends, keep = \
+                self.packed_on_timeout(actors, aidx)
+            t_lens, t_msgs, t_hist = lens, msgs, hist
+            t_ovf = jnp.bool_(False)
+            for sdst, smsg, svalid in t_sends:
+                t_lens, t_msgs, t_hist, t_ovf = append_send(
+                    t_lens, t_msgs, t_hist, t_ovf,
+                    aidx, sdst.astype(jnp.uint32), smsg, svalid)
+            new_tw = (tw & ~(jnp.uint32(1) << aidx)) \
+                | (keep.astype(jnp.uint32) << aidx)
+            t_parts = [t_actors, t_lens, t_msgs.reshape(-1),
+                       new_tw[None], crash]
             if hw:
-                new_hist = self.packed_record_in(hist, src, dst, head)
-            overflow = jnp.bool_(False)
-            for sdst, smsg, svalid in sends:
-                new_lens, new_msgs, new_hist, overflow = append_send(
-                    new_lens, new_msgs, new_hist, overflow,
-                    dst, sdst.astype(jnp.uint32), smsg, svalid)
-            parts = [new_actors, new_lens, new_msgs.reshape(-1), timer,
-                     crash]
-            if hw:
-                parts.append(new_hist)
-            row_out = jnp.concatenate(parts).astype(jnp.uint32)
+                t_parts.append(t_hist)
+            t_row = jnp.concatenate(t_parts).astype(jnp.uint32)
+            row_out = jnp.where(is_timeout, t_row, row_out)
+            valid = jnp.where(is_timeout, tbit, valid)
+            overflow = jnp.where(is_timeout, t_ovf, overflow)
 
-            if timers_on:
-                # same Timeout semantics as the unordered step (see
-                # _packed_step_unordered): a fired timer always yields a
-                # successor; sends append to ordered channels
-                aidx = jnp.minimum(a - n_chan, n_actors - 1) \
-                    .astype(jnp.uint32)
-                tw = timer[0]
-                tbit = ((tw >> aidx) & 1).astype(bool)
-                t_actors, t_changed, t_sends, keep = \
-                    self.packed_on_timeout(actors, aidx)
-                t_lens, t_msgs, t_hist = lens, msgs, hist
-                t_ovf = jnp.bool_(False)
-                for sdst, smsg, svalid in t_sends:
-                    t_lens, t_msgs, t_hist, t_ovf = append_send(
-                        t_lens, t_msgs, t_hist, t_ovf,
-                        aidx, sdst.astype(jnp.uint32), smsg, svalid)
-                new_tw = (tw & ~(jnp.uint32(1) << aidx)) \
-                    | (keep.astype(jnp.uint32) << aidx)
-                t_parts = [t_actors, t_lens, t_msgs.reshape(-1),
-                           new_tw[None], crash]
-                if hw:
-                    t_parts.append(t_hist)
-                t_row = jnp.concatenate(t_parts).astype(jnp.uint32)
-                row_out = jnp.where(is_timeout, t_row, row_out)
-                valid = jnp.where(is_timeout, tbit, valid)
-                overflow = jnp.where(is_timeout, t_ovf, overflow)
-
-            overflow = valid & overflow
-            row_out = jnp.where(overflow,
-                                jnp.full_like(row_out, 0xDEADBEEF),
-                                row_out)
-            valid = valid & ~overflow & self.packed_boundary(row_out)
-            return row_out, valid, overflow
-
-        return jax.vmap(one_action)(jnp.arange(self.max_actions))
+        overflow = valid & overflow
+        row_out = jnp.where(overflow, jnp.full_like(row_out, 0xDEADBEEF),
+                            row_out)
+        valid = valid & ~overflow & self.packed_boundary(row_out)
+        return row_out, valid, overflow
 
     def _packed_step_unordered(self, words):
         import jax

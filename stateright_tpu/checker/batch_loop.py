@@ -331,9 +331,9 @@ class BatchLoop:
             progressed = gen > 0 or log_n > st.log_n
             st.log_n = log_n
             if P:
-                hit = row[15:15 + P].astype(bool)
-                hi = row[15 + P:15 + 2 * P].astype(np.uint64)
-                lo = row[15 + 2 * P:15 + 3 * P].astype(np.uint64)
+                hit = row[16:16 + P].astype(bool)
+                hi = row[16 + P:16 + 2 * P].astype(np.uint64)
+                lo = row[16 + 2 * P:16 + 3 * P].astype(np.uint64)
                 for i, prop in enumerate(self._properties):
                     if hit[i] and prop.name not in st.disc:
                         st.disc[prop.name] = int(

@@ -306,6 +306,11 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
     device_prop_idx = [i for i in range(prop_count) if i not in host_idx]
     fa = fmax * n_actions
     kmax = min(kmax, fa)
+    # a model whose lane validity needs no successor row (the ordered
+    # actor step, ``offers_lane_step``) has its rows built AFTER stage
+    # one: only the kraw kept lanes, from their parent rows, instead of
+    # all fmax * max_actions lanes (and their F*A flat relayout) first
+    lanes = bool(getattr(model, "offers_lane_step", False))
     # two-stage candidate compaction: raw-valid lanes compact to the
     # kraw buffer (where hashing and in-batch dedup run); dedup
     # SURVIVORS compact again to the narrower kmax buffer for the table
@@ -350,9 +355,9 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
 
     def make_cond(lo_water, hi_water):
         def cond(state):
-            # the fused+cc state threads (carry, ring_hi, ring_lo, cch)
-            # ahead of the scalars; index from both ends so one cond
-            # covers both layouts
+            # the state threads the materialized count (and, fused+cc,
+            # ring_hi, ring_lo, cch) between the carry and the scalars;
+            # index from both ends so one cond covers both layouts
             c, target_remaining, grow_limit = (state[0], state[-2],
                                                state[-1])
             avail = c.q_tail - c.q_head
@@ -382,7 +387,8 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
 
     def make_step(fmax_b: int, kraw_b: int, kfin_b: int):
         def step(state):
-            c, target_remaining, grow_limit = state
+            # (carry, materialized, target_remaining, grow_limit)
+            c, target_remaining, grow_limit = state[0], state[2], state[3]
             sl = jax.lax.dynamic_slice(
                 c.q, (c.q_head, 0), (fmax_b, width + 3))
             frontier = sl[:, :width]
@@ -396,7 +402,8 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
             # child fingerprints are deferred to the narrow buffer below
             exp = expand_frontier(model, frontier, fvalid, ebits,
                                   eventually_idx, symmetry=symmetry,
-                                  pfp=pfp, child_fp=False)
+                                  pfp=pfp, child_fp=False,
+                                  materialize=not lanes)
             cvalid = exp.cvalid
             gen_count = cvalid.sum(dtype=jnp.int32)
             vcount = gen_count
@@ -457,6 +464,13 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
             # rows block-written past an un-advanced tail are never
             # observed: the tail only moves on commit and the next
             # commit overwrites them.
+            def kept_rows(src):
+                # the rows of the kept lanes (flat index row * A + action)
+                if lanes:
+                    return jax.vmap(model.packed_step_lane)(
+                        frontier[src // n_actions], src % n_actions)[0]
+                return exp.flat[src]
+
             if hint_eff:
                 # PER-ROW stage one: hint_eff rounds of argmax-and-mask
                 # over each row's action axis (pure elementwise/reduce —
@@ -479,7 +493,7 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
                 j_table = jnp.stack(cols, axis=1)  # (F, hint)
                 src = (jnp.arange(fmax_b, dtype=jnp.int32)[:, None]
                        * n_actions + j_table).reshape(-1)
-                rows_k = exp.flat[src]
+                rows_k = kept_rows(src)
                 rvalid = (jnp.arange(hint_eff, dtype=jnp.int32)[None, :]
                           < rcnt[:, None]).reshape(-1)
                 par3 = jnp.broadcast_to(
@@ -488,7 +502,7 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
             else:
                 src = shrink_indices(cvalid, kraw_b)
                 rvalid = jnp.arange(kraw_b, dtype=jnp.int32) < vcount
-                rows_k = exp.flat[src]
+                rows_k = kept_rows(src)
                 ridx = src // n_actions  # parent frontier row per lane
                 # parent-side columns gathered in ONE 3-column pass
                 par3 = jnp.stack([exp.ebits, p_whi, p_wlo], axis=1)[ridx]
@@ -607,7 +621,7 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
                 c.log, n_all[:, log_off:log_off + c.log.shape[1]],
                 (c.log_n, 0))
 
-            return c._replace(
+            nc = c._replace(
                 q=q,
                 q_head=c.q_head + take,
                 q_tail=c.q_tail + cnt,
@@ -628,6 +642,8 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
                 # dedup telemetry (kovf iterations committed nothing)
                 pdh=c.pdh + jnp.where(kovf, 0, vcount - dcount),
                 prb=c.prb + jnp.where(kovf, 0, t_rounds))
+            # the full-width rows this iteration built (a static count)
+            return nc, (rows_k.shape[0] if lanes else fmax_b * n_actions)
         return step
 
     def make_fused_step(fmax_b: int):
@@ -653,10 +669,10 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
 
         def step(state):
             if cc:
-                (c, rhi, rlo, cch, target_remaining,
+                (c, rhi, rlo, cch, mat, target_remaining,
                  grow_limit) = state
             else:
-                c, target_remaining, grow_limit = state
+                c, target_remaining, grow_limit = state[0], state[2], state[3]
                 rhi = rlo = None
             sl = jax.lax.dynamic_slice(
                 c.q, (c.q_head, 0), (fmax_b, width + 3))
@@ -718,8 +734,8 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
                 prb=c.prb + out.rounds)
             if cc:
                 return (nc, out.ring_hi, out.ring_lo, cch + out.cch,
-                        target_remaining, grow_limit)
-            return nc
+                        mat + fa_b, target_remaining, grow_limit)
+            return nc, fa_b
         return step
 
     # thin BFS frontiers (a few hundred pending states) are common at the
@@ -753,7 +769,8 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
         if full_state:
             return step  # the fused+cc step returns the whole state
         def body(state):
-            return (step(state), state[1], state[2])
+            nc, built = step(state)
+            return (nc, state[1] + built, state[2], state[3])
         return body
 
     def run_loops(state):
@@ -779,13 +796,13 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
             make_cond(jnp.int32(0), imax),
             make_body(step_large), state)
 
-    def base_stats(out):
+    def base_stats(out, mat):
         # ALL host-read scalars packed into ONE uint32 vector: every
         # device->host transfer is a sync, and a per-leaf device_get
         # of a dozen scalars is a dozen of them. Layout
         # (tpu.py unpacks positionally — keep in sync):
         # [q_head, q_tail, log_n, gen, ovf, xovf, kovf, h_n, hovf,
-        #  vmax, dmax, rmax, e_n, pdh, prb,
+        #  vmax, dmax, rmax, e_n, pdh, prb, mat,
         #  disc_hit[P], disc_hi[P], disc_lo[P],
         #  recent queue row (W+3),
         #  then hist window (hist_on) | cc ring hits (fused+cc)]
@@ -801,7 +818,8 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
                        out.h_n,
                        out.hovf.astype(jnp.int32),
                        out.vmax, out.dmax, out.rmax,
-                       out.e_n, out.pdh, out.prb]).astype(jnp.uint32),
+                       out.e_n, out.pdh, out.prb,
+                       mat]).astype(jnp.uint32),
             out.disc_hit.astype(jnp.uint32),
             out.disc_hi, out.disc_lo, recent])
 
@@ -814,12 +832,13 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
             # cache for the whole non-fused matrix (the seed_carry
             # 5-arg caveat, CHANGES.md PR 9). cch (ring hits) is
             # chunk-local telemetry — re-zeroed per dispatch — and
-            # rides the stats vector as one trailing element.
+            # rides the stats vector as one trailing element. So does
+            # the materialized count (loop-local, in base_stats).
             state = run_loops((carry, ring_hi, ring_lo, jnp.int32(0),
-                               target_remaining, grow_limit))
-            out, rhi, rlo, cch = state[0], state[1], state[2], state[3]
+                               jnp.int32(0), target_remaining, grow_limit))
+            out, rhi, rlo, cch, mat = state[:5]
             stats = jnp.concatenate([
-                base_stats(out),
+                base_stats(out, mat),
                 jnp.reshape(cch, (1,)).astype(jnp.uint32)])
             return out, rhi, rlo, stats
         return chunk_cc
@@ -828,9 +847,13 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
         # h_base anchors the representative window at the host's pulled
         # count (NOT this launch's entry h_n), covering everything the
         # whole small/large loop sequence logged
-        state = run_loops((carry, target_remaining, grow_limit))
+        # the loop state threads the chunk-local materialized count
+        # beside the carry, like the ring hits above: a ChunkCarry field
+        # would change every staged program's signature
+        state = run_loops((carry, jnp.int32(0), target_remaining,
+                           grow_limit))
         out = state[0]
-        stats = base_stats(out)
+        stats = base_stats(out, state[1])
         if not hist_on:
             return out, stats
         # window over the representatives logged this chunk: rides the

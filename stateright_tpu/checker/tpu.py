@@ -297,7 +297,10 @@ def auto_fmax(model, shards: int = 1) -> int:
     states, width >= 256) have a much lower knee (~6M lane-words —
     ABD-ordered measured best near fmax=1024 at width 331, round 4): the
     dense successor materialization is bandwidth-bound there, not
-    op-latency-bound. Shared by the single-chip and sharded engines so
+    op-latency-bound. That knee was tuned on dense materialization: the
+    single-chip loop now builds an ordered actor model's rows only for
+    the lanes it keeps (``offers_lane_step``), so its knee may lie
+    elsewhere. Shared by the single-chip and sharded engines so
     the knee is tuned in one place. The floor (1024 rows on a single
     chip, divided across shards down to 256) keeps enough frontier rows
     per iteration to amortize the fixed per-iteration cost."""
@@ -1443,16 +1446,17 @@ class TpuChecker(HostChecker):
             nonlocal seed_ovf, corruption_attempt
             acts: set = set()
             (q_head, q_tail, log_n, gen, ovf, xovf, kovf, h_n, hovf,
-             vmax, dmax, rmax, e_n, pdh, prb) = (
+             vmax, dmax, rmax, e_n, pdh, prb, mat) = (
                 int(stats[0]), int(stats[1]), int(stats[2]),
                 int(stats[3]), bool(stats[4]), bool(stats[5]),
                 bool(stats[6]), int(stats[7]), bool(stats[8]),
                 int(stats[9]), int(stats[10]), int(stats[11]),
-                int(stats[12]), int(stats[13]), int(stats[14]))
-            disc_hit = stats[15:15 + prop_count].astype(bool)
-            disc_hi = stats[15 + prop_count:15 + 2 * prop_count]
-            disc_lo = stats[15 + 2 * prop_count:15 + 3 * prop_count]
-            tail0 = 15 + 3 * prop_count
+                int(stats[12]), int(stats[13]), int(stats[14]),
+                int(stats[15]))
+            disc_hit = stats[16:16 + prop_count].astype(bool)
+            disc_hi = stats[16 + prop_count:16 + 2 * prop_count]
+            disc_lo = stats[16 + 2 * prop_count:16 + 3 * prop_count]
+            tail0 = 16 + 3 * prop_count
             width3 = model.packed_width + 3
             if q_tail > 0:
                 # most recently enqueued state (live Explorer progress)
@@ -1532,6 +1536,8 @@ class TpuChecker(HostChecker):
             # rows this chunk expanded: the head only advances (a
             # re-seed restarts it at 0, and cur with it)
             metrics.inc("expanded", q_head - cur["q_head"])
+            # full-width successor rows the chunk built (chunk-local)
+            metrics.inc("materialized", mat)
             cur.update(q_head=q_head, q_size=q_tail - q_head,
                        q_tail=q_tail, log_n=log_n, e_n=e_n)
             # observed branching (raw / post-dedup), for tuning

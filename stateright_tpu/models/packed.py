@@ -38,6 +38,12 @@ class PackedModel(Model):
     packed_width: int
     #: static upper bound on actions per state
     max_actions: int
+    #: True where the model also gives ``packed_lane_valid(words) ->
+    #: (valid, overflow)`` over every action, with no successor built,
+    #: and ``packed_step_lane(words, a) -> (row, valid, overflow)``, one
+    #: lane of ``packed_step``: the device loop then builds only the rows
+    #: of the lanes it keeps
+    offers_lane_step: bool = False
     #: (offset, width) of the packed columns host-evaluated properties
     #: depend on (None = the whole row). Lets the device engine dedup
     #: states by host-property key before the host evaluates them.

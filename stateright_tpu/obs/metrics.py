@@ -138,6 +138,11 @@ GLOSSARY: Dict[str, str] = {
     "expanded": "frontier rows expanded by the device engine, from the "
                 "queue head's advance per chunk (summed over shards; a "
                 "row re-expanded after a re-seed counts again)",
+    "materialized": "full-width successor rows the device steps built, "
+                    "summed over the chunk loop's iterations and shards: "
+                    "every lane of a dense step (fmax x max_actions), the "
+                    "candidate buffer's lanes where the model builds rows "
+                    "after lane compaction (offers_lane_step)",
     "action_lanes": "successor lanes per expanded row: the model's "
                     "max_actions, the dense action axis (gauge; device "
                     "engines)",

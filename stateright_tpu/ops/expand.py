@@ -23,7 +23,8 @@ from .hash_kernel import fp64_device
 class Expansion(NamedTuple):
     pbits: jax.Array     # bool[F, P]  property bits per frontier row
     ebits: jax.Array     # uint32[F]   eventually-bits after clearing
-    flat: jax.Array      # uint32[F*A, W] children (action-major per row)
+    flat: jax.Array      # uint32[F*A, W] children (action-major per row);
+    #                                  None where none were materialized
     avalid: jax.Array    # bool[F, A]  per-(row, action) validity
     cvalid: jax.Array    # bool[F*A]   child validity (enabled & non-no-op)
     chi: jax.Array       # uint32[F*A] child fingerprints (canonical under
@@ -48,7 +49,8 @@ def eventually_indices(properties) -> list:
 def expand_frontier(model, frontier, fvalid, ebits,
                     eventually_idx: Sequence[int],
                     symmetry: bool = False, pfp=None,
-                    child_fp: bool = True) -> Expansion:
+                    child_fp: bool = True,
+                    materialize: bool = True) -> Expansion:
     """Evaluate properties and expand one frontier batch (pure JAX).
 
     With ``symmetry``, fingerprints are taken over
@@ -79,7 +81,14 @@ def expand_frontier(model, frontier, fvalid, ebits,
     skipped (returned as None): callers on the gather-early path compact
     valid lanes to the narrow candidate buffer FIRST and hash there —
     hashing (and canonicalizing, under symmetry) at the full ``F*A`` lane
-    width was one of the widest per-iteration op groups."""
+    width was one of the widest per-iteration op groups.
+
+    With ``materialize=False`` (models with ``offers_lane_step``, and
+    ``child_fp=False``) no successor is built: ``flat`` is None, and
+    validity, terminal rows and encoding overflow come from
+    ``model.packed_lane_valid`` over every lane, so an overflow on any
+    lane is still fatal. The caller builds the rows of the lanes it
+    keeps with ``model.packed_step_lane``."""
     fcount = frontier.shape[0]
     width = model.packed_width
     pbits = jax.vmap(model.packed_properties)(frontier)
@@ -89,15 +98,19 @@ def expand_frontier(model, frontier, fvalid, ebits,
             sat = sat | jnp.where(pbits[:, i], jnp.uint32(1 << i),
                                   jnp.uint32(0))
         ebits = ebits & ~sat
-    out = jax.vmap(model.packed_step)(frontier)
-    if len(out) == 3:  # models reporting per-action encoding overflow
-        succ, avalid, aovf = out
-        xovf = (aovf & fvalid[:, None]).any()
+    flat = aovf = None
+    if materialize:
+        out = jax.vmap(model.packed_step)(frontier)
+        avalid = out[1]
+        flat = out[0].reshape((-1, width))
+        if len(out) == 3:  # models reporting per-action encoding overflow
+            aovf = out[2]
     else:
-        succ, avalid = out
-        xovf = jnp.bool_(False)
+        assert not child_fp, "child fingerprints need the built rows"
+        avalid, aovf = jax.vmap(model.packed_lane_valid)(frontier)
+    xovf = jnp.bool_(False) if aovf is None \
+        else (aovf & fvalid[:, None]).any()
     avalid = avalid & fvalid[:, None]
-    flat = succ.reshape((-1, width))
     if symmetry:
         phi, plo = pfp if pfp is not None \
             else fp64_device(jax.vmap(model.packed_representative)(frontier))

@@ -487,7 +487,8 @@ class ShardedTpuChecker(TpuChecker):
             bmax = int(stats[3 * D + 6])
             pdh = int(stats[3 * D + 7])
             prb = int(stats[3 * D + 8])
-            base = 3 * D + 9
+            mat = int(stats[3 * D + 9])
+            base = 3 * D + 10
             disc_hit = stats[base:base + prop_count].astype(bool)
             disc_hi = stats[base + prop_count:base + 2 * prop_count]
             disc_lo = stats[base + 2 * prop_count:base + 3 * prop_count]
@@ -619,6 +620,7 @@ class ShardedTpuChecker(TpuChecker):
             # rows this chunk expanded, over the shards: each head only
             # advances (a re-seed restarts them at 0, and cur with them)
             metrics.inc("expanded", int((q_head - cur["q_head"]).sum()))
+            metrics.inc("materialized", mat)
             cur.update(q_head=q_head, q_tail=q_tail, log_n=log_n,
                        e_n=e_n)
             metrics.observe_max("vmax", vmax)

@@ -334,10 +334,12 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
             interpret=fused_interpret)
 
       def step(state):
+        # the materialized count (chunk-local, like cchv) rides the loop
+        # state beside the carry
         if fused and cc:
-            c, rhi, rlo, cchv, target_remaining, grow_limit = state
+            c, rhi, rlo, cchv, mat, target_remaining, grow_limit = state
         else:
-            c, target_remaining, grow_limit = state
+            c, mat, target_remaining, grow_limit = state
             rhi = rlo = cchv = None
         me = lax.axis_index(axis).astype(jnp.uint32)
         me_i = me.astype(jnp.int32)
@@ -658,9 +660,11 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
             gen=gen, ovf=ovf, xovf=xovf, kovf=kovf, vmax=vmax,
             dmax=dmax, bmax=bmax_c, steps=steps, go=go, pavail=pavail,
             pdh=pdh, prb=prb)
+        # every shard builds all fmax_b * max_actions lanes' rows
+        mat = mat + fmax_b * n_actions * D
         if fused and cc:
-            return (nc, rhi, rlo, cchv, target_remaining, grow_limit)
-        return (nc, target_remaining, grow_limit)
+            return (nc, rhi, rlo, cchv, mat, target_remaining, grow_limit)
+        return (nc, mat, target_remaining, grow_limit)
       return step
 
     step_large = make_step(fmax, kraw, kmax)
@@ -701,11 +705,11 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
                      carry.steps, target_remaining, grow_limit)
         return carry._replace(go=go, pavail=pm[0])
 
-    def base_stats(out):
+    def base_stats(out, mat):
         # ONE replicated sync vector for everything the host reads per
         # chunk (layout parsed by parallel/engine.py — keep in sync):
         # [q_head[D], q_tail[D], log_n[D],
-        #  gen, ovf, xovf, kovf, vmax, dmax, bmax, pdh, prb,
+        #  gen, ovf, xovf, kovf, vmax, dmax, bmax, pdh, prb, mat,
         #  disc_hit[P], disc_hi[P], disc_lo[P], e_n[D],
         #  cc ring hits (fused+cc only)]
         hs = lax.all_gather(out.q_head, axis, tiled=True)
@@ -721,7 +725,7 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
                        out.kovf.astype(jnp.int32),
                        out.vmax, out.dmax,
                        out.bmax, out.pdh,
-                       out.prb]).astype(jnp.uint32),
+                       out.prb, mat]).astype(jnp.uint32),
             out.disc_hit.astype(jnp.uint32),
             out.disc_hi, out.disc_lo, es.astype(jnp.uint32)])
 
@@ -732,14 +736,16 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
             # the cross-chunk ring threads OUTSIDE ShardedCarry (adding
             # carry fields would change the staged programs' traced
             # signatures — the persistent-compile-cache caveat); cch is
-            # chunk-local telemetry re-zeroed per dispatch
+            # chunk-local telemetry re-zeroed per dispatch, and so is
+            # the materialized count
             state = run_loops((
                 entry_carry(carry, target_remaining, grow_limit),
-                rhi, rlo, jnp.int32(0), target_remaining, grow_limit))
+                rhi, rlo, jnp.int32(0), jnp.int32(0), target_remaining,
+                grow_limit))
             out, rhi2, rlo2 = state[0], state[1], state[2]
             cch = lax.psum(state[3], axis)
             stats = jnp.concatenate([
-                base_stats(out),
+                base_stats(out, state[4]),
                 jnp.reshape(cch, (1,)).astype(jnp.uint32)])
             return out, rhi2, rlo2, stats
 
@@ -753,8 +759,8 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
     def local_chunk(carry, target_remaining, grow_limit):
         state = run_loops((
             entry_carry(carry, target_remaining, grow_limit),
-            target_remaining, grow_limit))
-        return state[0], base_stats(state[0])
+            jnp.int32(0), target_remaining, grow_limit))
+        return state[0], base_stats(state[0], state[1])
 
     # check_vma=False throughout: the hash kernel's scan carry starts
     # axis-invariant and becomes varying; skipping the varying-manual-
