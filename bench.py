@@ -151,9 +151,7 @@ def _compact_metrics(ck):
     for k in ("chunks", "levels", "grows", "hgrows", "kovfs",
               "compiles", "retries", "failovers", "degrades",
               "autosaves", "engine", "shard_balance", "mesh_shards",
-              "fused_chunks", "fused_unsupported",
-              "predup_hits", "probe_rounds", "cc_dedup_hits",
-              "cc_dedup_capacity", "spills", "evicted_keys",
+              "predup_hits", "probe_rounds", "spills", "evicted_keys",
               "host_probe_hits", "host_tier_keys"):
         if prof.get(k):
             m[k] = prof[k]
@@ -218,24 +216,12 @@ def _sampled(name, mk, value=None, unit="uniq/s", warmups=2,
            "uniq": uniq,
            "gen": gen,
            # generated-per-unique ratio: the duplicate-expansion cost
-           # the fused kernel attacks (ROADMAP item 1 names it as the
-           # fusion proxy — rows generated, hashed and probed per state
-           # actually kept)
+           # (rows generated, hashed and probed per state actually kept)
            "gen_per_uniq": round(gen / uniq, 3) if uniq else None,
-           # which dedup path produced this rate — the trajectory must
-           # never silently mix fused and staged numbers
-           "fused": bool(ck.profile().get("fused")),
            "samples": samples,
            # last sample's metrics snapshot: explains the round
            # (stalls, growth storms), not just ranks it
            "metrics": _compact_metrics(ck)}
-    cch = int(ck.profile().get("cc_dedup_hits") or 0)
-    if cch and uniq:
-        # the duplicate-expansion factor REMAINING after the
-        # cross-chunk ring killed its share in-register: the measurable
-        # gen/uniq reduction the dedup cache buys (gen itself is
-        # host-engine generation semantics and cannot shrink)
-        row["gen_per_uniq_cc"] = round((gen - cch) / uniq, 3)
     if extra_fn is not None:
         row.update(extra_fn(ck))
     print(json.dumps(row), file=sys.stderr)
@@ -1118,28 +1104,6 @@ def _run_workloads(contract: dict, backend: str) -> None:
             contract["vs_baseline"] = round(tpu_rate / host_rate, 2)
     if sync_rate is not None:
         contract["pipeline"]["off"] = round(sync_rate, 1)
-
-    # --- fused pipeline + cross-chunk dedup ring (runs on CPU too) -----
-    # A duplicate-heavy 2pc space through the fused kernels with the cc
-    # ring on: the row's gen_per_uniq vs gen_per_uniq_cc pair is the
-    # measured reduction the dedup cache buys, and cc_dedup_hits rides
-    # the metrics snapshot. 'auto'+fused_attempt: on TPU this attempts
-    # the real Pallas build (a failed build raises, and the guarded row
-    # records the classified error); on CPU it runs the interpreter.
-    from stateright_tpu.models.twopc import TwoPhaseSys
-    cc_n = 3 if SMOKE else 4
-
-    def fused_cc_run():
-        return (TwoPhaseSys(cc_n).checker()
-                .tpu_options(capacity=1 << 13, race=False,
-                             fused="auto", fused_attempt=True,
-                             **_retry_opts())
-                .spawn_tpu().join())
-
-    _guarded(
-        "fused-cc-2pc",
-        lambda: _sampled(f"2pc{cc_n} fused cc-dedup full",
-                         fused_cc_run, warmups=1))
 
     # --- the rest of the reference bench.sh matrix ---------------------
     # context only; each workload is individually guarded, so a flake

@@ -23,8 +23,8 @@ Default run (one chip):
 owning states.
 
 Every run fails on a non-zero ``failovers``/``retries``/``degrades``/
-``spills`` or ``chunks == 0`` (the host racer answered); a fused-kernel
-build failure raises rather than falling back, so it fails the run too. Speeds printed here are informational, not metrics. The
+``spills`` or ``chunks == 0`` (the host racer answered). Speeds printed
+here are informational, not metrics. The
 last line of stdout is the JSON verdict; everything else comes first.
 """
 
@@ -131,7 +131,6 @@ def run_device(label: str, builder, clock: CompileClock):
         compile_s=c1 - c0, cache_hits=h1 - h0, cache_misses=m1 - m0,
         unique=unique, uniq_per_s=unique / wall if wall else None,
         chunks=int(prof.get("chunks", 0)),
-        fused_chunks=int(prof.get("fused_chunks", 0)),
         engine=prof.get("engine", "device"),
         mesh_shards=prof.get("mesh_shards"),
         peak_bytes_in_use=peaks[0] if len(peaks) == 1 else peaks)

@@ -83,23 +83,28 @@ class CheckerBuilder:
         self.sound_eventually_ = True
         return self
 
+    #: every ``tpu_options`` key an engine reads (``checker/``,
+    #: ``parallel/``, ``service/``); any other key raises, so a
+    #: misspelt or removed option fails loudly instead of running the
+    #: default path
+    TPU_OPTION_KEYS = frozenset({
+        "artifact_dir", "audit", "autosave", "autosave_interval",
+        "backoff", "blame_after", "capacity", "chunk_deadline",
+        "chunk_steps", "corrupt_hook", "degrade", "exchange", "failover",
+        "fault_hook", "flight", "flight_path", "fmax", "grow_at", "hcap",
+        "hint", "hmax", "host_map", "host_table_capacity", "job_id",
+        "kb", "kmax", "kraw", "max_capacity", "max_segment",
+        "max_spills", "mesh", "mesh_axis", "min_mesh", "mode",
+        "pipeline", "profile_dir", "race", "race_budget", "resumable",
+        "retries", "retry_seed", "run_id", "spill", "spill_frac",
+        "trace",
+    })
+
     def tpu_options(self, **options) -> "CheckerBuilder":
         """Tuning knobs for ``spawn_tpu`` (table capacity, batch caps,
-        mesh selection, ...). Notable:
+        mesh selection, ...). A key outside :attr:`TPU_OPTION_KEYS`
+        raises ``ValueError`` naming it. Notable:
 
-        * ``fused`` (default ``False``, the staged path) selects the
-          fused Pallas expand→fingerprint→dedup kernel
-          (``ops/fused.py``; README § Fused device kernel), which does
-          not lower for TPU v5e yet. ``'auto'`` tries the Pallas build
-          on TPU backends and RAISES ``FusedUnavailable`` (naming the
-          classified cause) when it fails; off TPU, ``'auto'`` resolves
-          to staged without an attempt (``fused_attempt=True`` forces
-          the attempt through the interpreter — a testing/debug knob).
-          ``True`` forces the fused build (interpret mode off TPU — how
-          the CPU parity suite pins bit-identical behavior).
-          Configurations outside the fused support matrix
-          (sound-eventually, host-evaluated properties, ``hint=``) stay
-          staged under ``'auto'`` and raise under ``True``;
         * ``pipeline`` (default ``True``) double-buffers the chunk
           loop — chunk N+1 is dispatched while the host consumes chunk
           N's stats; ``pipeline=False`` forces the synchronous loop
@@ -143,6 +148,11 @@ class CheckerBuilder:
           D -> D/2 -> ... -> single chip, before any host fallback;
           ``failover=False`` opts a raced run out of the final
           device->host rung."""
+        unknown = sorted(set(options) - self.TPU_OPTION_KEYS)
+        if unknown:
+            raise ValueError(
+                f"unknown tpu_options key(s) {', '.join(unknown)}; "
+                f"accepted: {', '.join(sorted(self.TPU_OPTION_KEYS))}")
         self.tpu_options_.update(options)
         return self
 
@@ -264,13 +274,6 @@ class Checker:
                 parts.append(f"mesh={int(prof['mesh_shards'])}")
             if "fault_device" in prof:
                 parts.append(f"fault_device={int(prof['fault_device'])}")
-        if prof.get("fused_unsupported"):
-            # a fused='auto' run stayed staged because the config is
-            # outside the kernel's support matrix — name the reason
-            # (also a one-time fused_unsupported trace event)
-            reason = getattr(self, "_fused_unsupported_reason", None)
-            parts.append("fused=unsupported"
-                         + (f" ({reason})" if reason else ""))
         attribution = prof.get("attribution") or {}
         wall = sum(attribution.values())
         if wall > 0:

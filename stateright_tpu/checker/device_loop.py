@@ -215,9 +215,7 @@ def model_cache_key(model):
 def build_chunk_fn(model, qcap: int, capacity: int, fmax: int, kmax: int,
                    symmetry: bool = False, sound: bool = False,
                    hcap: int = 0, n_init: int = 0, kraw: int = 0,
-                   hint_eff: int = 0, ecap: int = 0,
-                   fused: bool = False, fused_interpret: bool = False,
-                   cc: int = 0):
+                   hint_eff: int = 0, ecap: int = 0):
     """Compile the K-level chunk runner for fixed buffer shapes.
 
     Returned callable: ``chunk(carry, target_remaining, grow_limit,
@@ -248,14 +246,13 @@ def build_chunk_fn(model, qcap: int, capacity: int, fmax: int, kmax: int,
     """
     mkey = model_cache_key(model)
     key = (mkey, qcap, capacity, fmax, kmax, symmetry, sound, hcap,
-           n_init, kraw, hint_eff, ecap, fused, fused_interpret, cc)
+           n_init, kraw, hint_eff, ecap)
     if mkey is not None:
         cached = _CHUNK_CACHE.get(key)
         if cached is not None:
             return cached
     fn = _build_chunk_fn(model, qcap, capacity, fmax, kmax, symmetry,
-                         sound, hcap, n_init, kraw, hint_eff, ecap,
-                         fused, fused_interpret, cc)
+                         sound, hcap, n_init, kraw, hint_eff, ecap)
     if mkey is not None:
         _CHUNK_CACHE[key] = fn
     return fn
@@ -264,36 +261,23 @@ def build_chunk_fn(model, qcap: int, capacity: int, fmax: int, kmax: int,
 def _build_chunk_fn(model, qcap: int, capacity: int, fmax: int, kmax: int,
                     symmetry: bool, sound: bool = False, hcap: int = 0,
                     n_init: int = 0, kraw: int = 0, hint_eff: int = 0,
-                    ecap: int = 0, fused: bool = False,
-                    fused_interpret: bool = False, cc: int = 0):
+                    ecap: int = 0):
     return jax.jit(
         build_chunk_core(model, qcap, capacity, fmax, kmax, symmetry,
-                         sound, hcap, n_init, kraw, hint_eff, ecap,
-                         fused, fused_interpret, cc),
-        # the fused+cc chunk additionally donates the cross-chunk ring
-        # halves it threads through (args 1 and 2)
-        donate_argnums=(0, 1, 2) if (fused and cc) else (0,))
+                         sound, hcap, n_init, kraw, hint_eff, ecap),
+        donate_argnums=(0,))
 
 
 def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
                      kmax: int, symmetry: bool, sound: bool = False,
                      hcap: int = 0, n_init: int = 0, kraw: int = 0,
-                     hint_eff: int = 0, ecap: int = 0,
-                     fused: bool = False, fused_interpret: bool = False,
-                     cc: int = 0):
+                     hint_eff: int = 0, ecap: int = 0):
     """The UN-jitted chunk program: ``chunk(carry, target_remaining,
     grow_limit, h_base) -> (carry, stats)``. ``build_chunk_fn`` wraps
     it in the solo engines' donating ``jax.jit``; the batch engine
     (``checker/batch_loop.py``) instead maps it over a LANE axis with
     ``jax.vmap`` — one compiled program advancing many small same-shape
     jobs, each lane carrying its own queue/table/log slices."""
-    if fused:
-        # support matrix (ops/fused.py supports()): the engines route
-        # sound / host-property / hint configs to the staged build
-        assert not sound and not hcap and not hint_eff and not ecap, \
-            "fused chunk build outside its support matrix"
-    else:
-        assert not cc, "cc dedup ring is a fused-path structure"
     n_actions = model.max_actions
     width = model.packed_width
     properties = model.properties()
@@ -346,20 +330,17 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
         # small constant. Hitting the bound reports hovf (the growth
         # signal) instead of spinning out the default 4096 rounds.
         h_rounds = min(4096, hcap + 64)
-    # the queue slice must cover BOTH the widest append (kmax rows; the
-    # fused step appends straight from the F*A lane mask — no candidate
-    # staging — so its margin is fa) and the frontier dequeue (fmax rows
-    # — dynamic_slice would silently CLAMP its start near the end of the
-    # queue, re-expanding consumed rows and skipping pending ones)
-    qmargin = max(fa, fmax) if fused else max(kmax, fmax)
+    # the queue slice must cover BOTH the widest append (kmax rows) and
+    # the frontier dequeue (fmax rows — dynamic_slice would silently
+    # CLAMP its start near the end of the queue, re-expanding consumed
+    # rows and skipping pending ones)
+    qmargin = max(kmax, fmax)
 
     def make_cond(lo_water, hi_water):
         def cond(state):
-            # the state threads the materialized count (and, fused+cc,
-            # ring_hi, ring_lo, cch) between the carry and the scalars;
-            # index from both ends so one cond covers both layouts
-            c, target_remaining, grow_limit = (state[0], state[-2],
-                                               state[-1])
+            # the state threads the materialized count between the
+            # carry and the scalars
+            c, _, target_remaining, grow_limit = state
             avail = c.q_tail - c.q_head
             # [lo, hi] is the loop's frontier-size window: the small loop
             # (hi = fmax_small) yields once the frontier outgrows it, the
@@ -646,98 +627,6 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
             return nc, (rows_k.shape[0] if lanes else fmax_b * n_actions)
         return step
 
-    def make_fused_step(fmax_b: int):
-        """The fused analog of ``make_step``: ONE Pallas kernel
-        (ops/fused.py) expands, fingerprints, evaluates the property
-        predicates (discovery lanes flagged in-register — only the
-        per-property sticky registers leave the kernel), pre-dedups
-        (against the in-batch arena AND the cross-chunk recent-key
-        ring, when ``cc``) and probes the visited table — duplicate
-        lanes die inside the kernel, so there is no kraw/kmax candidate
-        staging (and no kovf protocol: appends gather the fresh-lane
-        mask at the raw F*A width, covered by the fa queue margin).
-        Everything after the kernel — the sticky discovery merge, the
-        candidate-matrix assembly for the two block appends — is the
-        staged code on the kernel's outputs."""
-        from ..ops.fused import build_fused_block_fn
-
-        blk = build_fused_block_fn(model, fmax_b, capacity,
-                                   symmetry=symmetry, probe=True,
-                                   interpret=fused_interpret,
-                                   props=bool(prop_count), cc=cc)
-        fa_b = fmax_b * n_actions
-
-        def step(state):
-            if cc:
-                (c, rhi, rlo, cch, mat, target_remaining,
-                 grow_limit) = state
-            else:
-                c, target_remaining, grow_limit = state[0], state[2], state[3]
-                rhi = rlo = None
-            sl = jax.lax.dynamic_slice(
-                c.q, (c.q_head, 0), (fmax_b, width + 3))
-            frontier = sl[:, :width]
-            ebits = sl[:, width]
-            phi, plo = sl[:, width + 1], sl[:, width + 2]
-            take = jnp.minimum(c.q_tail - c.q_head, fmax_b)
-            fvalid = jnp.arange(fmax_b, dtype=jnp.int32) < take
-
-            out = blk(frontier, ebits, fvalid, c.key_hi, c.key_lo,
-                      pfp=(phi, plo) if prop_count else None,
-                      ring=(rhi, rlo) if cc else None)
-            vcount = out.cvalid.sum(dtype=jnp.int32)
-            dcount = out.dvalid.sum(dtype=jnp.int32)
-            cnt = out.inserted.sum(dtype=jnp.int32)
-
-            disc_hit, disc_hi, disc_lo = c.disc_hit, c.disc_hi, c.disc_lo
-            if prop_count:
-                # in-kernel property eval: the kernel's per-call sticky
-                # registers merge into the carry with the same
-                # first-hit-wins rule the staged path uses
-                keep = disc_hit | ~out.disc_hit
-                disc_hi = jnp.where(keep, disc_hi, out.disc_hi)
-                disc_lo = jnp.where(keep, disc_lo, out.disc_lo)
-                disc_hit = disc_hit | out.disc_hit
-
-            # parent-side columns broadcast along the action axis;
-            # assemble_candidates keeps the staged column layout so the
-            # queue and log blocks stay the same contiguous slices
-            ceb = jnp.repeat(out.ebits, n_actions)
-            par_hi = jnp.repeat(phi, n_actions)
-            par_lo = jnp.repeat(plo, n_actions)
-            cand, log_off = assemble_candidates(
-                out.flat, ceb, out.chi, out.clo, par_hi, par_lo,
-                out.ohi, out.olo, width, symmetry, False)
-            src3 = shrink_indices(out.inserted, fa_b)
-            n_all = cand[src3]
-            q = jax.lax.dynamic_update_slice(
-                c.q, n_all[:, :width + 3], (c.q_tail, 0))
-            log = jax.lax.dynamic_update_slice(
-                c.log, n_all[:, log_off:log_off + c.log.shape[1]],
-                (c.log_n, 0))
-            nc = c._replace(
-                q=q, q_head=c.q_head + take, q_tail=c.q_tail + cnt,
-                key_hi=out.key_hi, key_lo=out.key_lo,
-                log=log, log_n=c.log_n + cnt,
-                gen=c.gen + vcount,
-                ovf=c.ovf | out.ovf,
-                disc_hit=disc_hit, disc_hi=disc_hi, disc_lo=disc_lo,
-                xovf=c.xovf | out.xovf,
-                steps=c.steps - 1,
-                vmax=jnp.maximum(c.vmax, vcount),
-                dmax=jnp.maximum(c.dmax, dcount),
-                # dvalid already excludes ring hits, so the in-batch
-                # share is (raw - survivors - ring hits) — keeps
-                # predup_hits bit-identical to the staged counter while
-                # cc_dedup_hits rides its own stats slot
-                pdh=c.pdh + (vcount - dcount - out.cch),
-                prb=c.prb + out.rounds)
-            if cc:
-                return (nc, out.ring_hi, out.ring_lo, cch + out.cch,
-                        mat + fa_b, target_remaining, grow_limit)
-            return nc, fa_b
-        return step
-
     # thin BFS frontiers (a few hundred pending states) are common at the
     # start and tail of every search, and for narrow models they dominate
     # the iteration count; paying the full fmax*max_actions lane width for
@@ -750,24 +639,15 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
     fa_small = fmax_small * n_actions
     kraw_small = fmax_small * hint_eff if hint_eff \
         else min(fa_small, kraw)
-    if fused:
-        step_large = make_fused_step(fmax)
-        if two_size:
-            step_small = make_fused_step(fmax_small)
-    else:
-        step_large = make_step(fmax, kraw, kmax)
-        if two_size:
-            # the small step's raw bound is fa_small itself; its
-            # stage-two buffer shrinks with kmax but never below what
-            # dedup can survive
-            step_small = make_step(fmax_small, kraw_small,
-                                   min(kmax_small, kraw_small))
-
-    full_state = bool(fused and cc)
+    step_large = make_step(fmax, kraw, kmax)
+    if two_size:
+        # the small step's raw bound is fa_small itself; its stage-two
+        # buffer shrinks with kmax but never below what dedup can
+        # survive
+        step_small = make_step(fmax_small, kraw_small,
+                               min(kmax_small, kraw_small))
 
     def make_body(step):
-        if full_state:
-            return step  # the fused+cc step returns the whole state
         def body(state):
             nc, built = step(state)
             return (nc, state[1] + built, state[2], state[3])
@@ -805,7 +685,7 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
         #  vmax, dmax, rmax, e_n, pdh, prb, mat,
         #  disc_hit[P], disc_hi[P], disc_lo[P],
         #  recent queue row (W+3),
-        #  then hist window (hist_on) | cc ring hits (fused+cc)]
+        #  then hist window (hist_on)]
         # the most recently enqueued state's queue row rides the sync
         # for free (the Explorer decodes it as live progress — the
         # chunk loop has no per-state visitation to sample from)
@@ -823,33 +703,13 @@ def build_chunk_core(model, qcap: int, capacity: int, fmax: int,
             out.disc_hit.astype(jnp.uint32),
             out.disc_hi, out.disc_lo, recent])
 
-    if full_state:
-        def chunk_cc(carry: ChunkCarry, ring_hi, ring_lo,
-                     target_remaining, grow_limit, h_base):
-            # the cross-chunk dedup ring threads OUTSIDE ChunkCarry:
-            # adding carry fields would change the STAGED programs'
-            # traced signatures and invalidate the persistent compile
-            # cache for the whole non-fused matrix (the seed_carry
-            # 5-arg caveat, CHANGES.md PR 9). cch (ring hits) is
-            # chunk-local telemetry — re-zeroed per dispatch — and
-            # rides the stats vector as one trailing element. So does
-            # the materialized count (loop-local, in base_stats).
-            state = run_loops((carry, ring_hi, ring_lo, jnp.int32(0),
-                               jnp.int32(0), target_remaining, grow_limit))
-            out, rhi, rlo, cch, mat = state[:5]
-            stats = jnp.concatenate([
-                base_stats(out, mat),
-                jnp.reshape(cch, (1,)).astype(jnp.uint32)])
-            return out, rhi, rlo, stats
-        return chunk_cc
-
     def chunk(carry: ChunkCarry, target_remaining, grow_limit, h_base):
         # h_base anchors the representative window at the host's pulled
         # count (NOT this launch's entry h_n), covering everything the
         # whole small/large loop sequence logged
         # the loop state threads the chunk-local materialized count
-        # beside the carry, like the ring hits above: a ChunkCarry field
-        # would change every staged program's signature
+        # beside the carry: a ChunkCarry field would change every
+        # program's signature
         state = run_loops((carry, jnp.int32(0), target_remaining,
                            grow_limit))
         out = state[0]

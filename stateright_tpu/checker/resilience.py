@@ -149,8 +149,8 @@ class CorruptionError(RuntimeError):
 
 class CandidateOverflowError(RuntimeError):
     """A wedged ``kovf`` protocol: the candidate-buffer resize made no
-    progress (the fused/sharded pre-mutation abort would rebuild the
-    identical program and abort forever). The message carries a
+    progress (the pre-mutation abort would rebuild the identical
+    program and abort forever). The message carries a
     :data:`CAPACITY_MARKERS` phrase so :func:`classify_error` reports
     CAPACITY, and the retry envelope recovers by growing the k-buffer
     to its bound and re-seeding, instead of surfacing to the user."""

@@ -359,39 +359,6 @@ class TpuChecker(HostChecker):
             "capacity must be a power of two"
         self._max_segment = int(opts.get("max_segment", 1 << 15))
         self._grow_at = float(opts.get("grow_at", 0.55))
-        # fused Pallas expand→fingerprint→dedup kernel (ops/fused.py):
-        # False (default) runs the staged path — neither kernel lowers
-        # for v5e yet (ROADMAP A1); 'auto' builds it where the config is
-        # supported and RAISES on a failed build (never a silent
-        # downgrade); True forces it (interpret mode off TPU, how the
-        # CPU parity suite pins it)
-        self._fused_mode = opts.get("fused", False)
-        if self._fused_mode not in (True, False, "auto"):
-            raise ValueError(
-                f"unknown tpu_options fused {self._fused_mode!r}; "
-                "expected True, False, or 'auto'")
-        # cross-chunk in-kernel dedup tier (ops/fused.py): a small
-        # device-resident recent-key ring probed before the main table,
-        # killing the re-expanded duplicates in-batch dedup cannot see
-        # (2pc7's ~9x gen/uniq). True = default capacity, an int = ring
-        # slots (power of two), False = off. Rides the fused path only.
-        cc_opt = opts.get("cc_dedup", True)
-        if cc_opt is True:
-            from ..ops.fused import CC_DEFAULT
-            self._cc_cap = CC_DEFAULT
-        elif cc_opt is False:
-            self._cc_cap = 0
-        else:
-            self._cc_cap = int(cc_opt)
-            if self._cc_cap & (self._cc_cap - 1) or self._cc_cap < 4:
-                raise ValueError(
-                    f"tpu_options(cc_dedup={cc_opt!r}) must be True, "
-                    "False, or a power-of-two slot count >= 4 (the "
-                    "ring is direct-mapped by the fingerprint hash)")
-        #: why a fused='auto' run stayed staged (None when it fused or
-        #: was never eligible to) — surfaced by report()'s metrics line
-        #: next to the fused_unsupported gauge
-        self._fused_unsupported_reason = None
         # host-evaluated properties (e.g. the linearizability search):
         # declared by the model, evaluated per level on newly inserted
         # states, memoized by model.host_property_key(row)
@@ -588,76 +555,6 @@ class TpuChecker(HostChecker):
                 "must keep the packed fast-path evaluators in lockstep "
                 "(or drop host_property_fns to fall back to decode())")
         return list(fns)
-
-    # --- fused-kernel selection (ops/fused.py) -------------------------
-    def _fused_resolve(self, *, sharded: bool, fmax: int,
-                       capacity: int, probe_lanes: int = 0) -> "tuple":
-        """Resolve ``tpu_options(fused=...)`` into ``(on, interpret)``.
-
-        ``'auto'``: configurations outside the support matrix stay
-        staged — announced by a one-time ``fused_unsupported`` trace
-        event naming the reason plus the ``fused_unsupported`` gauge
-        (so profile()/report() say WHY a run didn't fuse); on a TPU
-        backend the build is attempted via ``ops.fused.verify_build``
-        (and, sharded, the owner-side probe kernel via
-        ``verify_probe_build``, timed under the ``probe_kernel_s``
-        metric; both memoized), and a failure RAISES
-        ``FusedUnavailable`` naming its resilience class — the run
-        never goes on, silently, on a path it was not asked for.
-        Off-TPU, 'auto' resolves to staged without an attempt (the
-        interpreter would be slower than compiled XLA);
-        ``tpu_options(fused_attempt=True)`` forces the attempt with the
-        interpreter. ``True`` forces the fused build: unsupported
-        configurations raise, and build failures surface.
-        """
-        mode = self._fused_mode
-        if mode is False:
-            return False, False
-        from ..ops import fused as fused_mod
-
-        hint = 0 if sharded else int(self._tpu_options.get("hint", 0))
-        reason = fused_mod.supports(
-            self._model, sound=self._sound,
-            host_props=bool(self._host_props), hint=hint)
-        if reason is not None:
-            if mode is True:
-                raise ValueError(
-                    f"tpu_options(fused=True) is unsupported for this "
-                    f"configuration: {reason}")
-            # satellite: say WHY the run stayed staged, once per run
-            if self._fused_unsupported_reason is None:
-                self._fused_unsupported_reason = reason
-                self._metrics.set("fused_unsupported", 1)
-                if self._trace:
-                    self._trace.emit("fused_unsupported", reason=reason)
-            return False, False
-        import jax
-        interpret = jax.default_backend() != "tpu"
-        if mode is True:
-            return True, interpret
-        if interpret and not self._tpu_options.get("fused_attempt"):
-            return False, False
-        try:
-            fused_mod.verify_build(self._model, fmax, capacity,
-                                   symmetry=self._symmetry,
-                                   probe=not sharded,
-                                   interpret=interpret,
-                                   props=bool(self._properties),
-                                   cc=self._cc_cap)
-            if sharded and probe_lanes:
-                # the pipeline's second kernel: its verify/compile wall
-                # time is the probe_kernel_s obs key (kernel_bench
-                # reports the per-dispatch timings)
-                with self._metrics.timed("probe_kernel_s"):
-                    fused_mod.verify_probe_build(
-                        probe_lanes, capacity, interpret=interpret)
-        except Exception as exc:
-            from .resilience import classify_error
-            cause = classify_error(exc).value
-            raise fused_mod.FusedUnavailable(
-                f"tpu_options(fused='auto'): the fused kernel build "
-                f"failed ({cause}): {type(exc).__name__}: {exc}") from exc
-        return True, interpret
 
     # --- resilience plumbing (checker/resilience.py) -------------------
     def _make_shadow(self, shards: int):
@@ -1281,25 +1178,8 @@ class TpuChecker(HostChecker):
             # in-flight seed slowed the loop ~2.5x no longer reproduces
             # with the consolidated carry (q/log matrices, 2-D table);
             # PJRT orders the dependent programs itself.
-        # fused Pallas kernel selection (ops/fused.py): resolved ONCE
-        # per run — 'auto' probes the build and falls back classified
-        fused_on, fused_interp = self._fused_resolve(
-            sharded=False, fmax=fmax, capacity=self._capacity)
-        self._metrics.set("fused", 1 if fused_on else 0)
         self._metrics.set("action_lanes", model.max_actions)
         self._metrics.set("row_words", model.packed_width)
-        # cross-chunk dedup ring (fused path only): the ring halves
-        # thread OUTSIDE the carry — adding ChunkCarry fields would
-        # change every STAGED program's traced signature and invalidate
-        # the persistent compile cache (the seed_carry 5-arg caveat).
-        # cc_ring[0] holds the live (hi, lo) device pair between
-        # dispatches; None = re-zeroed lazily (fresh run, post-fault
-        # re-seed, spill epoch), which is always sound — the ring is a
-        # cache whose misses only cost a table probe.
-        cc_cap = self._cc_cap if fused_on else 0
-        cc_ring = [None]
-        if cc_cap:
-            self._metrics.set("cc_dedup_capacity", cc_cap)
 
         # True until a freshly built chunk program's first call, which
         # traces and compiles it (or loads it from the compile cache)
@@ -1316,25 +1196,9 @@ class TpuChecker(HostChecker):
                                     kmax, symmetry=self._symmetry,
                                     sound=self._sound, hcap=hcap,
                                     n_init=n_init, kraw=kraw,
-                                    hint_eff=hint_eff, ecap=ecap,
-                                    fused=fused_on,
-                                    fused_interpret=fused_interp,
-                                    cc=cc_cap)
+                                    hint_eff=hint_eff, ecap=ecap)
             first_call[0] = True
-            if not cc_cap:
-                return fn
-
-            def chunk_with_ring(carry_, remaining_, grow_, h_base_):
-                if cc_ring[0] is None:
-                    cc_ring[0] = (jnp.zeros((cc_cap,), jnp.uint32),
-                                  jnp.zeros((cc_cap,), jnp.uint32))
-                carry2, rhi, rlo, stats_d = fn(
-                    carry_, cc_ring[0][0], cc_ring[0][1], remaining_,
-                    grow_, h_base_)
-                cc_ring[0] = (rhi, rlo)
-                return carry2, stats_d
-
-            return chunk_with_ring
+            return fn
 
         chunk_fn = mk_chunk()
         pipeline = bool(opts.get("pipeline", True))
@@ -1412,8 +1276,6 @@ class TpuChecker(HostChecker):
             first_call[0] = False
             t_disp = time.perf_counter()
             self._metrics.inc("chunks")
-            if fused_on:
-                self._metrics.inc("fused_chunks")
             if self._metrics.get("first_dispatch_s") is None:
                 self._metrics.set("first_dispatch_s",
                                   t_disp - self._spawn_t0)
@@ -1461,9 +1323,6 @@ class TpuChecker(HostChecker):
             if q_tail > 0:
                 # most recently enqueued state (live Explorer progress)
                 self._recent_row = stats[tail0:tail0 + width3].copy()
-            # cross-chunk dedup ring hits ride one trailing stats
-            # element on the fused+cc path (chunk-local, like gen)
-            cch = int(stats[tail0 + width3]) if cc_cap else 0
             if shadow is not None:
                 # fold this chunk's appends into the host shadow (the
                 # queue/log suffixes are append-only, so gathering them
@@ -1551,8 +1410,6 @@ class TpuChecker(HostChecker):
                 metrics.inc("predup_hits", pdh)
             if prb:
                 metrics.inc("probe_rounds", prb)
-            if cch:
-                metrics.inc("cc_dedup_hits", cch)
             if size_key is not None:
                 _SIZE_MEMO.merge_max(size_key, (vmax, dmax))
             self._state_count += gen
@@ -1573,10 +1430,7 @@ class TpuChecker(HostChecker):
                     dedup_hit=(round(1.0 - new / gen, 4) if gen else 0.0),
                     # hash-table load factor (growth trips near grow_at)
                     load=round(log_n / self._capacity, 4),
-                    vmax=vmax, dmax=dmax,
-                    # cross-chunk ring hits this chunk (fused+cc only;
-                    # trace_report's fused summary totals them)
-                    cc_hits=(cch if cc_cap else None))
+                    vmax=vmax, dmax=dmax)
             disc_fps = _combine64(disc_hi, disc_lo)
             for i, prop in enumerate(properties):
                 if i in host_prop_idx:
@@ -1849,11 +1703,6 @@ class TpuChecker(HostChecker):
                        e_n=0)
             hgrow_pend.update(on=False, hovf=False, h_n=0)
             kovf_pend[:] = [0, 0, 0]
-            # fresh epoch: re-zero the cc ring lazily (its entries stay
-            # sound across a spill, but the epoch invariant — ring ⊆
-            # this epoch's committed inserts — is the simplest one to
-            # keep airtight)
-            cc_ring[0] = None
             self._metrics.inc("spills")
             if ecount:
                 self._metrics.inc("evicted_keys", ecount)
@@ -1935,9 +1784,6 @@ class TpuChecker(HostChecker):
                        e_n=0)
             hgrow_pend.update(on=False, hovf=False, h_n=0)
             kovf_pend[:] = [0, 0, 0]
-            # the old ring arrays may be poisoned by the fault that got
-            # us here; re-zero lazily on the next dispatch
-            cc_ring[0] = None
             chunk_fn = mk_chunk("retry")
 
         fault_attempt = 0
@@ -2020,9 +1866,9 @@ class TpuChecker(HostChecker):
                         self._capacity_terminal(exc, shadow, discoveries)
                     cand = find_candidate_overflow(exc)
                     if cand is not None:
-                        # satellite: the fused/sharded-style kovf abort
-                        # re-routes through the envelope with a GROWN
-                        # k-buffer instead of raising to the user
+                        # a kovf abort (the iteration committed
+                        # nothing) re-routes through the envelope with a
+                        # GROWN k-buffer instead of raising to the user
                         kraw = fa
                         hint_eff = 0
                         kmax = min(max(kmax * 2, cand.dmax

@@ -51,7 +51,7 @@ INTERVENTIONS = {
     "compile": "c", "retry": "R", "watchdog": "W", "autosave": "a",
     "failover": "F", "degrade": "D", "spill": "S", "evict": "S",
     "pause": "P", "recorder_dump": "!",
-    "fused_unsupported": "f", "crash": "C", "restart": "C",
+    "crash": "C", "restart": "C",
     "partition": "C", "violation": "V", "burnin_preempt": "B",
     "host_drop": "H", "mesh_init": "M",
     "host_join": "M", "job_submit": "j", "job_grant": "j",

@@ -200,28 +200,15 @@ GLOSSARY: Dict[str, str] = {
                         "audited boundary, quarantined the liar, and "
                         "replayed — the final digest stays identical "
                         "to an uncorrupted oracle run",
-    "fused_chunks": "chunks dispatched through the fused Pallas "
-                    "expand→fingerprint→dedup kernel (ops/fused.py; "
-                    "tpu_options(fused=...))",
     "predup_hits": "duplicate candidate lanes killed by the in-batch "
-                   "pre-dedup before the visited-table probe — the "
-                   "fusion win's direct measure (compare against "
+                   "pre-dedup before the visited-table probe "
+                   "(compare against "
                    "state_count - unique_state_count, the TOTAL "
                    "duplicate work)",
     "probe_rounds": "visited-table bucket probe rounds taken across "
                     "the run (claim-retry pressure: rising rounds per "
                     "chunk mean duplicate lanes or load factor are "
                     "stressing the open-addressed table)",
-    "cc_dedup_hits": "duplicate lanes killed by the cross-chunk "
-                     "in-kernel recent-key ring BEFORE the table probe "
-                     "(or the sharded exchange) — the tier that "
-                     "attacks the re-expansion share of gen/uniq the "
-                     "in-batch pre-dedup cannot see "
-                     "(tpu_options(cc_dedup=...), fused path only)",
-    "probe_kernel_s": "verify/compile wall time of the owner-side "
-                      "post-exchange probe kernel (the sharded fused "
-                      "pipeline's second Pallas kernel; per-dispatch "
-                      "timings come from tools/kernel_bench.py)",
     # --- soak harness (actor/chaos.py + tools/soak.py) ----------------
     "ops": "client operations completed (returned) during a soak run "
            "against the spawned UDP cluster",
@@ -274,18 +261,6 @@ GLOSSARY: Dict[str, str] = {
                     "error message)",
     "engine": "race winner tag on a raced spawn_tpu profile: 'host' "
               "or 'device'",
-    "fused": "1 when the run's chunk program took the fused Pallas "
-             "path, 0 when staged (bench tags its contract lines from "
-             "this so the perf trajectory can't silently mix paths)",
-    "fused_unsupported": "1 when a fused='auto' run stayed staged "
-                         "because the configuration is outside the "
-                         "kernel's support matrix (the one-time "
-                         "fused_unsupported trace event carries the "
-                         "reason; report()'s metrics line renders it)",
-    "cc_dedup_capacity": "slot count of the cross-chunk recent-key "
-                         "ring when enabled (gauge; "
-                         "tpu_options(cc_dedup=True|N|False), 0/absent "
-                         "when off or staged)",
     "host_tier_keys": "keys resident ONLY in the host tier after the "
                       "most recent spill (decremented as evicted keys "
                       "are rediscovered and re-promoted); 0 until the "
@@ -418,11 +393,11 @@ GLOSSARY: Dict[str, str] = {
 #: keys that are point-in-time GAUGES, not accumulating counters:
 #: :meth:`Metrics.merge` takes the incoming value (last-writer-wins)
 #: instead of summing — summing gauges produced impossible merged
-#: values (``fused=2``, a ``mesh_shards`` no mesh ever had).
+#: values (a summed ``lanes``, a ``mesh_shards`` no mesh ever had).
 GAUGES = frozenset({
-    "mesh_shards", "fused", "engine", "fault_device", "history_ok",
+    "mesh_shards", "engine", "fault_device", "history_ok",
     "shard_balance", "host_tier_keys", "queue_depth", "lanes",
-    "hosts", "procs", "fused_unsupported", "cc_dedup_capacity",
+    "hosts", "procs",
     "pool_busy_frac", "jobs_per_min", "burnin_frac", "flex_width",
     "quarantined", "first_dispatch_s", "race_losers_alive",
     "action_lanes", "row_words",
@@ -480,8 +455,8 @@ class Metrics:
     def merge(self, other: "Metrics") -> None:
         """Fold ``other`` in: timers/counters add, maxima take max, and
         gauges (:data:`GAUGES`) take the incoming value — last-writer-
-        wins, so a merged profile can never report ``fused=2`` or a
-        summed ``mesh_shards`` no mesh ever had.
+        wins, so a merged profile can never report a summed
+        ``lanes`` or ``mesh_shards`` no mesh ever had.
 
         Used by consumers that aggregate engines (e.g. the host-vs-
         device race reporting the winner on top of its own bookkeeping).

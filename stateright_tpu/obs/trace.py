@@ -125,11 +125,6 @@ EVENT_SCHEMA: Dict[str, frozenset] = {
     "audit": frozenset({"chunk", "rows", "mismatches"}),
     "corruption": frozenset({"error"}),
     "quarantine": frozenset({"device", "quarantined"}),
-    # a fused='auto' configuration is OUTSIDE the kernel's support
-    # matrix (sound mode / host properties / hint) and stayed staged —
-    # emitted once per run with the supports() reason, so "why didn't
-    # this run fuse?" is answerable from the trace, not a shrug
-    "fused_unsupported": frozenset({"reason"}),
     # chaos soak harness (actor/chaos.py + tools/soak.py): live
     # crash/restart of one spawned actor (the runtime twin of the
     # modeled Crash/Restart), a partition flip (groups=[] on heal), a

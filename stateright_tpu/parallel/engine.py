@@ -285,37 +285,8 @@ class ShardedTpuChecker(TpuChecker):
                     insert_fn, carry.key_hi, carry.key_lo, table_fps, D)
                 carry = carry._replace(key_hi=key_hi, key_lo=key_lo)
 
-        # fused Pallas kernel selection (ops/fused.py): the sharded
-        # step kernel fuses expand→fingerprint→props→pre-dedup up to
-        # the exchange boundary, and the owner-side post-exchange
-        # probe/insert runs as the pipeline's SECOND Pallas kernel —
-        # both verified (the probe kernel's verify wall time rides the
-        # probe_kernel_s metric) before 'auto' commits to the path
-        kb_eff = (effective_kb(kmax, D, kb)
-                  if exchange == "bucket" and D > 1 else 0)
-        fused_on, fused_interp = self._fused_resolve(
-            sharded=True, fmax=fmax, capacity=self._capacity // D,
-            probe_lanes=(D * kb_eff if kb_eff else kmax))
-        self._metrics.set("fused", 1 if fused_on else 0)
         self._metrics.set("action_lanes", model.max_actions)
         self._metrics.set("row_words", model.packed_width)
-        # cross-chunk dedup ring (fused path only): per-shard (cc,)
-        # slices of one mesh-sharded array pair, threaded OUTSIDE
-        # ShardedCarry for the same persistent-compile-cache reason as
-        # the single-chip engine; None = re-zeroed lazily (fresh run,
-        # post-fault/degrade reseed — a new mesh width changes the
-        # global ring shape anyway)
-        cc_cap = self._cc_cap if fused_on else 0
-        cc_ring = [None]
-        if cc_cap:
-            self._metrics.set("cc_dedup_capacity", cc_cap)
-
-        def _fresh_ring():
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            sh = NamedSharding(mesh, P(axis))
-            z = np.zeros((D * cc_cap,), np.uint32)
-            return (jax.device_put(z, sh), jax.device_put(z.copy(), sh))
-
         # True until a freshly built chunk program's first call, which
         # traces and compiles it (or loads it from the compile cache)
         first_call = [False]
@@ -328,23 +299,9 @@ class ShardedTpuChecker(TpuChecker):
                 fn = build_sharded_chunk_fn(
                     model, mesh, axis, qcap, self._capacity, fmax, kmax,
                     symmetry=self._symmetry, sound=self._sound,
-                    kraw=kraw, exchange=exchange, kb=kb, ecap=ecap,
-                    fused=fused_on, fused_interpret=fused_interp,
-                    cc=cc_cap)
+                    kraw=kraw, exchange=exchange, kb=kb, ecap=ecap)
             first_call[0] = True
-            if not cc_cap:
-                return fn
-
-            def chunk_with_ring(carry_, remaining_, grow_):
-                if cc_ring[0] is None:
-                    cc_ring[0] = _fresh_ring()
-                carry2, rhi, rlo, stats_d = fn(
-                    carry_, cc_ring[0][0], cc_ring[0][1], remaining_,
-                    grow_)
-                cc_ring[0] = (rhi, rlo)
-                return carry2, stats_d
-
-            return chunk_with_ring
+            return fn
 
         chunk_fn = rebuild_chunk()
         pipeline = bool(opts.get("pipeline", True))
@@ -448,8 +405,6 @@ class ShardedTpuChecker(TpuChecker):
             first_call[0] = False
             t_disp = time.perf_counter()
             self._metrics.inc("chunks")
-            if fused_on:
-                self._metrics.inc("fused_chunks")
             if self._metrics.get("first_dispatch_s") is None:
                 self._metrics.set("first_dispatch_s",
                                   t_disp - self._spawn_t0)
@@ -494,10 +449,6 @@ class ShardedTpuChecker(TpuChecker):
             disc_lo = stats[base + 2 * prop_count:base + 3 * prop_count]
             e_n = stats[base + 3 * prop_count:
                         base + 3 * prop_count + D].astype(np.int64)
-            # cross-chunk dedup ring hits ride one trailing element of
-            # the replicated sync vector on the fused+cc path
-            cch = (int(stats[base + 3 * prop_count + D])
-                   if cc_cap else 0)
             if shadow is not None:
                 # fold each shard's appends into the host shadow: the
                 # per-shard queue/log slices are append-only and keep
@@ -631,8 +582,6 @@ class ShardedTpuChecker(TpuChecker):
                 metrics.inc("predup_hits", pdh)
             if prb:
                 metrics.inc("probe_rounds", prb)
-            if cch:
-                metrics.inc("cc_dedup_hits", cch)
             if size_key is not None:
                 _SIZE_MEMO.merge_max(size_key, (vmax, dmax))
             self._state_count += gen
@@ -655,8 +604,6 @@ class ShardedTpuChecker(TpuChecker):
                     load=round(int(log_n.max()) / (self._capacity // D),
                                4),
                     vmax=vmax, dmax=dmax, bmax=bmax,
-                    # cross-chunk ring hits this chunk (fused+cc only)
-                    cc_hits=(cch if cc_cap else None),
                     # per-shard balance/exchange volumes: states each
                     # owner shard inserted this chunk, plus its live
                     # queue depth
@@ -725,13 +672,6 @@ class ShardedTpuChecker(TpuChecker):
             vmax, dmax, bmax = kovf_pend
             before = (kraw, kmax, kb)
             grew = False
-            if fused_on and kraw < fa:
-                # the fused step subsumes the kraw staging (the kernel
-                # dedups in-register at full F*A width), so a memo-
-                # tightened kraw must never clamp the kmax resize below
-                # what the abort actually observed
-                kraw = fa
-                grew = True
             if vmax > kraw:
                 kraw = min(max(kraw * 2,
                                -(-(vmax + vmax // 4) // 256) * 256),
@@ -754,8 +694,8 @@ class ShardedTpuChecker(TpuChecker):
                 # wedged pre-mutation abort: rebuilding the identical
                 # program would abort forever — reclassify as a
                 # capacity fault; the retry envelope recovers with a
-                # k-buffer grown to its bound (satellite: the fused/
-                # sharded kovf abort no longer surfaces to the user)
+                # k-buffer grown to its bound (the kovf abort never
+                # surfaces to the user)
                 from ..checker.resilience import CandidateOverflowError
                 raise CandidateOverflowError(
                     "candidate-buffer capacity overflow (kovf) wedged "
@@ -891,10 +831,6 @@ class ShardedTpuChecker(TpuChecker):
                        log_n=np.zeros(D, np.int64),
                        e_n=np.zeros(D, np.int64))
             kovf_pend[:] = [0, 0, 0]
-            # re-zero the cc ring lazily: the old arrays may be fault-
-            # poisoned, and after a degrade rung the mesh width (hence
-            # the global ring shape) changed anyway
-            cc_ring[0] = None
             chunk_fn = rebuild_chunk(recover_reason)
 
         spill_warned = [False]
@@ -1190,9 +1126,9 @@ class ShardedTpuChecker(TpuChecker):
                         self._capacity_terminal(exc, shadow, discoveries)
                     cand = find_candidate_overflow(exc)
                     if cand is not None:
-                        # the fused/sharded kovf pre-mutation abort
-                        # re-routes here with a GROWN k-buffer instead
-                        # of raising to the user
+                        # the kovf pre-mutation abort re-routes here
+                        # with a GROWN k-buffer instead of raising to
+                        # the user
                         kraw = fa
                         kmax = min(max(kmax * 2, cand.dmax
                                        + cand.dmax // 4), fa)

@@ -183,10 +183,7 @@ def build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
                            capacity: int, fmax: int, kmax: int,
                            symmetry: bool = False, sound: bool = False,
                            kraw: int = 0, exchange: str = "ring",
-                           kb: int = 0, ecap: int = 0,
-                           fused: bool = False,
-                           fused_interpret: bool = False,
-                           cc: int = 0):
+                           kb: int = 0, ecap: int = 0):
     """Compile the K-iteration SPMD chunk runner for fixed buffer shapes.
 
     ``qcap``/``capacity`` are **global**; each shard works on its
@@ -210,15 +207,13 @@ def build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
     key = None
     if mkey is not None:
         key = ("chunk", mkey, mesh, axis, qcap, capacity, fmax, kmax,
-               symmetry, sound, kraw, exchange, kb, ecap, fused,
-               fused_interpret, cc)
+               symmetry, sound, kraw, exchange, kb, ecap)
         cached = _SHARDED_CACHE.get(key)
         if cached is not None:
             return cached
     fn = _build_sharded_chunk_fn(model, mesh, axis, qcap, capacity,
                                  fmax, kmax, symmetry, sound, kraw,
-                                 exchange, kb, ecap, fused,
-                                 fused_interpret, cc)
+                                 exchange, kb, ecap)
     if key is not None:
         _SHARDED_CACHE[key] = fn
     return fn
@@ -229,21 +224,8 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
                             symmetry: bool = False,
                             sound: bool = False, kraw: int = 0,
                             exchange: str = "ring", kb: int = 0,
-                            ecap: int = 0, fused: bool = False,
-                            fused_interpret: bool = False,
-                            cc: int = 0):
+                            ecap: int = 0):
     from ..checker.device_loop import shrink_indices
-    if fused:
-        # the sharded fusion boundary is the exchange: expand, hash,
-        # property eval and pre-dedup (in-batch arena + the cross-chunk
-        # ring) run in the step kernel; the post-exchange probe/insert
-        # runs as a SECOND Pallas kernel on the owner shard
-        # (ops/fused.py build_probe_block_fn), so a chunk iteration is
-        # two kernel dispatches around one collective
-        # (supports() keeps sound staged)
-        assert not sound, "fused sharded build outside its support matrix"
-    else:
-        assert not cc, "cc dedup ring is a fused-path structure"
 
     D = mesh.shape[axis]
     kbits = _owner_bits(D)
@@ -315,84 +297,22 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
         return go
 
     def make_step(fmax_b: int, kraw_b: int, kfin_b: int):
-      if fused:
-        from ..ops.fused import (build_fused_block_fn,
-                                 build_probe_block_fn, cc_ring_update)
-        fused_blk = build_fused_block_fn(
-            model, fmax_b, 0, symmetry=symmetry, probe=False,
-            interpret=fused_interpret, props=bool(prop_count), cc=cc)
-        # the kernel's in-register dedup subsumes the kraw staging: the
-        # stage-two compaction (and the kovf abort, still pre-mutation
-        # here — the probe runs after the exchange) works off the raw
-        # F*A lane masks
-        kraw_b = fmax_b * n_actions
-        # the SECOND kernel of the fused pipeline: the owner-side
-        # post-exchange probe/insert (model-independent, sized to the
-        # received lane width and the per-shard table slice)
-        probe_blk = build_probe_block_fn(
-            D * kb if bucket else kfin_b, closc,
-            interpret=fused_interpret)
-
-      def step(state):
-        # the materialized count (chunk-local, like cchv) rides the loop
-        # state beside the carry
-        if fused and cc:
-            c, rhi, rlo, cchv, mat, target_remaining, grow_limit = state
-        else:
+        def step(state):
+            # the materialized count (chunk-local) rides the loop state
+            # beside the carry
             c, mat, target_remaining, grow_limit = state
-            rhi = rlo = cchv = None
-        me = lax.axis_index(axis).astype(jnp.uint32)
-        me_i = me.astype(jnp.int32)
-        q_head, q_tail, log_n = c.q_head[0], c.q_tail[0], c.log_n[0]
-        elog, e_n = c.elog, c.e_n[0]
+            me = lax.axis_index(axis).astype(jnp.uint32)
+            me_i = me.astype(jnp.int32)
+            q_head, q_tail, log_n = c.q_head[0], c.q_tail[0], c.log_n[0]
+            elog, e_n = c.elog, c.e_n[0]
 
-        take = jnp.minimum(q_tail - q_head, fmax_b)
-        sl = lax.dynamic_slice(c.q, (q_head, 0), (fmax_b, width + 3))
-        frontier = sl[:, :width]
-        ebits = sl[:, width]
-        pfp = (sl[:, width + 1], sl[:, width + 2])
-        fvalid = jnp.arange(fmax_b, dtype=jnp.int32) < take
+            take = jnp.minimum(q_tail - q_head, fmax_b)
+            sl = lax.dynamic_slice(c.q, (q_head, 0), (fmax_b, width + 3))
+            frontier = sl[:, :width]
+            ebits = sl[:, width]
+            pfp = (sl[:, width + 1], sl[:, width + 2])
+            fvalid = jnp.arange(fmax_b, dtype=jnp.int32) < take
 
-        if fused:
-            # fused front-end (ops/fused.py): ONE Pallas kernel expands,
-            # fingerprints, evaluates the property predicates (discovery
-            # lanes flagged in-register — only the per-property sticky
-            # registers leave the kernel) and pre-dedups this shard's
-            # frontier block — against the in-batch arena AND, with
-            # ``cc``, the cross-chunk recent-key ring, so a duplicate
-            # re-generated chunks apart dies BEFORE it costs an
-            # exchange hop. The staged exchange below consumes the
-            # kernel's lane masks directly.
-            fout = fused_blk(frontier, ebits, fvalid,
-                             pfp=pfp if prop_count else None,
-                             ring=(rhi, rlo) if cc else None)
-            cvalid = fout.cvalid
-            gen_count = cvalid.sum(dtype=jnp.int32)
-            vcount = gen_count
-            xovf_it = fout.xovf
-            p_whi, p_wlo = pfp
-            disc_hit, disc_hi, disc_lo = (c.disc_hit, c.disc_hi,
-                                          c.disc_lo)
-            if prop_count:
-                hit_l = fout.disc_hit
-                cand_hi, cand_lo = fout.disc_hi, fout.disc_lo
-                negsel = jnp.where(hit_l, jnp.int32(D - 1) - me_i,
-                                   jnp.int32(-1))
-            else:
-                negsel = jnp.zeros((0,), jnp.int32)
-            rows_k = fout.flat
-            rvalid = cvalid
-            s_chi, s_clo = fout.chi, fout.clo
-            o_hi, o_lo = fout.ohi, fout.olo
-            # parent-side columns broadcast along the action axis
-            par3 = jnp.repeat(
-                jnp.stack([fout.ebits, p_whi, p_wlo], axis=1),
-                n_actions, axis=0)
-            ebits_k = par3[:, 0]
-            dvalid = fout.dvalid
-            cch_it = fout.cch
-            k_chi, k_clo = s_chi, s_clo
-        else:
             # shared check_block analog (ops/expand.py) on local rows;
             # the frontier fingerprints come from the queue cache, not a
             # re-hash, and child fingerprints are deferred to the narrow
@@ -448,147 +368,100 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
                 dvalid = pre_dedup(s_chi, s_clo, rvalid)
                 k_chi, k_clo = s_chi, s_clo
             xovf_it = exp.xovf
-            cch_it = jnp.int32(0)
-        dcount = dvalid.sum(dtype=jnp.int32)
-        if bucket:
-            # exact per-destination counts (the dedup key's top bits
-            # pick the owner), pre-abort: a skewed batch must not
-            # overflow a send bucket mid-mutation
-            own_raw = (k_chi >> jnp.uint32(32 - kbits)).astype(jnp.int32)
-            oh_raw = (own_raw[:, None]
-                      == jnp.arange(D, dtype=jnp.int32)[None, :]) \
-                & dvalid[:, None]
-            bmax_it = oh_raw.sum(axis=0, dtype=jnp.int32).max()
-        else:
-            bmax_it = jnp.int32(0)
-
-        # --- fused collective 1 of 3 (pre-ring): every reduction the
-        # abort gating needs, in ONE pmax
-        pm1 = lax.pmax(jnp.concatenate([
-            jnp.stack([vcount, dcount, xovf_it.astype(jnp.int32),
-                       bmax_it]),
-            negsel]), axis)
-        vshard, dshard, bshard = pm1[0], pm1[1], pm1[3]
-        xovf_any = pm1[2] > 0
-        kovf = c.kovf | (vshard > kraw_b) | (dshard > kfin_b)
-        if bucket:
-            kovf = kovf | (bshard > kb)
-        if prop_count:
-            min_shard = jnp.int32(D - 1) - pm1[4:4 + prop_count]
-            g_hit = pm1[4:4 + prop_count] >= 0
-            pick = hit_l & (me_i == min_shard)
-
-        cand, log_off = assemble_candidates(
-            rows_k, ebits_k, s_chi, s_clo, par3[:, 1], par3[:, 2],
-            o_hi, o_lo, width, symmetry, sound,
-            nk_hi=k_chi if sound else None,
-            nk_lo=k_clo if sound else None)
-        if kfin_b < kraw_b:
-            # stage two: dedup survivors to the ring-width buffer
-            src2 = shrink_indices(dvalid, kfin_b)
-            k_all = cand[src2]
-            kvalid = (jnp.arange(kfin_b, dtype=jnp.int32) < dcount) \
-                & ~kovf
-        else:
-            k_all = cand
-            kvalid = dvalid & ~kovf
-
-        if kbits:
-            owner = k_all[:, log_off] >> jnp.uint32(32 - kbits)
-        else:
-            owner = jnp.zeros((kfin_b,), jnp.uint32)
-
-        take = jnp.where(kovf, 0, take)
-        q_head = q_head + take
-        key_hi, key_lo = c.key_hi, c.key_lo
-        q, log = c.q, c.log
-        t_ovf = jnp.bool_(False)
-
-        if bucket:
-            # bucketed exchange: rank each lane within its destination
-            # (exclusive one-hot cumsum — pure elementwise), ONE scatter
-            # into the (D, kb) send buffer (a trailing validity column
-            # rides along so no separate count exchange is needed), ONE
-            # all_to_all, then ONE insert/append round over the D*kb
-            # received lanes.
-            own_f = owner.astype(jnp.int32)
-            oh = ((own_f[:, None]
-                   == jnp.arange(D, dtype=jnp.int32)[None, :])
-                  & kvalid[:, None]).astype(jnp.int32)
-            rank = jnp.take_along_axis(
-                jnp.cumsum(oh, axis=0), own_f[:, None], axis=1)[:, 0] - 1
-            dst = jnp.where(kvalid, own_f * kb + rank, D * kb)
-            sendbuf = jnp.zeros((D * kb, k_all.shape[1] + 1),
-                                jnp.uint32)
-            payload = jnp.concatenate(
-                [k_all, jnp.ones((kfin_b, 1), jnp.uint32)], axis=1)
-            sendbuf = sendbuf.at[dst].set(payload, mode="drop")
-            recv = lax.all_to_all(
-                sendbuf.reshape(D, kb, -1), axis, split_axis=0,
-                concat_axis=0, tiled=True).reshape(D * kb, -1)
-            mine = recv[:, -1] == 1
-            if fused:
-                # the owner-side probe/insert as the pipeline's second
-                # Pallas kernel (same jaxpr as table_insert — same
-                # bucket-probe invariant, same benign race)
-                inserted, key_hi, key_lo, t_ovf, prb_it = probe_blk(
-                    recv[:, log_off], recv[:, log_off + 1], mine,
-                    key_hi, key_lo)
+            dcount = dvalid.sum(dtype=jnp.int32)
+            if bucket:
+                # exact per-destination counts (the dedup key's top bits
+                # pick the owner), pre-abort: a skewed batch must not
+                # overflow a send bucket mid-mutation
+                own_raw = (k_chi >> jnp.uint32(32 - kbits)).astype(jnp.int32)
+                oh_raw = (own_raw[:, None]
+                          == jnp.arange(D, dtype=jnp.int32)[None, :]) \
+                    & dvalid[:, None]
+                bmax_it = oh_raw.sum(axis=0, dtype=jnp.int32).max()
             else:
+                bmax_it = jnp.int32(0)
+
+            # --- fused collective 1 of 3 (pre-ring): every reduction the
+            # abort gating needs, in ONE pmax
+            pm1 = lax.pmax(jnp.concatenate([
+                jnp.stack([vcount, dcount, xovf_it.astype(jnp.int32),
+                           bmax_it]),
+                negsel]), axis)
+            vshard, dshard, bshard = pm1[0], pm1[1], pm1[3]
+            xovf_any = pm1[2] > 0
+            kovf = c.kovf | (vshard > kraw_b) | (dshard > kfin_b)
+            if bucket:
+                kovf = kovf | (bshard > kb)
+            if prop_count:
+                min_shard = jnp.int32(D - 1) - pm1[4:4 + prop_count]
+                g_hit = pm1[4:4 + prop_count] >= 0
+                pick = hit_l & (me_i == min_shard)
+
+            cand, log_off = assemble_candidates(
+                rows_k, ebits_k, s_chi, s_clo, par3[:, 1], par3[:, 2],
+                o_hi, o_lo, width, symmetry, sound,
+                nk_hi=k_chi if sound else None,
+                nk_lo=k_clo if sound else None)
+            if kfin_b < kraw_b:
+                # stage two: dedup survivors to the ring-width buffer
+                src2 = shrink_indices(dvalid, kfin_b)
+                k_all = cand[src2]
+                kvalid = (jnp.arange(kfin_b, dtype=jnp.int32) < dcount) \
+                    & ~kovf
+            else:
+                k_all = cand
+                kvalid = dvalid & ~kovf
+
+            if kbits:
+                owner = k_all[:, log_off] >> jnp.uint32(32 - kbits)
+            else:
+                owner = jnp.zeros((kfin_b,), jnp.uint32)
+
+            take = jnp.where(kovf, 0, take)
+            q_head = q_head + take
+            key_hi, key_lo = c.key_hi, c.key_lo
+            q, log = c.q, c.log
+            t_ovf = jnp.bool_(False)
+
+            if bucket:
+                # bucketed exchange: rank each lane within its destination
+                # (exclusive one-hot cumsum — pure elementwise), ONE scatter
+                # into the (D, kb) send buffer (a trailing validity column
+                # rides along so no separate count exchange is needed), ONE
+                # all_to_all, then ONE insert/append round over the D*kb
+                # received lanes.
+                own_f = owner.astype(jnp.int32)
+                oh = ((own_f[:, None]
+                       == jnp.arange(D, dtype=jnp.int32)[None, :])
+                      & kvalid[:, None]).astype(jnp.int32)
+                rank = jnp.take_along_axis(
+                    jnp.cumsum(oh, axis=0), own_f[:, None], axis=1)[:, 0] - 1
+                dst = jnp.where(kvalid, own_f * kb + rank, D * kb)
+                sendbuf = jnp.zeros((D * kb, k_all.shape[1] + 1),
+                                    jnp.uint32)
+                payload = jnp.concatenate(
+                    [k_all, jnp.ones((kfin_b, 1), jnp.uint32)], axis=1)
+                sendbuf = sendbuf.at[dst].set(payload, mode="drop")
+                recv = lax.all_to_all(
+                    sendbuf.reshape(D, kb, -1), axis, split_axis=0,
+                    concat_axis=0, tiled=True).reshape(D * kb, -1)
+                mine = recv[:, -1] == 1
                 inserted, key_hi, key_lo, t_ovf, prb_it = table_insert(
                     key_hi, key_lo, recv[:, log_off],
                     recv[:, log_off + 1], mine, with_rounds=True)
-            cnt = inserted.sum(dtype=jnp.int32)
-            if sound and eloc:
-                # cross edges for the lasso sweep: dedup hits whose
-                # child node still has pending bits
-                ehit = mine & ~inserted & (recv[:, width] != 0)
-                esrc = shrink_indices(ehit, D * kb)
-                erows = jnp.concatenate(
-                    [recv[:, width + 5:width + 7],
-                     recv[:, width + 3:width + 5]], axis=1)[esrc]
-                elog = lax.dynamic_update_slice(elog, erows, (e_n, 0))
-                e_n = e_n + ehit.sum(dtype=jnp.int32)
-            src3 = shrink_indices(inserted, D * kb)
-            n_all = recv[src3]
-            q = lax.dynamic_update_slice(
-                q, n_all[:, :width + 3], (q_tail, 0))
-            log = lax.dynamic_update_slice(
-                log, n_all[:, log_off:log_off + c.log.shape[1]],
-                (log_n, 0))
-            q_tail = q_tail + cnt
-            log_n = log_n + cnt
-        else:
-            # ownership routing: D hops around the ring; each shard
-            # claims and dedups the in-flight children it owns, then
-            # forwards the buffer
-            rc = (k_all, kvalid, owner)
-            prb_it = jnp.int32(0)
-            for hop in range(D):
-                k_c, val_c, own_c = rc
-                mine = val_c & (own_c == me)
-                if fused:
-                    inserted, key_hi, key_lo, o, rnds = probe_blk(
-                        k_c[:, log_off], k_c[:, log_off + 1], mine,
-                        key_hi, key_lo)
-                else:
-                    inserted, key_hi, key_lo, o, rnds = table_insert(
-                        key_hi, key_lo, k_c[:, log_off],
-                        k_c[:, log_off + 1], mine, with_rounds=True)
-                prb_it = prb_it + rnds
-                t_ovf = t_ovf | o
                 cnt = inserted.sum(dtype=jnp.int32)
                 if sound and eloc:
-                    ehit = mine & ~inserted & (k_c[:, width] != 0)
-                    esrc = shrink_indices(ehit, kfin_b)
+                    # cross edges for the lasso sweep: dedup hits whose
+                    # child node still has pending bits
+                    ehit = mine & ~inserted & (recv[:, width] != 0)
+                    esrc = shrink_indices(ehit, D * kb)
                     erows = jnp.concatenate(
-                        [k_c[:, width + 5:width + 7],
-                         k_c[:, width + 3:width + 5]], axis=1)[esrc]
-                    elog = lax.dynamic_update_slice(elog, erows,
-                                                    (e_n, 0))
+                        [recv[:, width + 5:width + 7],
+                         recv[:, width + 3:width + 5]], axis=1)[esrc]
+                    elog = lax.dynamic_update_slice(elog, erows, (e_n, 0))
                     e_n = e_n + ehit.sum(dtype=jnp.int32)
-                src3 = shrink_indices(inserted, kfin_b)
-                n_all = k_c[src3]
+                src3 = shrink_indices(inserted, D * kb)
+                n_all = recv[src3]
                 q = lax.dynamic_update_slice(
                     q, n_all[:, :width + 3], (q_tail, 0))
                 log = lax.dynamic_update_slice(
@@ -596,83 +469,98 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
                     (log_n, 0))
                 q_tail = q_tail + cnt
                 log_n = log_n + cnt
-                if D > 1 and hop < D - 1:
-                    rc = tuple(lax.ppermute(x, axis, ring) for x in rc)
+            else:
+                # ownership routing: D hops around the ring; each shard
+                # claims and dedups the in-flight children it owns, then
+                # forwards the buffer
+                rc = (k_all, kvalid, owner)
+                prb_it = jnp.int32(0)
+                for hop in range(D):
+                    k_c, val_c, own_c = rc
+                    mine = val_c & (own_c == me)
+                    inserted, key_hi, key_lo, o, rnds = table_insert(
+                        key_hi, key_lo, k_c[:, log_off],
+                        k_c[:, log_off + 1], mine, with_rounds=True)
+                    prb_it = prb_it + rnds
+                    t_ovf = t_ovf | o
+                    cnt = inserted.sum(dtype=jnp.int32)
+                    if sound and eloc:
+                        ehit = mine & ~inserted & (k_c[:, width] != 0)
+                        esrc = shrink_indices(ehit, kfin_b)
+                        erows = jnp.concatenate(
+                            [k_c[:, width + 5:width + 7],
+                             k_c[:, width + 3:width + 5]], axis=1)[esrc]
+                        elog = lax.dynamic_update_slice(elog, erows,
+                                                        (e_n, 0))
+                        e_n = e_n + ehit.sum(dtype=jnp.int32)
+                    src3 = shrink_indices(inserted, kfin_b)
+                    n_all = k_c[src3]
+                    q = lax.dynamic_update_slice(
+                        q, n_all[:, :width + 3], (q_tail, 0))
+                    log = lax.dynamic_update_slice(
+                        log, n_all[:, log_off:log_off + c.log.shape[1]],
+                        (log_n, 0))
+                    q_tail = q_tail + cnt
+                    log_n = log_n + cnt
+                    if D > 1 and hop < D - 1:
+                        rc = tuple(lax.ppermute(x, axis, ring) for x in rc)
 
-        # --- fused collectives 2 and 3 of 3 (post-ring): the loop
-        # condition's maxima in ONE pmax, the sums (generated count and
-        # the picked discovery fingerprints) in ONE psum
-        pm2 = lax.pmax(jnp.stack([q_tail - q_head, q_tail, log_n, e_n,
-                                  t_ovf.astype(jnp.int32)]), axis)
-        pavail, max_tail, max_log, max_e = pm2[0], pm2[1], pm2[2], pm2[3]
-        ovf = c.ovf | ((pm2[4] > 0) & ~kovf)
-        xovf = c.xovf | xovf_any
-        # in-batch duplicate lanes this shard (dvalid already excludes
-        # the cross-chunk ring hits, counted separately as cch)
-        pdh_it = vcount - dcount - cch_it
-        if fused and cc:
-            # cross-chunk ring update, STAGED and post-commit: ring
-            # entries must stay a subset of the committed visited set,
-            # so only iterations that neither kovf-aborted (nothing
-            # mutated) nor hit a table probe overflow (some exchanged
-            # lanes unresolved at their owner) cache their exchanged
-            # keys. A key this shard sent was claimed by its owner —
-            # fresh or duplicate, it is in the visited set either way.
-            commit = ~kovf & (pm2[4] == 0)
-            rhi, rlo = cc_ring_update(
-                rhi, rlo, k_all[:, log_off], k_all[:, log_off + 1],
-                kvalid & commit, cc)
-            cchv = cchv + jnp.where(kovf, 0, cch_it)
-        if prop_count:
-            ps = lax.psum(jnp.concatenate([
-                jnp.stack([gen_count, pdh_it,
-                           prb_it]).astype(jnp.uint32),
-                jnp.where(pick, cand_hi, jnp.uint32(0)),
-                jnp.where(pick, cand_lo, jnp.uint32(0))]), axis)
-            gen_sum = ps[0].astype(jnp.int32)
-            pdh_sum = ps[1].astype(jnp.int32)
-            prb_sum = ps[2].astype(jnp.int32)
-            g_hi = ps[3:3 + prop_count]
-            g_lo = ps[3 + prop_count:3 + 2 * prop_count]
-            keep = disc_hit | ~g_hit
-            disc_hi = jnp.where(keep, disc_hi, g_hi)
-            disc_lo = jnp.where(keep, disc_lo, g_lo)
-            disc_hit = disc_hit | g_hit
-        else:
-            ps = lax.psum(jnp.stack([gen_count, pdh_it, prb_it]), axis)
-            gen_sum, pdh_sum, prb_sum = ps[0], ps[1], ps[2]
-        gen = c.gen + jnp.where(kovf, 0, gen_sum)
-        pdh = c.pdh + jnp.where(kovf, 0, pdh_sum)
-        prb = c.prb + jnp.where(kovf, 0, prb_sum)
-        vmax = jnp.maximum(c.vmax, vshard)
-        dmax = jnp.maximum(c.dmax, dshard)
-        bmax_c = jnp.maximum(c.bmax, bshard)
-        steps = c.steps - 1
-        go = go_from(pavail, max_tail, max_log, max_e, disc_hit, gen,
-                     ovf, xovf, kovf, steps, target_remaining,
-                     grow_limit)
-        nc = ShardedCarry(
-            q=q, q_head=q_head[None], q_tail=q_tail[None],
-            key_hi=key_hi, key_lo=key_lo,
-            log=log, log_n=log_n[None],
-            elog=elog, e_n=e_n[None],
-            disc_hit=disc_hit, disc_hi=disc_hi, disc_lo=disc_lo,
-            gen=gen, ovf=ovf, xovf=xovf, kovf=kovf, vmax=vmax,
-            dmax=dmax, bmax=bmax_c, steps=steps, go=go, pavail=pavail,
-            pdh=pdh, prb=prb)
-        # every shard builds all fmax_b * max_actions lanes' rows
-        mat = mat + fmax_b * n_actions * D
-        if fused and cc:
-            return (nc, rhi, rlo, cchv, mat, target_remaining, grow_limit)
-        return (nc, mat, target_remaining, grow_limit)
-      return step
+            # --- fused collectives 2 and 3 of 3 (post-ring): the loop
+            # condition's maxima in ONE pmax, the sums (generated count and
+            # the picked discovery fingerprints) in ONE psum
+            pm2 = lax.pmax(jnp.stack([q_tail - q_head, q_tail, log_n, e_n,
+                                      t_ovf.astype(jnp.int32)]), axis)
+            pavail, max_tail, max_log, max_e = pm2[0], pm2[1], pm2[2], pm2[3]
+            ovf = c.ovf | ((pm2[4] > 0) & ~kovf)
+            xovf = c.xovf | xovf_any
+            # in-batch duplicate lanes this shard
+            pdh_it = vcount - dcount
+            if prop_count:
+                ps = lax.psum(jnp.concatenate([
+                    jnp.stack([gen_count, pdh_it,
+                               prb_it]).astype(jnp.uint32),
+                    jnp.where(pick, cand_hi, jnp.uint32(0)),
+                    jnp.where(pick, cand_lo, jnp.uint32(0))]), axis)
+                gen_sum = ps[0].astype(jnp.int32)
+                pdh_sum = ps[1].astype(jnp.int32)
+                prb_sum = ps[2].astype(jnp.int32)
+                g_hi = ps[3:3 + prop_count]
+                g_lo = ps[3 + prop_count:3 + 2 * prop_count]
+                keep = disc_hit | ~g_hit
+                disc_hi = jnp.where(keep, disc_hi, g_hi)
+                disc_lo = jnp.where(keep, disc_lo, g_lo)
+                disc_hit = disc_hit | g_hit
+            else:
+                ps = lax.psum(jnp.stack([gen_count, pdh_it, prb_it]), axis)
+                gen_sum, pdh_sum, prb_sum = ps[0], ps[1], ps[2]
+            gen = c.gen + jnp.where(kovf, 0, gen_sum)
+            pdh = c.pdh + jnp.where(kovf, 0, pdh_sum)
+            prb = c.prb + jnp.where(kovf, 0, prb_sum)
+            vmax = jnp.maximum(c.vmax, vshard)
+            dmax = jnp.maximum(c.dmax, dshard)
+            bmax_c = jnp.maximum(c.bmax, bshard)
+            steps = c.steps - 1
+            go = go_from(pavail, max_tail, max_log, max_e, disc_hit, gen,
+                         ovf, xovf, kovf, steps, target_remaining,
+                         grow_limit)
+            nc = ShardedCarry(
+                q=q, q_head=q_head[None], q_tail=q_tail[None],
+                key_hi=key_hi, key_lo=key_lo,
+                log=log, log_n=log_n[None],
+                elog=elog, e_n=e_n[None],
+                disc_hit=disc_hit, disc_hi=disc_hi, disc_lo=disc_lo,
+                gen=gen, ovf=ovf, xovf=xovf, kovf=kovf, vmax=vmax,
+                dmax=dmax, bmax=bmax_c, steps=steps, go=go, pavail=pavail,
+                pdh=pdh, prb=prb)
+            # every shard builds all fmax_b * max_actions lanes' rows
+            mat = mat + fmax_b * n_actions * D
+            return (nc, mat, target_remaining, grow_limit)
+        return step
 
     step_large = make_step(fmax, kraw, kmax)
     if two_size:
         step_small = make_step(fmax_small, kraw_small,
                                min(kmax_small, kraw_small))
-
-    cc_state = bool(fused and cc)
 
     def run_loops(state):
         # sequenced small/large while_loops gated on the REPLICATED
@@ -710,8 +598,7 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
         # chunk (layout parsed by parallel/engine.py — keep in sync):
         # [q_head[D], q_tail[D], log_n[D],
         #  gen, ovf, xovf, kovf, vmax, dmax, bmax, pdh, prb, mat,
-        #  disc_hit[P], disc_hi[P], disc_lo[P], e_n[D],
-        #  cc ring hits (fused+cc only)]
+        #  disc_hit[P], disc_hi[P], disc_lo[P], e_n[D]]
         hs = lax.all_gather(out.q_head, axis, tiled=True)
         ts = lax.all_gather(out.q_tail, axis, tiled=True)
         ls = lax.all_gather(out.log_n, axis, tiled=True)
@@ -730,32 +617,6 @@ def _build_sharded_chunk_fn(model, mesh: Mesh, axis: str, qcap: int,
             out.disc_hi, out.disc_lo, es.astype(jnp.uint32)])
 
     specs = carry_specs(axis)
-    if cc_state:
-        def local_chunk_cc(carry, rhi, rlo, target_remaining,
-                           grow_limit):
-            # the cross-chunk ring threads OUTSIDE ShardedCarry (adding
-            # carry fields would change the staged programs' traced
-            # signatures — the persistent-compile-cache caveat); cch is
-            # chunk-local telemetry re-zeroed per dispatch, and so is
-            # the materialized count
-            state = run_loops((
-                entry_carry(carry, target_remaining, grow_limit),
-                rhi, rlo, jnp.int32(0), jnp.int32(0), target_remaining,
-                grow_limit))
-            out, rhi2, rlo2 = state[0], state[1], state[2]
-            cch = lax.psum(state[3], axis)
-            stats = jnp.concatenate([
-                base_stats(out, state[4]),
-                jnp.reshape(cch, (1,)).astype(jnp.uint32)])
-            return out, rhi2, rlo2, stats
-
-        s = P(axis)
-        fn = jax.shard_map(
-            local_chunk_cc, mesh=mesh,
-            in_specs=(specs, s, s, P(), P()),
-            out_specs=(specs, s, s, P()), check_vma=False)
-        return jax.jit(fn, donate_argnums=(0, 1, 2))
-
     def local_chunk(carry, target_remaining, grow_limit):
         state = run_loops((
             entry_carry(carry, target_remaining, grow_limit),

@@ -491,6 +491,11 @@ class Scheduler:
             else JobStore(store)
         self._lock = threading.RLock()
         self._running: Dict[str, _JobRuntime] = {}
+        #: job id -> its worker thread, until the worker's exit
+        #: bookkeeping (lease release, quarantine harvest and its
+        #: persisted record) is done: a job turns terminal BEFORE that,
+        #: so :meth:`wait` joins the thread it finds here
+        self._workers: Dict[str, threading.Thread] = {}
         self._closed = False
         self._step_budget = max(1, int(step_budget))
         self._metrics = Metrics()
@@ -689,14 +694,24 @@ class Scheduler:
     def wait(self, job_id: str, timeout: float = 60.0,
              states=TERMINAL_STATES) -> str:
         """Poll until the job reaches one of ``states`` (default: a
-        terminal state); returns the state reached (or the current one
-        on timeout)."""
+        terminal state; a terminal state also waits out the worker's
+        exit bookkeeping); returns the state reached (or the current
+        one on timeout)."""
         deadline = time.monotonic() + timeout
         job = self._store.get(job_id)
         while job is not None and job.state not in states \
                 and time.monotonic() < deadline:
             time.sleep(0.02)
-        return job.state if job is not None else "unknown"
+        if job is None:
+            return "unknown"
+        worker = self._workers.get(job_id)
+        if job.state in TERMINAL_STATES and worker is not None \
+                and worker is not threading.current_thread():
+            # the state flips inside the worker, before its exit
+            # bookkeeping: a caller reading quarantined() or the pool
+            # right after wait() must see that bookkeeping done
+            worker.join(max(0.0, deadline - time.monotonic()))
+        return job.state
 
     def shutdown(self, wait: bool = True, timeout: float = 60.0) -> None:
         """Stop placing work and pause every RUNNING job (each lands
@@ -1272,6 +1287,7 @@ class Scheduler:
             target=self._run_job, args=(job, lease, rt),
             name=f"stateright-job-{job.id}", daemon=True)
         rt.thread = thread
+        self._workers[job.id] = thread
         thread.start()
 
     # --- the per-job worker --------------------------------------------
@@ -1308,6 +1324,7 @@ class Scheduler:
             if fresh:
                 self._persist_quarantine()
             self._schedule()
+            self._workers.pop(job.id, None)
 
     # --- silent-corruption quarantine ----------------------------------
     def _device_key(self, device, pos: int) -> str:

@@ -274,8 +274,7 @@ class TestBenchHistory:
                            "unit": "uniq/s", "backend": "tpu"}}))
 
         row = {"workload": "tpu 2pc7 full 296448", "unit": "uniq/s",
-               "uniq": 1, "gen": 2, "gen_per_uniq": 2.0, "fused": False,
-               "metrics": {}}
+               "uniq": 1, "gen": 2, "gen_per_uniq": 2.0, "metrics": {}}
         art("BENCH_r01.json", [dict(row, best=1000.0)], value=100.0)
         art("BENCH_r02.json", [dict(row, best=400.0),
                                {"workload": "extra", "error": "boom"}],
@@ -324,7 +323,7 @@ class TestBenchHistory:
         bench_history = _tool("bench_history")
         row = {"workload": "tpu 2pc7 full 296448", "best": 900.0,
                "unit": "uniq/s", "uniq": 1, "gen": 2,
-               "gen_per_uniq": 2.0, "fused": False, "spilled": True,
+               "gen_per_uniq": 2.0, "spilled": True,
                "metrics": {"spills": 3, "host_tier_keys": 123}}
         (tmp_path / "BENCH_r09.json").write_text(json.dumps({
             "n": 1, "rc": 0, "tail": json.dumps(row),

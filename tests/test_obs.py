@@ -55,19 +55,19 @@ class TestMetrics:
 
     def test_merge_gauges_last_writer_wins(self):
         # gauges were SUMMED on merge: a raced-run fold could report
-        # fused=2 or a mesh width no mesh ever had — pinned here
+        # lanes=2 or a mesh width no mesh ever had — pinned here
         from stateright_tpu.obs import GAUGES, GLOSSARY
         assert GAUGES <= set(GLOSSARY)
         a, b = Metrics(), Metrics()
-        a.set("fused", 1)
+        a.set("lanes", 1)
         a.set("mesh_shards", 4)
         a.set("shard_balance", 0.9)
         a.set("fault_device", 3)
-        b.set("fused", 1)
+        b.set("lanes", 1)
         b.set("mesh_shards", 2)
         b.set("history_ok", 1)
         a.merge(b)
-        assert a.get("fused") == 1  # NOT 2
+        assert a.get("lanes") == 1  # NOT 2
         assert a.get("mesh_shards") == 2  # the incoming width, not 6
         assert a.get("shard_balance") == 0.9  # absent in b: untouched
         assert a.get("fault_device") == 3

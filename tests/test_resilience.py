@@ -923,14 +923,13 @@ class TestBenchContract:
         assert payload["value"] is not None
         assert "partial" not in payload
         assert "init_fallback" not in payload  # backend came up clean
-        # every workload row carries the fusion-proxy ratio and the
-        # dedup-path tag, so the trajectory can't silently mix paths
+        # every workload row carries the generated-per-unique ratio
         rows = [json.loads(ln) for ln in proc.stderr.splitlines()
                 if ln.startswith("{")]
         samples = [r for r in rows if "samples" in r]
         assert samples
         for row in samples:
-            assert "fused" in row, row["workload"]
+            assert "gen_per_uniq" in row, row["workload"]
             assert row["gen_per_uniq"] is None \
                 or row["gen_per_uniq"] >= 1.0
 

@@ -292,7 +292,8 @@ class TestFlexController:
         # lands on 4; when B finishes and its buddies merge free, the
         # flex pass doubles A mid-run — promotes == 1, digest pinned.
         # A's step delay keeps it running past B's finish (at 0.02 s a
-        # step it sometimes finished first, and nothing was promoted)
+        # step it sometimes finished first, and nothing was promoted;
+        # at 0.1 s it still did, now and then, on a loaded machine)
         if len(jax.devices()) < 8:
             pytest.skip("need 8 devices")
         sched = Scheduler(JobStore(tmp_path), devices=jax.devices(),
@@ -302,7 +303,7 @@ class TestFlexController:
             b = sched.submit(JobSpec("twopc", args=[3], options=OPTS,
                                      width=4))
             a = sched.submit(JobSpec("twopc", args=[4], options=OPTS,
-                                     width=8, step_delay=0.1))
+                                     width=8, step_delay=0.25))
             deadline = time.monotonic() + 60.0
             while time.monotonic() < deadline and not \
                     sched.job(a.id).status.get("granted_width"):
@@ -314,12 +315,8 @@ class TestFlexController:
             prof = sched.profile()
             assert prof.get("promotes") == 1
             assert prof.get("demotes", 0) == 0
-            # the promote lease releases in the worker's exit path,
-            # just AFTER the state flip wait() unblocks on — settle
-            deadline = time.monotonic() + 30.0
-            while time.monotonic() < deadline \
-                    and sched.profile().get("flex_width"):
-                time.sleep(0.05)
+            # wait() returned after the worker's exit path, which
+            # releases the promote lease
             assert sched.profile().get("flex_width") == 0
             assert sched.job(b.id).read_result()[
                 "fingerprints_sha256"] == _digest(solo_2pc3)

@@ -348,7 +348,7 @@ class TestBenchHistoryAttribution:
     def _art(tmp_path, name, metrics):
         row = {"workload": "tpu 2pc7 full 296448", "unit": "uniq/s",
                "best": 1000.0, "uniq": 1, "gen": 2, "gen_per_uniq": 2.0,
-               "fused": False, "metrics": metrics}
+               "metrics": metrics}
         (tmp_path / name).write_text(json.dumps({
             "n": 1, "rc": 0, "tail": json.dumps(row),
             "parsed": {"metric": "m", "value": 100.0,

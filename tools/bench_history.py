@@ -13,8 +13,8 @@ human reviewer noticed. This tool is the machine that notices:
 * **trend table** — one row per workload (names normalized across
   cap changes: ``tpu paxos3 capped 500k`` and ``... capped 40000`` are
   the same trend line), one column per round, each cell the best rate
-  with its tags (``fused``/``staged``, ``degraded``,
-  ``init_fallback``, ``multihost``) — so a round whose number was
+  with its tags (``degraded``, ``init_fallback``, ``multihost``) —
+  so a round whose number was
   measured on a degraded mesh, a CPU fallback, or a DCN-spanning
   fleet mesh can never silently ride the trajectory as a
   single-host device number;
@@ -147,8 +147,6 @@ def parse_round(path: str) -> Dict[str, Any]:
             "gen_per_uniq_cc": row.get("gen_per_uniq_cc"),
             "tags": sorted(
                 t for t, on in (
-                    ("fused", row.get("fused")),
-                    ("staged", row.get("fused") is False),
                     ("degraded", bool(metrics.get("degrades"))),
                     ("retried", bool(metrics.get("retries"))),
                     ("spilled", bool(row.get("spilled"))

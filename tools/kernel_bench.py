@@ -1,42 +1,25 @@
-"""Microbenchmark: staged expand→hash→dedup→probe vs the fused kernels.
+"""Microbenchmark: the staged expand→hash→dedup→probe pipeline, per stage.
 
 Usage:
     python tools/kernel_bench.py [--model 2pc7|2pc4|paxos3] [--fmax N]
                                  [--iters N] [--capacity 2^k] [--out F]
 
-Times ONE device iteration's dedup pipeline both ways, on a synthetic
-frontier drawn from the model's real reachable states (BFS prefix):
-
-  * **staged**, per stage — ``expand`` (``ops.expand.expand_frontier``,
-    child fingerprints deferred), ``hash`` (``fp64_device`` over the
-    compacted raw-valid lanes), ``pre_dedup`` (scatter-min claim arena),
-    ``probe`` (``ops.hashtable.table_insert``) — each stage jitted
-    standalone so the per-stage costs are visible, plus the composed
-    staged pipeline in one jit (what the engines actually run);
-  * **fused single-chip** (``ops.fused``): the one-kernel
-    expand→fingerprint→props→pre-dedup→probe path (in-kernel property
-    eval + the cross-chunk dedup ring, the production config);
-  * **fused sharded two-kernel path**: the step kernel at the exchange
-    boundary (``probe=False``) composed with the owner-side
-    post-exchange probe kernel (``build_probe_block_fn``) — what a
-    sharded fused chunk iteration dispatches around its all-to-all (the
-    collective itself is excluded: this is a single-process microbench
-    of the kernels, not the interconnect).
+Times ONE device iteration's dedup pipeline on a synthetic frontier
+drawn from the model's real reachable states (BFS prefix), per stage —
+``expand`` (``ops.expand.expand_frontier``, child fingerprints
+deferred), ``hash`` (``fp64_device`` over the compacted raw-valid
+lanes), ``pre_dedup`` (scatter-min claim arena), ``probe``
+(``ops.hashtable.table_insert``) — each stage jitted standalone so the
+per-stage costs are visible, plus the composed pipeline in one jit
+(what the engines run).
 
 JSON fields (one line on stdout):
-  ``stages.expand_ms/hash_ms/pre_dedup_ms/probe_ms`` — staged stages;
-  ``stages.probe_kernel_ms`` — the owner-side probe kernel standalone,
-  the direct A/B against ``stages.probe_ms`` at identical lanes/table;
-  ``staged_ms``/``fused_ms``/``fused_over_staged`` — composed
-  single-chip pipelines; ``sharded_staged_ms``/``sharded_fused_ms``/
-  ``sharded_fused_over_staged`` — the sharded two-kernel path vs its
-  staged equivalent (exchange excluded on both sides);
+  ``stages.expand_ms/hash_ms/pre_dedup_ms/probe_ms`` — the stages;
+  ``staged_ms`` — the composed pipeline;
   ``dup_lane_frac`` — the workload's duplicate-lane fraction (the
-  quantity the fusion attacks).
+  share of lanes the in-batch dedup kills).
 
-On non-TPU backends the fused paths run through the Pallas
-**interpreter** — correctness-representative, NOT perf-representative;
-the line carries ``"interpret": true`` so nobody reads a CPU ratio as a
+The line names its ``backend``: a time from a non-TPU backend is not a
 TPU result.
 """
 
@@ -121,8 +104,6 @@ def main(argv) -> int:
     from stateright_tpu.checker.device_loop import shrink_indices
     from stateright_tpu.ops.expand import (eventually_indices,
                                            expand_frontier, pre_dedup)
-    from stateright_tpu.ops.fused import (build_fused_block_fn,
-                                          build_probe_block_fn)
     from stateright_tpu.ops.hash_kernel import fp64_device
     from stateright_tpu.ops.hashtable import _BUCKET, table_insert
 
@@ -131,23 +112,17 @@ def main(argv) -> int:
     iters = int(args["--iters"])
     capacity = 1 << int(args["--capacity"])
     backend = jax.default_backend()
-    interpret = backend != "tpu"
 
     width = model.packed_width
     n_actions = model.max_actions
     fa = fmax * n_actions
-    props = len(model.properties()) > 0
-    cc = 1 << 12  # a small production-shaped ring for the bench
     ev_idx = eventually_indices(model.properties())
 
     frontier = jnp.asarray(_frontier(model, fmax))
     ebits = jnp.zeros((fmax,), jnp.uint32)
     fvalid = jnp.ones((fmax,), bool)
-    pfp0 = fp64_device(frontier)
     khi0 = jnp.zeros((capacity // _BUCKET, _BUCKET), jnp.uint32)
     klo0 = jnp.zeros((capacity // _BUCKET, _BUCKET), jnp.uint32)
-    rhi0 = jnp.zeros((cc,), jnp.uint32)
-    rlo0 = jnp.zeros((cc,), jnp.uint32)
 
     # --- staged stages, each standalone ------------------------------
     def stage_expand(rows):
@@ -181,77 +156,23 @@ def main(argv) -> int:
     j_probe = jax.jit(stage_probe)
     j_staged = jax.jit(staged_all)
 
-    # --- fused single-chip kernel (props + cc, the production shape) --
-    blk = build_fused_block_fn(
-        model, fmax, capacity, symmetry=False, probe=True,
-        interpret=interpret, props=props, cc=cc)
-
-    def fused_one(rows, khi, klo, rhi, rlo):
-        return blk(rows, ebits, fvalid, key_hi=khi, key_lo=klo,
-                   pfp=pfp0 if props else None, ring=(rhi, rlo))
-
-    fused_fn = jax.jit(fused_one)
-
-    # --- the sharded two-kernel path: step kernel at the exchange
-    # boundary + the owner-side probe kernel (exchange excluded) -------
-    step_blk = build_fused_block_fn(
-        model, fmax, 0, symmetry=False, probe=False,
-        interpret=interpret, props=props, cc=cc)
-    probe_blk = build_probe_block_fn(fa, capacity, interpret=interpret)
-
-    def sharded_fused(rows, khi, klo, rhi, rlo):
-        out = step_blk(rows, ebits, fvalid,
-                       pfp=pfp0 if props else None, ring=(rhi, rlo))
-        return probe_blk(out.chi, out.clo, out.dvalid, khi, klo)
-
-    j_sharded_fused = jax.jit(sharded_fused)
-    # its staged equivalent is the composed staged pipeline (the real
-    # sharded staged path interleaves the exchange between dedup and
-    # probe; the op content is identical)
-    j_sharded_staged = j_staged
-
-    def probe_kernel_one(khi, klo, chi_, clo_, dvalid_):
-        return probe_blk(chi_, clo_, dvalid_, khi, klo)
-
-    j_probe_kernel = jax.jit(probe_kernel_one)
-
     stages = {
         "expand_ms": _timed(j_expand, (frontier,), iters),
         "hash_ms": _timed(j_hash, (flat, cvalid), iters),
         "pre_dedup_ms": _timed(j_dedup, (chi, clo, cvalid), iters),
         "probe_ms": _timed(j_probe, (khi0, klo0, chi, clo, dvalid),
                            iters),
-        # the owner-side probe kernel, same lanes/table as probe_ms —
-        # the direct per-stage A/B the sharded fused path rides
-        "probe_kernel_ms": _timed(
-            j_probe_kernel, (khi0, klo0, chi, clo, dvalid), iters),
     }
     staged_ms = _timed(j_staged, (frontier, khi0, klo0), iters)
-    fused_ms = _timed(fused_fn, (frontier, khi0, klo0, rhi0, rlo0),
-                      iters)
-    sharded_staged_ms = _timed(j_sharded_staged,
-                               (frontier, khi0, klo0), iters)
-    sharded_fused_ms = _timed(j_sharded_fused,
-                              (frontier, khi0, klo0, rhi0, rlo0),
-                              iters)
 
     n_valid = int(np.asarray(cvalid).sum())
     n_dedup = int(np.asarray(dvalid).sum())
     line = {
-        "model": args["--model"], "backend": backend,
-        "interpret": interpret, "fmax": fmax,
+        "model": args["--model"], "backend": backend, "fmax": fmax,
         "lanes": fa, "valid_lanes": n_valid,
         "dup_lane_frac": round(1.0 - n_dedup / max(n_valid, 1), 4),
         "stages": stages,
         "staged_ms": staged_ms,
-        "fused_ms": fused_ms,
-        "fused_over_staged": round(fused_ms / staged_ms, 3)
-        if staged_ms else None,
-        "sharded_staged_ms": sharded_staged_ms,
-        "sharded_fused_ms": sharded_fused_ms,
-        "sharded_fused_over_staged": round(
-            sharded_fused_ms / sharded_staged_ms, 3)
-        if sharded_staged_ms else None,
     }
     out = json.dumps(line)
     print(out)

@@ -177,7 +177,6 @@ def report(events, out=None):
                   ("grow", "hgrow", "egrow", "kovf", "compile",
                    "retry", "watchdog", "autosave", "failover",
                    "degrade", "promote", "host_promote",
-                   "fused_unsupported",
                    "recorder_dump",
                    "corruption", "quarantine",
                    "spill", "evict", "pause",
@@ -393,20 +392,6 @@ def report(events, out=None):
                 lanes = [e.get("lanes") for e in batches
                          if e.get("bucket") == b]
                 out.write(f"  bucket {b}: lanes={lanes[0]}\n")
-
-        # fused-kernel summary: why a fused='auto' run never fused
-        # (the supports() exclusion), and what the cross-chunk dedup
-        # ring killed
-        unsup = [e for e in evs if e["ev"] == "fused_unsupported"]
-        if unsup:
-            out.write(f"\nfused: unsupported — "
-                      f"{unsup[0].get('reason', '?')}\n")
-        cc_hits = sum(e.get("cc_hits") or 0
-                      for e in evs if e["ev"] == "chunk")
-        if cc_hits:
-            out.write(f"\nfused: cc_dedup_hits={cc_hits} "
-                      "(cross-chunk ring kills before the table "
-                      "probe/exchange)\n")
 
         for ev in evs:
             if ev["ev"] == "discovery":

@@ -10,8 +10,8 @@ as a scrolling console: per-chunk progress lines with unique-state
 throughput, dedup hit-rate, table load factor, queue depth and the
 device/transfer time split, plus one line per intervention — growth and
 kovf resizes, compiles, the resilience layer's
-retry/watchdog/autosave/failover/degrade events, fused-kernel
-fallbacks, flight-recorder dumps, and the soak harness's live
+retry/watchdog/autosave/failover/degrade events, flight-recorder
+dumps, and the soak harness's live
 crash/restart/partition injections — and the discovery/done/error
 endings.
 
@@ -181,7 +181,7 @@ class Console:
                     f"returned={ev.get('op_return')} "
                     f"timeouts={ev.get('op_timeouts')}")
         else:
-            # growth/resize, resilience, fused, recorder, soak faults —
+            # growth/resize, resilience, recorder, soak faults —
             # and any future event kind: always visible
             self._event_line(ev)
 
